@@ -46,6 +46,7 @@ import math
 from typing import Dict, Optional
 
 import jax
+from jax.extend import core as jcore
 import numpy as np
 
 # primitives that move data across mesh axes, with their per-rank byte
@@ -232,7 +233,7 @@ def _walk(jaxpr, sizes: Dict[str, int], est: CostEstimate,
     last_use: Dict[int, int] = {}
     for i, eqn in enumerate(jaxpr.eqns):
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, jcore.Literal):
                 last_use[id(v)] = i
     for v in jaxpr.outvars:
         last_use[id(v)] = len(jaxpr.eqns)
@@ -247,15 +248,15 @@ def _walk(jaxpr, sizes: Dict[str, int], est: CostEstimate,
         if name == "scan":
             inner_repeat *= int(eqn.params.get("length", 1) or 1)
         for p in eqn.params.values():
-            if isinstance(p, jax.core.ClosedJaxpr):
+            if isinstance(p, jcore.ClosedJaxpr):
                 sub.append(p.jaxpr)
-            elif isinstance(p, jax.core.Jaxpr):
+            elif isinstance(p, jcore.Jaxpr):
                 sub.append(p)
             elif isinstance(p, (list, tuple)):
-                sub.extend(q.jaxpr if isinstance(q, jax.core.ClosedJaxpr)
+                sub.extend(q.jaxpr if isinstance(q, jcore.ClosedJaxpr)
                            else q for q in p
-                           if isinstance(q, (jax.core.Jaxpr,
-                                             jax.core.ClosedJaxpr)))
+                           if isinstance(q, (jcore.Jaxpr,
+                                             jcore.ClosedJaxpr)))
         if name == "cond":
             # branches are alternatives: flops of the widest branch,
             # peak of the most memory-hungry one (they may differ)
@@ -304,7 +305,7 @@ def _walk(jaxpr, sizes: Dict[str, int], est: CostEstimate,
                 live[id(v)] = _nbytes(v.aval)
         peak = max(peak, sum(live.values()) + inner_peak)
         for v in list(eqn.invars) + list(eqn.outvars):
-            if not isinstance(v, jax.core.Literal) \
+            if not isinstance(v, jcore.Literal) \
                     and last_use.get(id(v), -1) <= i:
                 live.pop(id(v), None)
     return peak

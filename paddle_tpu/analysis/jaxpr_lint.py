@@ -29,6 +29,7 @@ import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
+from jax.extend import core as jcore
 import numpy as np
 
 from .findings import (CONSTANT_OUTPUT, DEAD_COMPUTATION, DTYPE_PROMOTION,
@@ -126,8 +127,8 @@ def _eqn_sig(eqn) -> tuple:
         return (tuple(getattr(aval, "shape", ())),
                 str(getattr(aval, "dtype", "?")))
     name = eqn.primitive.name
-    if name == "pjit":  # jnp ops like cumsum hide behind pjit
-        name = f"pjit:{eqn.params.get('name', '?')}"
+    if name == "jit":  # jnp ops like cumsum hide behind a nested jit
+        name = f"jit:{eqn.params.get('name', '?')}"
     return (name,
             tuple(aval_sig(v) for v in eqn.invars),
             tuple(aval_sig(v) for v in eqn.outvars))
@@ -144,10 +145,9 @@ def _walk_eqns(jaxpr):
 
 
 def _subjaxprs(p):
-    core = jax.core
-    if isinstance(p, core.ClosedJaxpr):
+    if isinstance(p, jcore.ClosedJaxpr):
         yield p.jaxpr
-    elif isinstance(p, core.Jaxpr):
+    elif isinstance(p, jcore.Jaxpr):
         yield p
     elif isinstance(p, (list, tuple)):
         for item in p:
@@ -220,7 +220,7 @@ def _live_eqn_mask(jaxpr) -> List[bool]:
         if eqn.effects or any(id(v) in live_vars for v in eqn.outvars):
             mask[i] = True
             for v in eqn.invars:
-                if hasattr(v, "aval") and not isinstance(v, jax.core.Literal):
+                if hasattr(v, "aval") and not isinstance(v, jcore.Literal):
                     live_vars.add(id(v))
     return mask
 
@@ -259,10 +259,10 @@ def _used_var_ids(jaxpr) -> set:
     used = set()
     for eqn in jaxpr.eqns:
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, jcore.Literal):
                 used.add(id(v))
     for v in jaxpr.outvars:
-        if hasattr(v, "aval") and not isinstance(v, jax.core.Literal):
+        if hasattr(v, "aval") and not isinstance(v, jcore.Literal):
             used.add(id(v))
     return used
 
@@ -302,14 +302,14 @@ def _check_constant_outputs(closed, findings: List[Finding],
     jaxpr = closed.jaxpr
     reachable = {id(v) for v in jaxpr.invars}
     for eqn in jaxpr.eqns:
-        if any(not isinstance(v, jax.core.Literal) and id(v) in reachable
+        if any(not isinstance(v, jcore.Literal) and id(v) in reachable
                for v in eqn.invars):
             for v in eqn.outvars:
                 reachable.add(id(v))
     outs = jaxpr.outvars if n_user_out is None \
         else jaxpr.outvars[:n_user_out]
     for k, v in enumerate(outs):
-        is_const = isinstance(v, jax.core.Literal) or id(v) not in reachable
+        is_const = isinstance(v, jcore.Literal) or id(v) not in reachable
         if is_const:
             aval = getattr(v, "aval", None)
             desc = (f"({tuple(aval.shape)}:{aval.dtype})"
@@ -353,7 +353,7 @@ def _check_unrolled(closed, findings: List[Finding],
 
 
 # the named-jit dispatch/combine implementations MoELayer stages per
-# mode (incubate/distributed/models/moe/moe_layer.py): their pjit
+# mode (incubate/distributed/models/moe/moe_layer.py): their jit
 # equations carry the function name, which is how a traced program
 # reveals which MoE dispatch it baked in
 _MOE_SLOW_DISPATCH_FNS = {"moe_dispatch_einsum": "einsum",
@@ -368,7 +368,7 @@ def _check_moe_dispatch(closed, findings: List[Finding]):
     One finding per dispatch mode found, at the first occurrence."""
     seen = set()
     for eqn in _walk_eqns(closed.jaxpr):
-        if eqn.primitive.name != "pjit":
+        if eqn.primitive.name != "jit":
             continue
         mode = _MOE_SLOW_DISPATCH_FNS.get(eqn.params.get("name"))
         if mode is None or mode in seen:

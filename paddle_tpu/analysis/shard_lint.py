@@ -334,7 +334,6 @@ def lint_sharded(fn, args=(), kwargs=None, *, mesh,
     shapes and dtypes are read; nothing executes on any device.
     `in_specs` defaults to fully-replicated (each rank sees the whole
     example), so per-rank shapes equal the given shapes."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = as_mesh(mesh)
@@ -354,8 +353,8 @@ def lint_sharded(fn, args=(), kwargs=None, *, mesh,
     def call(*xs):
         return fn(*xs, **kwargs)
 
-    wrapped = shard_map(call, mesh=mesh, in_specs=tuple(in_specs),
-                        out_specs=out_specs, check_rep=False)
+    wrapped = jax.shard_map(call, mesh=mesh, in_specs=tuple(in_specs),
+                            out_specs=out_specs, check_vma=False)
     closed = None
     with recording(mesh) as rec:
         try:

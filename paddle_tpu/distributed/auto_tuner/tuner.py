@@ -113,7 +113,7 @@ class AutoTuner:
         import sys
 
         # run trial.py BY PATH, not -m: python -m would import the
-        # paddle_tpu parent package (and initialize the site-pinned jax
+        # paddle_tpu parent package (and initialize the default jax
         # backend) before the trial can force the virtual-CPU platform
         trial_path = os.path.join(os.path.dirname(__file__), "trial.py")
         cmd = [sys.executable, trial_path,

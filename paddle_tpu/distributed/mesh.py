@@ -101,31 +101,20 @@ def get_mesh() -> Optional[Mesh]:
 
 
 def ambient_concrete_mesh() -> Optional[Mesh]:
-    """The concrete mesh from JAX's own ambient context (native
-    ``jax.set_mesh`` builds), or None. The fallback that keeps
-    ``with jax.set_mesh(mesh):`` a sufficient spelling on BOTH
-    runtimes: on the pinned 0.4.x the compat shim installs the paddle
-    global directly; on newer jax only the ambient context is set and
-    consumers reach it through here."""
-    get_conc = getattr(jax.sharding, "get_concrete_mesh", None)
-    if get_conc is None:
-        return None
+    """The concrete mesh ``jax.set_mesh`` installed, or None: what
+    keeps ``with jax.set_mesh(mesh):`` a sufficient spelling for
+    consumers that otherwise read the paddle global."""
     try:
-        mesh = get_conc()
-    except Exception:  # noqa: BLE001 — probe, never fatal
+        mesh = jax.sharding.get_mesh()
+    except ValueError:  # under jit only the abstract mesh is visible
         return None
-    if mesh is None or not getattr(mesh, "axis_names", ()):
-        return None
-    return mesh
+    return mesh if mesh.axis_names else None
 
 
-class _SetMeshCompat:
-    """``jax.set_mesh`` impersonator for jax builds without one.
-
-    Mirrors the native API's BOTH usages: as a plain statement it
-    installs ``mesh`` as the paddle global immediately (persistently,
-    like native set_mesh's global install); as a context manager it
-    additionally enters the legacy jax mesh env and restores the
+class _UseMesh:
+    """What :func:`use_mesh` returns: as a plain statement it installs
+    ``mesh`` as the paddle global immediately; as a context manager it
+    additionally enters the jax ``Mesh`` context and restores the
     previous paddle global on exit."""
 
     def __init__(self, mesh: Mesh):
@@ -135,9 +124,7 @@ class _SetMeshCompat:
         set_mesh(mesh)
 
     def __enter__(self):
-        # the legacy Mesh context (physical axis-env binding) is the
-        # 0.4.x analog of jax.set_mesh's ambient-mesh install; an
-        # AbstractMesh has no context manager — the paddle global
+        # an AbstractMesh has no context manager — the paddle global
         # alone is what device-free analysis reads
         if hasattr(self.mesh, "__enter__"):
             self.mesh.__enter__()
@@ -152,57 +139,10 @@ class _SetMeshCompat:
         return False
 
 
-def use_mesh(mesh: Mesh) -> "_SetMeshCompat":
+def use_mesh(mesh: Mesh) -> "_UseMesh":
     """Install ``mesh`` as the paddle global (and, used as a context
-    manager, the legacy jax mesh env for the duration) — the portable
-    spelling behind the ``jax.set_mesh`` compat shim."""
-    return _SetMeshCompat(mesh)
-
-
-def _shard_map_compat(f, mesh=None, in_specs=None, out_specs=None,
-                      axis_names=None, check_rep=None, **kwargs):
-    """``jax.shard_map`` for jax builds that only ship
-    ``jax.experimental.shard_map`` (the pinned 0.4.x): translates the
-    newer ``axis_names={...}`` partial-manual spelling into the
-    experimental API's complementary ``auto=frozenset(...)``."""
-    from jax.experimental.shard_map import shard_map as _sm
-    if axis_names is not None and mesh is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kwargs["auto"] = auto
-    # the 0.4.x replication checker has no rule for
-    # sharding_constraint inside (partial-)manual regions — the mixed
-    # manual/GSPMD bodies every schedule here traces — so default it
-    # OFF unless the caller asked; newer jax (where this shim is
-    # never installed) runs its own vma checking regardless
-    kwargs["check_rep"] = bool(check_rep) if check_rep is not None \
-        else False
-    fn = _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-             **kwargs)
-    if kwargs.get("auto"):
-        # 0.4.x partial-manual shard_map has no EAGER impl ("if auto:
-        # raise NotImplementedError") — stage through jit, which is
-        # where every schedule here runs anyway; jit-in-jit callers
-        # just inline it
-        fn = jax.jit(fn)
-    return fn
-
-
-def _install_jax_set_mesh_compat() -> None:
-    """Give this jax build a ``jax.set_mesh`` / ``jax.shard_map`` when
-    it lacks them (both added upstream well after the pinned 0.4.x):
-    tests and user code use ``with jax.set_mesh(mesh):`` and
-    ``jax.shard_map(...)`` as the one spelling that works on every
-    version, delegating to :func:`use_mesh` / :func:`_shard_map_compat`
-    here."""
-    if not hasattr(jax, "set_mesh"):
-        jax.set_mesh = use_mesh
-    if not hasattr(jax, "shard_map"):
-        # marker consulted by code whose programs the 0.4.x lowering
-        # cannot compile (kernels/ring_attention.py fails loudly
-        # instead of letting XLA CHECK-abort the process)
-        _shard_map_compat._is_compat_shim = True
-        jax.shard_map = _shard_map_compat
+    manager, the jax ``Mesh`` context for the duration)."""
+    return _UseMesh(mesh)
 
 
 def ensure_mesh() -> Mesh:
@@ -223,10 +163,10 @@ def fake_mesh(degrees: Dict[str, int],
     axes are NOT padded to degree 1 — the analyzer should see exactly
     the axes the plan names."""
     from jax.sharding import AbstractMesh
-    named = [(ax, int(degrees[ax])) for ax in axis_order if ax in degrees]
-    named += [(ax, int(d)) for ax, d in degrees.items()
-              if ax not in axis_order]
-    return AbstractMesh(tuple(named))
+    names = [ax for ax in axis_order if ax in degrees]
+    names += [ax for ax in degrees if ax not in axis_order]
+    return AbstractMesh(tuple(int(degrees[ax]) for ax in names),
+                        tuple(names))
 
 
 def mesh_axis_sizes(mesh=None) -> Dict[str, int]:
@@ -245,7 +185,7 @@ def mesh_axis_sizes(mesh=None) -> Dict[str, int]:
 def axis_degree(name: str) -> int:
     mesh = get_mesh()
     if mesh is None:
-        # native-set_mesh builds install only jax's ambient context;
+        # `with jax.set_mesh(...)` installs only jax's ambient context;
         # the TP layer selection must see the same topology there
         mesh = ambient_concrete_mesh()
     if mesh is None or name not in mesh.axis_names:
@@ -408,5 +348,3 @@ def get_hybrid_communicate_group() -> HybridCommunicateGroup:
         _hcg = HybridCommunicateGroup()
     return _hcg
 
-
-_install_jax_set_mesh_compat()

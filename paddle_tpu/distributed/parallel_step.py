@@ -20,18 +20,22 @@ from ..jit.api import TrainStep
 from . import mesh as mesh_mod
 
 
-def _shard_leaf_over(arr, axis: str, mesh):
-    """Shard dim-0-divisible leaves over `axis`; replicate the rest."""
+def _also_over(sharding, shape, axis: str):
+    """`sharding` with `axis` added on the first free dim it divides
+    (ZeRO-1/2: a moment follows its parameter AND splits over the
+    sharding axis); unchanged when the axis is already used, has
+    degree 1, or fits no dim."""
     deg = mesh_mod.axis_degree(axis)
-    if deg <= 1:
-        return arr
-    for d, size in enumerate(arr.shape):
-        if size % deg == 0:
-            entries = [None] * arr.ndim
+    entries = list(sharding.spec) + [None] * (len(shape) - len(sharding.spec))
+    used = {a for e in entries if e is not None
+            for a in (e if isinstance(e, tuple) else (e,))}
+    if deg <= 1 or axis in used:
+        return sharding
+    for d, size in enumerate(shape):
+        if entries[d] is None and size % deg == 0:
             entries[d] = axis
-            return jax.device_put(
-                arr, NamedSharding(mesh, PartitionSpec(*entries)))
-    return arr
+            return NamedSharding(sharding.mesh, PartitionSpec(*entries))
+    return sharding
 
 
 def _batch_sharding(mesh, ndim):
@@ -57,20 +61,24 @@ class DistributedTrainStep(TrainStep):
     def __init__(self, model, loss_fn, optimizer, amp_dtype=None,
                  donate=True, sharding_stage: Optional[int] = None):
         inner = getattr(optimizer, "_inner_opt", optimizer)
-        super().__init__(model, loss_fn, inner, amp_dtype=amp_dtype,
-                         donate=donate)
         self._mesh = mesh_mod.ensure_mesh()
         stage = sharding_stage
         if stage is None:
             stage = getattr(inner, "_sharding_stage", 0)
         self._sharding_stage = int(stage or 0)
-        if self._sharding_stage >= 1 and \
-                mesh_mod.axis_degree("sharding") > 1:
-            self._opt_state = jax.tree_util.tree_map(
-                lambda a: _shard_leaf_over(a, "sharding", self._mesh),
-                self._opt_state)
+        super().__init__(model, loss_fn, inner, amp_dtype=amp_dtype,
+                         donate=donate)
 
-    def __call__(self, inputs, labels):
+    def _state_mesh(self):
+        return self._mesh
+
+    def _slot_sharding(self, param_sharding, shape):
+        if self._sharding_stage >= 1:
+            return _also_over(param_sharding, shape, "sharding")
+        return param_sharding
+
+    def _place(self, inputs, labels):
+        """Commit the batch to its data-axis sharding."""
         if not isinstance(inputs, (list, tuple)):
             inputs = (inputs,)
         mesh = self._mesh
@@ -88,4 +96,10 @@ class DistributedTrainStep(TrainStep):
         labels = jax.tree_util.tree_map(
             place, labels,
             is_leaf=lambda t: hasattr(t, "_data") or hasattr(t, "shape"))
-        return super().__call__(inputs, labels)
+        return inputs, labels
+
+    def lower(self, inputs, labels):
+        return super().lower(*self._place(inputs, labels))
+
+    def __call__(self, inputs, labels):
+        return super().__call__(*self._place(inputs, labels))

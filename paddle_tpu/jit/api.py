@@ -18,6 +18,7 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 import numpy as np
 
 from ..core import random as random_mod
@@ -440,6 +441,14 @@ def enable_to_static(flag=True):
     pass
 
 
+def _committed_sharding(a):
+    """The NamedSharding an array is committed to, else None."""
+    sh = getattr(a, "sharding", None)
+    if isinstance(sh, NamedSharding) and getattr(a, "committed", False):
+        return sh
+    return None
+
+
 class TrainStep:
     """Whole-train-step compilation:
 
@@ -465,6 +474,52 @@ class TrainStep:
         self._donate = donate
         from .functional import _tensor_registry
         self._registry = _tensor_registry(model)
+        self._state_shardings = None
+        mesh = self._state_mesh()
+        if mesh is not None:
+            self._commit_state(mesh)
+
+    def _state_mesh(self):
+        """The mesh a state leaf is committed to (a model built from
+        mpu layers commits its weights at construction), or None: a
+        plain model, which a single device runs with nothing to pin."""
+        for a in jax.tree_util.tree_leaves(
+                (self._params, self._frozen, self._buffers)):
+            sh = _committed_sharding(a)
+            if sh is not None:
+                return sh.mesh
+        return None
+
+    def _commit_state(self, mesh):
+        """Commit EVERY state leaf to an explicit sharding on `mesh`,
+        and remember them: the step's outputs are held to the same
+        shardings (_make_step), so the state keeps its layout from
+        step to step — one executable instead of one per change of
+        commitment, donation that can alias, and a parameter that never
+        loses its 'mp' split to the compiler's own choice of output
+        layout. Leaves already committed to `mesh` keep their sharding,
+        the rest replicate; an optimizer slot shaped like its parameter
+        follows it (_slot_sharding), scalar slots replicate."""
+        rep = NamedSharding(mesh, PartitionSpec())
+
+        def own(a):
+            sh = _committed_sharding(a)
+            return sh if sh is not None and sh.mesh == mesh else rep
+
+        params_sh = {n: own(a) for n, a in self._params.items()}
+        opt_sh = {
+            n: {slot: self._slot_sharding(params_sh[n], a.shape)
+                if a.shape and a.shape == self._params[n].shape else rep
+                for slot, a in st.items()}
+            for n, st in self._opt_state.items()}
+        self._params = jax.device_put(self._params, params_sh)
+        self._opt_state = jax.device_put(self._opt_state, opt_sh)
+        self._buffers = jax.device_put(self._buffers, rep)
+        self._frozen = jax.device_put(self._frozen, rep)
+        self._state_shardings = (params_sh, rep, opt_sh, rep)
+
+    def _slot_sharding(self, param_sharding, shape):
+        return param_sharding
 
     def _build_step(self):
         """The raw python step function (un-jitted) — also traced
@@ -513,7 +568,11 @@ class TrainStep:
 
     def _make_step(self):
         donate = (0, 1, 3) if self._donate else ()
-        return jax.jit(self._build_step(), donate_argnums=donate)
+        # (params, buffers, opt_state, loss) are held to the shardings
+        # _commit_state chose; None (a plain single-device model) leaves
+        # them to the compiler
+        return jax.jit(self._build_step(), donate_argnums=donate,
+                       out_shardings=self._state_shardings)
 
     @staticmethod
     def _leaf_sig(tree):
@@ -531,6 +590,31 @@ class TrainStep:
         Returns an analysis.Report."""
         from ..analysis import lint_train_step
         return lint_train_step(self, inputs, labels, mesh=mesh)
+
+    def lower(self, inputs, labels):
+        """The fused step lowered — not compiled, not run — at these
+        inputs/labels and the step's live state (shapes, dtypes and
+        shardings are read; no buffer is touched or donated).
+        ``.as_text()`` shows what the program baked in (a Pallas kernel
+        is a ``tpu_custom_call``); ``.compile()`` is the ahead-of-time
+        route."""
+        from ..analysis.functional_shapes import rng_key_struct
+
+        def struct(a):
+            a = unwrap(a) if isinstance(a, Tensor) else a
+            return jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=getattr(a, "sharding", None))
+
+        if not isinstance(inputs, (list, tuple)):
+            inputs = (inputs,)
+        state, data = jax.tree_util.tree_map(
+            struct, ((self._params, self._buffers, self._frozen,
+                      self._opt_state), (tuple(inputs), labels)),
+            is_leaf=lambda t: isinstance(t, Tensor))
+        params, buffers, frozen, opt_state = state
+        return self._make_step().lower(
+            params, buffers, frozen, opt_state, rng_key_struct(),
+            jax.ShapeDtypeStruct((), jnp.float32), *data)
 
     def __call__(self, inputs, labels):
         if not isinstance(inputs, (list, tuple)):

@@ -38,15 +38,23 @@ def paged_pallas_requirements(head_dim, block_size, cache_dtype):
     """Which Pallas-eligibility constraint a page-pool geometry misses,
     as a human-readable string — or None when the geometry is eligible.
     The [block_size, head_dim] page tile must meet the dtype's minimum
-    (sublane, lane) tile: (8, 128) f32, (16, 128) bf16/f16,
-    (32, 128) int8 (docs/DECODE.md eligibility table)."""
+    (sublane, lane) tile: (8, 128) f32, (16, 128) bf16/f16. An int8
+    pool also streams one f32 scale row of block_size lanes per
+    (page, head), which must fill whole 128-lane tiles — the v5e
+    compiler refuses to slice a narrower row out of the scale pool
+    (tests/test_tpu_compile.py; docs/DECODE.md eligibility table)."""
     name = jnp.dtype(cache_dtype).name
-    sublane = {"int8": 32, "bfloat16": 16, "float16": 16}.get(name, 8)
+    sublane = {"bfloat16": 16, "float16": 16}.get(name, 8)
     problems = []
     if head_dim % 128:
         problems.append(
             f"head_dim {head_dim} is not a multiple of the 128 lane width")
-    if block_size % sublane:
+    if name == "int8":
+        if block_size % 128:
+            problems.append(
+                f"page_size {block_size} is not a multiple of the 128 "
+                f"lanes an int8 pool's per-token scale rows must fill")
+    elif block_size % sublane:
         problems.append(
             f"page_size {block_size} is not a multiple of the {name} "
             f"sublane minimum {sublane}")
@@ -305,8 +313,8 @@ def _paged_decode_kernel(bt_ref, cl_ref, buf_ref, step_ref, q_ref,
 
     Refs: q [hpb, rep, d] (kv-head-major GQA rows), k/v pools
     [num_blocks, h_kv, bs, d] in ANY, [scale pools [num_blocks, h_kv,
-    bs] when quant], o [hpb, rep, d]; scratch: k/v chunk buffers
-    [2, hpb, ppc, bs, d] (+ scale buffers [2, hpb, ppc, bs]), one DMA
+    1, bs] when quant], o [hpb, rep, d]; scratch: k/v chunk buffers
+    [2, hpb, ppc, bs, d] (+ scale buffers [2, hpb, ppc, 1, bs]), one DMA
     semaphore per buffer slot, online-softmax m/l [hpb, rep, 128] and
     acc [hpb, rep, d].
     """
@@ -417,13 +425,22 @@ def _paged_decode_kernel(bt_ref, cl_ref, buf_ref, step_ref, q_ref,
         q = q_ref[...].astype(jnp.float32) * jnp.float32(scale)
         k = kbuf[buf].reshape(hpb, T, d).astype(jnp.float32)
         v = vbuf[buf].reshape(hpb, T, d).astype(jnp.float32)
-        if quant:
-            k = k * ksbuf[buf].reshape(hpb, T)[:, :, None]
-            v = v * vsbuf[buf].reshape(hpb, T)[:, :, None]
         # batched-over-heads skinny dots, f32 accumulation
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)       # [hpb, rep, T]
+        if quant:
+            # dequantize on the SCORE side: q·(k∘ks) = (q·k)∘ks and
+            # p·(v∘vs) = (p∘vs)·v, with the per-token scales as
+            # lane-dense [hpb, 1, T] rows (tokens on lanes, like s) —
+            # a [hpb, T, 1] column to scale k/v themselves is a shape
+            # cast the v5e compiler refuses, and rep·T multiplies are
+            # fewer than T·d anyway
+            def lane_row(sbuf):
+                pages = sbuf[buf]                  # [hpb, ppc, 1, bs]
+                return jnp.concatenate(
+                    [pages[:, p] for p in range(ppc)], axis=-1)
+            s = s * lane_row(ksbuf)
         pos = ctx - 1
         k_pos = (j * T
                  + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2))
@@ -440,7 +457,8 @@ def _paged_decode_kernel(bt_ref, cl_ref, buf_ref, step_ref, q_ref,
         alpha = jnp.exp(m_prev - m_cur)
         l_cur = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((2,), (1,)), ((0,), (0,))),
+            p * lane_row(vsbuf) if quant else p, v,
+            (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)       # [hpb, rep, d]
         m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_cur, l_ref.shape)
@@ -509,23 +527,27 @@ def paged_decode_pallas(q, k_cache, v_cache, block_tables, context_lens,
                        lambda i, hb, j, *_: (i, hb, 0, 0))
     in_specs = [
         blk,                                               # q
-        pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-        pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+        pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
     ]
     inputs = [qr, k_cache, v_cache]
     if quant:
         in_specs += [
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ]
-        inputs += [k_scale, v_scale]
+        # one [1, bs] row per (page, head): the page index stays a
+        # major dimension of the VMEM buffer the rows are copied into,
+        # so picking a page there never cuts through a tile
+        inputs += [k_scale.reshape(nb, h_kv, 1, bs),
+                   v_scale.reshape(nb, h_kv, 1, bs)]
     scratch = [
         pltpu.VMEM((2, hpb, ppc, bs, d), k_cache.dtype),
         pltpu.VMEM((2, hpb, ppc, bs, d), v_cache.dtype),
     ]
     if quant:
-        scratch += [pltpu.VMEM((2, hpb, ppc, bs), jnp.float32),
-                    pltpu.VMEM((2, hpb, ppc, bs), jnp.float32)]
+        scratch += [pltpu.VMEM((2, hpb, ppc, 1, bs), jnp.float32),
+                    pltpu.VMEM((2, hpb, ppc, 1, bs), jnp.float32)]
     scratch += [
         pltpu.SemaphoreType.DMA((2,)),
         pltpu.VMEM((hpb, rep, 128), jnp.float32),
@@ -547,7 +569,7 @@ def paged_decode_pallas(q, k_cache, v_cache, block_tables, context_lens,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, h_kv, rep, d), q.dtype),
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary",
                                      "arbitrary")),
             interpret=interpret,

@@ -131,17 +131,6 @@ def ring_attention_arrays(q, k, v, mesh=None, axis: str = "sep",
     if q.shape[2] % n:
         raise ValueError(
             f"seq len {q.shape[2]} not divisible by {axis} degree {n}")
-    if getattr(jax.shard_map, "_is_compat_shim", False):
-        # the 0.4.x shard_map compat shim (distributed.mesh): XLA on
-        # that jaxlib CHECK-aborts — killing the whole process, not
-        # just this call — when compiling the ring's partial-manual
-        # ppermute program, so fail loudly BEFORE the compile. Newer
-        # jax ships jax.shard_map natively and never takes this branch.
-        raise NotImplementedError(
-            f"ring attention over {axis}={n} needs a jax with native "
-            f"jax.shard_map (this build's experimental shard_map "
-            f"aborts XLA on the ring program); run on the newer-jax "
-            f"runtime or set the {axis} degree to 1")
     if k.shape[1] != v.shape[1] or k.shape[1] < 1 \
             or q.shape[1] % k.shape[1] != 0:
         raise ValueError(

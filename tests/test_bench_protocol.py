@@ -166,12 +166,22 @@ def test_plausible_mfu_carries_no_suspect_flag(stubbed, capsys,
     assert "llama_1b_mfu_suspect" not in lines[0]["extras"]
 
 
-def test_peak_microbench_is_dce_proof_by_construction():
+@pytest.mark.parametrize("known_kind", [True, False])
+def test_peak_microbench_is_dce_proof_by_construction(known_kind,
+                                                      monkeypatch):
     """The measured-peak protocol itself: grads anchored (value_and_grad
     over every layer weight — no matmul is dead code) and the sync
-    inside the timed window. Runs TINY on CPU; the assertion is that
-    the measured number exists, is finite, and the claimed FLOPs obey
-    the conservative 6L-2 count."""
+    inside the timed window. Runs TINY on CPU, whose device_kind has no
+    published peak: against a known row the measured number exists and
+    is finite; against the CPU's own kind `_peak()` raises instead of
+    assuming a v5e."""
+    if not known_kind:
+        with pytest.raises(RuntimeError, match="no published peak"):
+            bench.bench_peak_microbench(n=64, layers=2, reps=1)
+        return
+    monkeypatch.setattr(
+        bench, "_peak",
+        lambda: (bench.PEAK_FLOPS["TPU v5 lite"], "TPU v5 lite"))
     tf, ratio = bench.bench_peak_microbench(n=64, layers=2, reps=1)
     assert tf > 0 and ratio > 0
     import math
@@ -186,7 +196,9 @@ def test_failing_extra_records_error_and_continues(stubbed, capsys,
         raise RuntimeError("RESOURCE_EXHAUSTED: hbm")
 
     monkeypatch.setattr(bench, "bench_llama_long_seq", boom)
-    bench.main()
+    # the line still prints, and the run exits nonzero naming the extra
+    with pytest.raises(SystemExit, match="llama_seq2048"):
+        bench.main()
     lines = _lines(capsys)
     last = lines[-1]["extras"]
     assert "RESOURCE_EXHAUSTED" in last["llama_seq2048_error"]

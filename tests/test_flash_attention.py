@@ -145,26 +145,22 @@ def test_force_pallas_trains(rng):
         assert float(jnp.max(jnp.abs(g))) > 0
 
 
-def test_fallback_is_flag_gated(rng, monkeypatch):
-    """Kernel failure raises when the fallback flag is off, falls back
-    (logged) when on — never silently."""
-    from paddle_tpu.core.flags import set_flags
+def test_kernel_failure_raises(rng, monkeypatch):
+    """A kernel failure raises: no flag, no catch, no reroute to the
+    XLA path — in the forward or when the VJP is pulled."""
     from paddle_tpu.kernels import flash_attention as mod
 
     def boom(*a, **kw):
         raise RuntimeError("mosaic exploded")
 
-    monkeypatch.setattr(mod, "_flash_pallas", boom)
     q = jnp.ones((1, 128, 2, 128), jnp.float32)  # paddle layout [B,S,H,D]
-    set_flags({"flash_allow_fallback": False})
-    try:
-        with pytest.raises(RuntimeError, match="mosaic exploded"):
-            mod.flash_attention_arrays(q, q, q, force_pallas=True)
-    finally:
-        set_flags({"flash_allow_fallback": True})
-    # with the flag on (default) it falls back to the XLA path
-    out = mod.flash_attention_arrays(q, q, q, force_pallas=True)
-    assert out.shape == (1, 128, 2, 128)
+    monkeypatch.setattr(mod, "_flash_pallas_bwd", boom)
+    with pytest.raises(RuntimeError, match="mosaic exploded"):
+        jax.grad(lambda x: mod.flash_attention_arrays(
+            x, x, x, force_pallas=True, interpret=True).sum())(q)
+    monkeypatch.setattr(mod, "_flash_pallas", boom)
+    with pytest.raises(RuntimeError, match="mosaic exploded"):
+        mod.flash_attention_arrays(q, q, q, force_pallas=True)
 
 
 @pytest.mark.parametrize("window", [64, 128, 200, 256, 1000])
@@ -807,7 +803,10 @@ def test_paged_decode_pallas_int8_interpret(rng):
     from paddle_tpu.quantization.functional import kv_quantize_arrays
 
     b, h, h_kv, d, bs, nblocks = 3, 8, 4, 128, 32, 5
-    assert paged_pallas_eligible(d, bs, jnp.int8)
+    # interpret mode runs any geometry; the chip takes int8 pools only
+    # with whole 128-lane scale rows
+    assert paged_pallas_eligible(d, 128, jnp.int8)
+    assert not paged_pallas_eligible(d, bs, jnp.int8)
     q = jnp.asarray(rng.standard_normal((b, h, d)).astype(np.float32))
     kq, ks = kv_quantize_arrays(jnp.asarray(rng.standard_normal(
         (b * nblocks, h_kv, bs, d)).astype(np.float32)))
@@ -1024,19 +1023,54 @@ def test_bert_padding_mask_flash_pallas_matches_xla(rng, d):
                                    rtol=2e-3, atol=2e-3, err_msg=name)
 
 
-def test_head_dim_gating(monkeypatch):
-    """_tileable admits 128-granular head dims outright; 64 only when
-    the per-platform probe passes; everything else stays XLA."""
+def test_pallas_route_splits_over_the_mesh(rng):
+    """GSPMD cannot partition a Mosaic kernel, so under a multi-device
+    mesh the Pallas route runs inside a shard_map — batch over the data
+    axes, heads over 'mp', each device its own block — and still agrees
+    with the XLA route, forward and backward."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.distributed import mesh as mesh_mod
+
+    prev = mesh_mod.get_mesh()
+    mesh = mesh_mod.build_mesh({"sharding": 2, "mp": 2},
+                               devices=jax.devices()[:4])
+    mesh_mod.set_mesh(mesh)
+    try:
+        sh = NamedSharding(mesh, P("sharding", None, "mp", None))
+        q, k, v = (jax.device_put(jnp.asarray(
+            rng.standard_normal((4, 128, 4, 128)), jnp.float32), sh)
+            for _ in range(3))
+
+        def run(**route):
+            return jax.jit(jax.value_and_grad(
+                lambda q_, k_, v_: (flash_attention_arrays(
+                    q_, k_, v_, causal=True, **route) ** 2).sum(),
+                argnums=(0, 1, 2)))
+
+        pallas = run(force_pallas=True, interpret=True)
+        assert "shard_map" in str(jax.make_jaxpr(pallas)(q, k, v))
+        (lp, gp), (lx, gx) = pallas(q, k, v), run()(q, k, v)
+        np.testing.assert_allclose(float(lp), float(lx), rtol=1e-5)
+        for a, b_ in zip(gp, gx):
+            assert a.sharding.spec == sh.spec
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       rtol=2e-4, atol=2e-4)
+    finally:
+        mesh_mod._global_mesh = prev
+
+
+def test_head_dim_gating():
+    """_tileable admits 128-granular head dims and the 64-wide BERT
+    geometry (both compile for the v5e: tests/test_tpu_compile.py);
+    everything else stays XLA — a rule of geometry, no probe."""
     from paddle_tpu.kernels import flash_attention as fa
 
     assert fa._head_dim_ok(128) and fa._head_dim_ok(256)
-    assert not fa._head_dim_ok(96)
-    monkeypatch.setattr(fa, "_minor64_ok", True)
     assert fa._head_dim_ok(64)
     assert fa._tileable(128, 128, 64)
-    monkeypatch.setattr(fa, "_minor64_ok", False)
-    assert not fa._head_dim_ok(64)
-    assert not fa._tileable(128, 128, 64)
+    assert not fa._head_dim_ok(96) and not fa._head_dim_ok(32)
+    assert not fa._tileable(128, 128, 96)
 
 
 def test_sdpa_fully_masked_rows_emit_zeros(rng):

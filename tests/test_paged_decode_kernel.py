@@ -175,7 +175,8 @@ def test_chunk_geometry_and_requirements():
     ppc, hpb = _chunk_geometry(12, 32, 4, 128, 4)
     assert 12 % ppc == 0 and ppc * 32 <= 512
     assert 4 % hpb == 0
-    assert paged_pallas_requirements(128, 32, jnp.int8) is None
-    why = paged_pallas_requirements(64, 16, jnp.int8)
+    assert paged_pallas_requirements(128, 128, jnp.int8) is None
+    assert "128 lanes" in paged_pallas_requirements(128, 32, jnp.int8)
+    why = paged_pallas_requirements(64, 8, jnp.bfloat16)
     assert "head_dim 64" in why and "sublane" in why
     assert paged_pallas_requirements(128, 8, jnp.bfloat16) is not None

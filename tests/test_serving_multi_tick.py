@@ -85,18 +85,24 @@ def test_multi_tick_eos_freezes_mid_stretch(rng):
     truncated at the same position."""
     net = _tiny_net()
     prompt = _prompts(rng, (6,))[0]
-    # discover what greedy emits, then make token #2 the eos id so it
-    # fires strictly inside an 8-tick fused stretch
+    # discover what greedy emits, then make the eos id a token that
+    # FIRST appears at position >= 2, so it fires strictly inside an
+    # 8-tick fused stretch (the tiny net repeats itself: taking token
+    # #2 blindly can name an id already emitted at #0, and the request
+    # then — correctly — ends after one token)
     probe, _ = _run_trace(
         net, [(prompt, SamplingParams(max_new_tokens=8))], multi_tick=1)
-    eos = next(iter(probe.values())).token_ids[2]
+    stream = next(iter(probe.values())).token_ids
+    at = next(i for i in range(2, len(stream) - 1)
+              if stream[i] not in stream[:i])
+    eos = stream[at]
     reqs = [(prompt, SamplingParams(max_new_tokens=8,
                                     eos_token_id=int(eos)))]
     ref, _ = _run_trace(net, reqs, multi_tick=1)
     got, _ = _run_trace(net, reqs, multi_tick=8)
     r, g = next(iter(ref.values())), next(iter(got.values()))
     assert g.token_ids == r.token_ids
-    assert g.token_ids[-1] == eos and len(g.token_ids) == 3
+    assert g.token_ids[-1] == eos and len(g.token_ids) == at + 1
     assert g.finish_reason == r.finish_reason == "eos"
 
 
