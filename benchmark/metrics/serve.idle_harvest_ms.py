@@ -1,0 +1,14 @@
+"""Device-idle time a decode tick while the host was inside `engine.harvest`:
+token append, finish checks, retiring finished requests."""
+from benchmark.harness import program_spans
+
+NAME = "serve.idle_harvest_ms"
+UNIT = "ms"
+BETTER = "lower"
+LAYER = "serving scheduler"
+MOVES = "serve_tokens_per_s"
+SOURCE = "program_span"
+
+
+def compute(ctx):
+    return program_spans.serve_idle_ms_per_tick(ctx, "harvest")

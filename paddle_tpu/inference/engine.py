@@ -136,6 +136,7 @@ from ..core.dispatch import unwrap
 from ..core.flags import get_flag
 from ..jit.functional import get_buffers, get_frozen, get_params
 from ..kernels.paged_attention import paged_pallas_requirements
+from ..profiler.profiler import RecordEvent
 from ..profiler.stats import CompileTracker
 from ..text.generation import (_model_forward, _resolve_cache_dtype,
                                sample_token_arrays, verify_token_arrays)
@@ -258,6 +259,11 @@ class Request:
     queued_step: int = -1                 # step the request last queued
     arrival_t: float = 0.0
     first_token_t: float = 0.0
+    # when the newest token was appended (engine clock; 0.0 = none on
+    # THIS engine yet): the inter-token gap histogram reads it. Reset
+    # by extract_request and absent from snapshots, so no gap spans a
+    # migration or a restore; a preemption's stall IS a gap
+    last_token_t: float = 0.0
     finish_t: float = 0.0
     finish_reason: Optional[str] = None
     # host-truth span log (tracing.py): plain dicts on the engine
@@ -390,6 +396,42 @@ class _PendingTick:
     t_dispatch: float         # perf_counter at dispatch
     dev_mark: float           # self._device_s at dispatch
     k: int = 0                # spec: draft len / multi: fused ticks
+    variant: str = "greedy"   # sampler variant of the executable
+
+
+class _Emitted:
+    """The tokens one harvest appends. They share one clock reading,
+    so their gaps to each request's previous token are grouped by
+    value: a 48-slot tick records one histogram entry of weight 48,
+    not 48 locked calls. Under ``multi_tick`` the k tokens of one
+    dispatch arrive together and k-1 of their gaps are 0 — what a
+    client sees."""
+
+    __slots__ = ("now", "tokens", "gaps")
+
+    def __init__(self, now: float):
+        self.now = now
+        self.tokens = 0
+        self.gaps: Dict[float, int] = {}
+
+    def append(self, req: Request, tok: int) -> None:
+        req.generated.append(tok)
+        if req.first_token_t == 0.0:
+            req.first_token_t = self.now
+        if req.last_token_t > 0.0:
+            gap = self.now - req.last_token_t
+            self.gaps[gap] = self.gaps.get(gap, 0) + 1
+        req.last_token_t = self.now
+        self.tokens += 1
+
+    def record(self, mon) -> None:
+        """Into ``serving.tokens`` and ``serving.hist.itl_ms``: one
+        counter call, and one histogram call per DISTINCT gap."""
+        if self.tokens:
+            mon.counter("serving.tokens").increase(self.tokens)
+        hist = mon.histogram("serving.hist.itl_ms")
+        for gap, n in self.gaps.items():
+            hist.record(gap * 1e3, n)
 
 
 @jax.jit
@@ -405,6 +447,15 @@ def _merge_rows(dev, host, mask):
         m = mask.reshape((-1,) + (1,) * (d.ndim - 1))
         return jnp.where(m, h.astype(d.dtype), d)
     return jax.tree_util.tree_map(pick, dev, host)
+
+
+def _named(body, name: str):
+    """Name a traceable body before ``jax.jit``: the XLA program is
+    then ``jit_<name>`` on a profiler trace's ``XLA Modules`` line
+    (and in compile logs), so the decode program and each prefill
+    bucket can be told apart instead of all reading ``jit_body``."""
+    body.__name__ = body.__qualname__ = name
+    return body
 
 
 def _lint_armed() -> bool:
@@ -846,7 +897,9 @@ class Engine:
         fn = self._decode_fns.get(variant)
         if fn is not None:
             return fn
-        fn = jax.jit(self._decode_body(variant), donate_argnums=(1, 3))
+        fn = jax.jit(_named(self._decode_body(variant),
+                            f"serve_decode_{variant}"),
+                     donate_argnums=(1, 3))
         self._decode_fns[variant] = fn
         self._note_compile()
         return fn
@@ -903,7 +956,8 @@ class Engine:
         fn = self._multi_fns.get(k)
         if fn is not None:
             return fn
-        fn = jax.jit(self._multi_body(k), donate_argnums=(1, 3, 4))
+        fn = jax.jit(_named(self._multi_body(k), f"serve_multi_{k}"),
+                     donate_argnums=(1, 3, 4))
         self._multi_fns[k] = fn
         self._note_compile()
         return fn
@@ -968,7 +1022,9 @@ class Engine:
         fn = self._verify_fns.get(variant)
         if fn is not None:
             return fn
-        fn = jax.jit(self._verify_body(variant), donate_argnums=(1, 3))
+        fn = jax.jit(_named(self._verify_body(variant),
+                            f"serve_verify_{variant}"),
+                     donate_argnums=(1, 3))
         self._verify_fns[variant] = fn
         self._note_compile()
         return fn
@@ -1006,7 +1062,8 @@ class Engine:
         fn = self._prefill_fns.get(pb)
         if fn is not None:
             return fn
-        fn = jax.jit(self._prefill_body(), donate_argnums=(1,))
+        fn = jax.jit(_named(self._prefill_body(), f"serve_prefill_{pb}"),
+                     donate_argnums=(1,))
         self._prefill_fns[pb] = fn
         self._note_compile()
         return fn
@@ -1149,52 +1206,55 @@ class Engine:
         """Queue a prompt (1-D token ids, or a [1, s] Tensor/array) for
         generation under ``sampling_params``. Returns the request id;
         the request is admitted to a slot by a later ``step()``."""
-        params = sampling_params or SamplingParams()
-        if isinstance(params, dict):
-            params = SamplingParams(**params)
-        params.validate()
-        prompt = _normalize_prompt(ids)
-        # validate the whole lifetime's page demand UP FRONT, naming
-        # the request and the pages it needs — an oversized request
-        # must never get as far as a mid-prefill _page_slots failure
-        rid = self._next_id
-        need = len(prompt) + int(params.max_new_tokens)
-        cap = self.max_blocks * self.page_size - (self._lookahead - 1)
-        # chunked prefill pads only ONE slice at a time (and clips that
-        # padding at the block table), so capacity is bounded by the
-        # REAL tokens; monolithic prefill buckets the whole prompt up
-        # front and must reserve the padded length
-        chunk_cap = (need if self.max_prefill_tokens_per_step is not None
-                     else self._pbucket(need))
-        if chunk_cap > cap:
-            raise ValueError(
-                f"request {rid} needs {need} token slots (prompt "
-                f"{len(prompt)} + {params.max_new_tokens} new = "
-                f"{_ceil_div(self._pbucket(need), self.page_size)} "
-                f"pages), beyond the engine's max_context capacity "
-                f"{cap}")
-        worst_pages = self._lifetime_pages(len(prompt),
-                                           int(params.max_new_tokens))
-        if worst_pages > self.pool_pages:
-            raise RuntimeError(
-                f"request {rid} can never be scheduled: it needs up "
-                f"to {worst_pages} page(s) (prompt {len(prompt)} + "
-                f"{params.max_new_tokens} new tokens at page_size "
-                f"{self.page_size}) but the pool has "
-                f"{self.pool_pages} — grow pool_pages or shrink the "
-                f"request")
-        req = Request(req_id=self._next_id, prompt=prompt, params=params,
-                      arrival_t=self._clock(),
-                      queued_step=self._steps)
-        req.key = np.asarray(jax.random.PRNGKey(int(params.seed)),
-                             np.uint32)
-        self._next_id += 1
-        self.requests[req.req_id] = req    # LIVE requests only (see _finish)
-        self._waiting.append(req)
-        tracing.open_span(req.spans, tracing.QUEUED,
-                          req.arrival_t * 1e3, self.label)
-        self._mon.counter("serving.requests").increase()
-        return req.req_id
+        with RecordEvent("engine.add_request") as span:
+            params = sampling_params or SamplingParams()
+            if isinstance(params, dict):
+                params = SamplingParams(**params)
+            params.validate()
+            prompt = _normalize_prompt(ids)
+            # validate the whole lifetime's page demand UP FRONT, naming
+            # the request and the pages it needs — an oversized request
+            # must never get as far as a mid-prefill _page_slots failure
+            rid = self._next_id
+            need = len(prompt) + int(params.max_new_tokens)
+            cap = self.max_blocks * self.page_size - (self._lookahead - 1)
+            # chunked prefill pads only ONE slice at a time (and clips that
+            # padding at the block table), so capacity is bounded by the
+            # REAL tokens; monolithic prefill buckets the whole prompt up
+            # front and must reserve the padded length
+            chunk_cap = (need if self.max_prefill_tokens_per_step is not None
+                         else self._pbucket(need))
+            if chunk_cap > cap:
+                raise ValueError(
+                    f"request {rid} needs {need} token slots (prompt "
+                    f"{len(prompt)} + {params.max_new_tokens} new = "
+                    f"{_ceil_div(self._pbucket(need), self.page_size)} "
+                    f"pages), beyond the engine's max_context capacity "
+                    f"{cap}")
+            worst_pages = self._lifetime_pages(len(prompt),
+                                               int(params.max_new_tokens))
+            if worst_pages > self.pool_pages:
+                raise RuntimeError(
+                    f"request {rid} can never be scheduled: it needs up "
+                    f"to {worst_pages} page(s) (prompt {len(prompt)} + "
+                    f"{params.max_new_tokens} new tokens at page_size "
+                    f"{self.page_size}) but the pool has "
+                    f"{self.pool_pages} — grow pool_pages or shrink the "
+                    f"request")
+            span.set(req=rid, prompt_tokens=len(prompt))
+            req = Request(req_id=rid, prompt=prompt, params=params,
+                          arrival_t=self._clock(),
+                          queued_step=self._steps)
+            req.key = np.asarray(jax.random.PRNGKey(int(params.seed)),
+                                 np.uint32)
+            self._next_id += 1
+            # LIVE requests only (see _finish)
+            self.requests[req.req_id] = req
+            self._waiting.append(req)
+            tracing.open_span(req.spans, tracing.QUEUED,
+                              req.arrival_t * 1e3, self.label)
+            self._mon.counter("serving.requests").increase()
+            return req.req_id
 
     def step(self) -> List[Output]:
         """One scheduler tick, PIPELINED against the device (JAX async
@@ -1213,124 +1273,139 @@ class Engine:
         deadline / queue-timeout enforcement then lands on dispatch
         boundaries, so a request can overrun its deadline_ms by at
         most one dispatch (k ticks) before _expire retires it."""
-        outputs: List[Output] = []
-        wall0 = time.perf_counter()
-        clk0 = self._clock()
-        self._device_s = 0.0
-        self._overlap_s = 0.0
-        c0 = self._tracker.compiles
-        if self._moe_layer is not None and c0 != self._moe_tracker_mark:
-            # compiles landed OUTSIDE our steps since the last sync
-            # (a sibling worker's warmup in disagg/fleet, a one-shot
-            # generate): fold their kernels.moe.decode_path.* deltas
-            # into the baseline WITHOUT republishing — a foreign trace
-            # must never read as this engine's dispatch proof
-            self._moe_seen = {
-                k: int(v) for k, v in monitor.snapshot().items()
-                if k.startswith("kernels.moe.decode_path.")}
-            self._moe_tracker_mark = c0
-        if self._injector is not None:
-            self._injector.on_step(self._steps)
-            self._prefix_faults()
-        with tape_mod.no_grad_guard():
-            # (a) dispatch the decode executable for the slots settled
-            # by the LAST step — the device starts tick t now
-            pending = self._safe_decode()
-            # (b) overlap window: tick-t+1 host scheduling runs while
-            # the device executes. Exactness is order-insensitive here
-            # (rows are independent; a request admitted now joins the
-            # NEXT dispatch, exactly as the sequential loop's same-step
-            # admission joined the decode after its prefill), and a
-            # request _expire retires mid-flight has its in-flight
-            # token discarded at harvest — the same token the
-            # sequential loop (expire before decode) never produced.
-            outputs.extend(self._expire())
-            self._pf_step_tokens = 0
-            self._admit()
-            outputs.extend(self._run_prefills())
-            self._watchdog.maybe_start_and_tick()
-            # (c) sync + harvest: block on the dispatched outputs
-            # (attributed — host work above that hid under device
-            # execution lands in the overlap share), append tokens,
-            # retire finished rows
-            outputs.extend(self._decode_harvest(pending))
-            # (d) page growth for the NEXT dispatch (multi-tick
-            # horizon pre-allocates k ticks of headroom when free
-            # pages allow; preemption key reads are post-sync here)
-            self._ensure_pages()
-        if self._injector is not None and \
-                self._injector.fire("alloc.refcount_skew",
-                                    record=False):
-            # a stray reference lands on a live page (the lost-free /
-            # doubled-share failure mode) — the audit below must
-            # detect and repair it before it can become a leak;
-            # recorded only when a live page existed to skew
-            held = [p for r in self._slots if r is not None
-                    for p in r.pages]
-            if held:
-                self._injector.record("alloc.refcount_skew")
-                self._alloc.share(
-                    held[int(self._injector.rng.integers(0, len(held)))])
-        self._maybe_audit()
-        self._mon.counter("serving.steps").increase()
-        self._publish_gauges()
-        # MoE path proof (docs/OBSERVABILITY.md "serving.moe.*"): a
-        # tick that traced something re-publishes the trace-time
-        # kernels.moe.decode_path.* deltas into the serving namespace —
-        # in steady state (zero recompiles) this branch never runs, so
-        # the per-step cost is one int compare
-        if self._moe_layer is not None \
-                and self._tracker.compiles != c0:
-            self._republish_moe_paths()
-            self._moe_tracker_mark = self._tracker.compiles
-        # O(1) warmup accounting, attributed to THIS engine: only
-        # compiles that land inside this step() count (the jax
-        # listener is process-global — another engine or a generate()
-        # call between ticks must not read as our recompile), and a
-        # tick that introduced a new executable folds its compiles
-        # into warmup. (Not tracker.on_step(): its per-step list
-        # would grow one entry per tick forever in a serving process.)
-        self._compiles += self._tracker.compiles - c0
-        if self._last_compile_step == self._steps:
-            self._warm_compiles = self._compiles
-        # host/device tick attribution (ROADMAP item 5's gate input):
-        # device time is what the tick spent blocked on dispatched
-        # results PLUS the host work that provably ran while the
-        # device was still executing the in-flight dispatch (the
-        # pipelining overlap — _sync_timed's windowed accounting; the
-        # overlap share is also published on its own so the gate
-        # measures real EXPOSED host cost, never double-counted).
-        # Wall clock, never the injectable clock — timelines stay
-        # deterministic, attribution stays honest. One step = one
-        # dispatch: under multi_tick these are per-DISPATCH values
-        # covering `ticks` device ticks (the sums the bench host-share
-        # gate aggregates stay true trace totals).
-        wall_ms = (time.perf_counter() - wall0) * 1e3
-        dev_ms = min(self._device_s * 1e3, wall_ms)
-        host_ms = wall_ms - dev_ms
-        ov_ms = min(self._overlap_s * 1e3, dev_ms)
-        self._mon.gauge("serving.host_ms_per_tick").set(host_ms)
-        self._mon.gauge("serving.device_ms_per_tick").set(dev_ms)
-        self._mon.gauge("serving.overlap_ms_per_tick").set(ov_ms)
-        self._mon.histogram("serving.hist.host_ms_per_tick").record(
-            host_ms)
-        self._mon.histogram("serving.hist.device_ms_per_tick").record(
-            dev_ms)
-        self._mon.histogram("serving.hist.overlap_ms_per_tick").record(
-            ov_ms)
-        self._mon.histogram("serving.hist.tick_ms").record(wall_ms)
-        if pending is not None:
-            if self.multi_tick > 1:
-                self._mon.gauge(
-                    "serving.multi_tick.ticks_per_dispatch").set(
-                        pending.ticks)
-            # per-device-tick duration EWMA on the INJECTABLE clock —
-            # the deadline clamp's horizon unit (_deadline_ticks)
-            d_ms = (self._clock() - clk0) * 1e3 / max(1, pending.ticks)
-            self._tick_est_ms = d_ms if self._tick_est_ms <= 0.0 \
-                else 0.7 * self._tick_est_ms + 0.3 * d_ms
-        self._steps += 1
-        return outputs
+        with RecordEvent("engine.step", step=self._steps,
+                         active=self.num_active,
+                         waiting=self.num_waiting,
+                         prefilling=self.num_prefilling):
+            outputs: List[Output] = []
+            wall0 = time.perf_counter()
+            clk0 = self._clock()
+            self._device_s = 0.0
+            self._overlap_s = 0.0
+            c0 = self._tracker.compiles
+            if self._moe_layer is not None and c0 != self._moe_tracker_mark:
+                # compiles landed OUTSIDE our steps since the last sync
+                # (a sibling worker's warmup in disagg/fleet, a one-shot
+                # generate): fold their kernels.moe.decode_path.* deltas
+                # into the baseline WITHOUT republishing — a foreign trace
+                # must never read as this engine's dispatch proof
+                self._moe_seen = {
+                    k: int(v) for k, v in monitor.snapshot().items()
+                    if k.startswith("kernels.moe.decode_path.")}
+                self._moe_tracker_mark = c0
+            if self._injector is not None:
+                self._injector.on_step(self._steps)
+                self._prefix_faults()
+            with tape_mod.no_grad_guard():
+                # (a) dispatch the decode executable for the slots settled
+                # by the LAST step — the device starts tick t now
+                with RecordEvent("engine.decode.dispatch") as span:
+                    pending = self._safe_decode()
+                    if pending is not None:
+                        span.set(
+                            variant=pending.variant,
+                            slots=len(pending.active),
+                            ctx_tokens=int(sum(
+                                self._pos[i] for i, _ in pending.active)),
+                            ticks=pending.ticks)
+                # (b) overlap window: tick-t+1 host scheduling runs while
+                # the device executes. Exactness is order-insensitive here
+                # (rows are independent; a request admitted now joins the
+                # NEXT dispatch, exactly as the sequential loop's same-step
+                # admission joined the decode after its prefill), and a
+                # request _expire retires mid-flight has its in-flight
+                # token discarded at harvest — the same token the
+                # sequential loop (expire before decode) never produced.
+                with RecordEvent("engine.expire"):
+                    outputs.extend(self._expire())
+                self._pf_step_tokens = 0
+                with RecordEvent("engine.admit") as span:
+                    span.set(admitted=len(self._admit()))
+                outputs.extend(self._run_prefills())
+                self._watchdog.maybe_start_and_tick()
+                # (c) sync + harvest: block on the dispatched outputs
+                # (attributed — host work above that hid under device
+                # execution lands in the overlap share), append tokens,
+                # retire finished rows
+                outputs.extend(self._decode_harvest(pending))
+                # (d) page growth for the NEXT dispatch (multi-tick
+                # horizon pre-allocates k ticks of headroom when free
+                # pages allow; preemption key reads are post-sync here)
+                self._ensure_pages()
+            if self._injector is not None and \
+                    self._injector.fire("alloc.refcount_skew",
+                                        record=False):
+                # a stray reference lands on a live page (the lost-free /
+                # doubled-share failure mode) — the audit below must
+                # detect and repair it before it can become a leak;
+                # recorded only when a live page existed to skew
+                held = [p for r in self._slots if r is not None
+                        for p in r.pages]
+                if held:
+                    self._injector.record("alloc.refcount_skew")
+                    self._alloc.share(
+                        held[int(self._injector.rng.integers(0, len(held)))])
+            with RecordEvent("engine.bookkeeping"):
+                self._maybe_audit()
+                self._mon.counter("serving.steps").increase()
+                self._publish_gauges()
+                # MoE path proof (docs/OBSERVABILITY.md "serving.moe.*"): a
+                # tick that traced something re-publishes the trace-time
+                # kernels.moe.decode_path.* deltas into the serving
+                # namespace — in steady state (zero recompiles) this branch
+                # never runs, so the per-step cost is one int compare
+                if self._moe_layer is not None \
+                        and self._tracker.compiles != c0:
+                    self._republish_moe_paths()
+                    self._moe_tracker_mark = self._tracker.compiles
+                # O(1) warmup accounting, attributed to THIS engine: only
+                # compiles that land inside this step() count (the jax
+                # listener is process-global — another engine or a generate()
+                # call between ticks must not read as our recompile), and a
+                # tick that introduced a new executable folds its compiles
+                # into warmup. (Not tracker.on_step(): its per-step list
+                # would grow one entry per tick forever in a serving process.)
+                self._compiles += self._tracker.compiles - c0
+                if self._last_compile_step == self._steps:
+                    self._warm_compiles = self._compiles
+                # host/device tick attribution (ROADMAP item 5's gate input):
+                # device time is what the tick spent blocked on dispatched
+                # results PLUS the host work that provably ran while the
+                # device was still executing the in-flight dispatch (the
+                # pipelining overlap — _sync_timed's windowed accounting; the
+                # overlap share is also published on its own so the gate
+                # measures real EXPOSED host cost, never double-counted).
+                # Wall clock, never the injectable clock — timelines stay
+                # deterministic, attribution stays honest. One step = one
+                # dispatch: under multi_tick these are per-DISPATCH values
+                # covering `ticks` device ticks (the sums the bench host-share
+                # gate aggregates stay true trace totals).
+                wall_ms = (time.perf_counter() - wall0) * 1e3
+                dev_ms = min(self._device_s * 1e3, wall_ms)
+                host_ms = wall_ms - dev_ms
+                ov_ms = min(self._overlap_s * 1e3, dev_ms)
+                self._mon.gauge("serving.host_ms_per_tick").set(host_ms)
+                self._mon.gauge("serving.device_ms_per_tick").set(dev_ms)
+                self._mon.gauge("serving.overlap_ms_per_tick").set(ov_ms)
+                self._mon.histogram("serving.hist.host_ms_per_tick").record(
+                    host_ms)
+                self._mon.histogram("serving.hist.device_ms_per_tick").record(
+                    dev_ms)
+                self._mon.histogram("serving.hist.overlap_ms_per_tick").record(
+                    ov_ms)
+                self._mon.histogram("serving.hist.tick_ms").record(wall_ms)
+                if pending is not None:
+                    if self.multi_tick > 1:
+                        self._mon.gauge(
+                            "serving.multi_tick.ticks_per_dispatch").set(
+                                pending.ticks)
+                    # per-device-tick duration EWMA on the INJECTABLE clock —
+                    # the deadline clamp's horizon unit (_deadline_ticks)
+                    d_ms = (self._clock() - clk0) * 1e3 / max(1, pending.ticks)
+                    self._tick_est_ms = d_ms if self._tick_est_ms <= 0.0 \
+                        else 0.7 * self._tick_est_ms + 0.3 * d_ms
+                self._steps += 1
+                return outputs
 
     def run(self, requests: Sequence, max_steps: int = 100_000,
             heartbeat_timeout: Optional[float] = None,
@@ -1439,6 +1514,7 @@ class Engine:
         # continues exactly (WAITING when no token was emitted yet —
         # no rng was consumed, a from-scratch prefill is exact)
         req.state = PREEMPTED if req.generated else WAITING
+        req.last_token_t = 0.0      # no token gap spans a migration
         # the extraction IS the migration's start: the open span
         # (DECODE/PREFILL/QUEUED) closes here and MIGRATING runs until
         # the destination engine's next span — origin stays the SOURCE
@@ -1944,131 +2020,135 @@ class Engine:
         multi-token paged path gathers the cache it just wrote), so a
         sliced prefix produces bit-identical cache contents and first
         tokens — under any cache_dtype."""
-        toks = req.resume_tokens()
-        fresh = not req.generated
-        P = len(toks)
-        if not req.pages:
-            # first chunk: the shared prefix pages acquired at
-            # admission land in the block table now; every page the
-            # request writes from here on is private
-            req.pages = list(req.shared_pages or [])
-            req.written = req.prefix_len   # page-aligned by construction
-        start = req.written
-        T = P - start
-        if self.max_prefill_tokens_per_step is not None:
-            limit = self.max_prefill_tokens_per_step
-            if cap is not None:
-                # the scheduler's remaining step budget, floored at one
-                # bucket so a scheduled request always makes progress
-                limit = min(limit, max(self.prefill_bucket, int(cap)))
-            T = min(T, limit)
-        final = start + T >= P
-        # bucket the chunk, but never past the block table: a deep
-        # cached prefix (or a near-max_context prompt) leaves less than
-        # one full bucket of room, and the padding positions would
-        # overflow the [1, max_blocks] row (add_request guarantees the
-        # REAL tokens always fit, so clipping only ever drops padding).
-        pb = min(self._pbucket(T),
-                 self.max_blocks * self.page_size - start)
-        # allocate pages for REAL tokens only: block-table rows beyond
-        # them stay 0, so the chunk's bucket-padding writes land in the
-        # shared scratch page (the masked-lane convention) instead of
-        # transiently holding pool pages that would be trimmed right
-        # back — the request's peak page demand never exceeds its real
-        # token count, which is what _lifetime_pages charges
-        need = _ceil_div(start + T, self.page_size) - len(req.pages)
-        if self._fault("alloc.exhausted"):
-            # simulated admission race / fragmented pool: surfaces as
-            # pool pressure, which _safe_prefill turns into a clean
-            # budget-free requeue-and-retry
-            raise PoolPressure(
-                f"injected pool exhaustion: sequence {req.req_id} "
-                f"requested {need} page(s)")
-        if need > 0:
-            try:
-                priv = self._alloc.alloc(need, seq=req.req_id)
-            except RuntimeError:
-                # admission charged only the first slice (or a test may
-                # drive _prefill directly): reclaim idle cached pages,
-                # then surface ANY remaining shortfall as backpressure
-                # (a partial evict must not turn into a retry-budget-
-                # burning RuntimeError)
-                if self._prefix is not None:
-                    self._prefix.evict(need)
+        with RecordEvent("engine.prefill", req=req.req_id) as span:
+            toks = req.resume_tokens()
+            fresh = not req.generated
+            P = len(toks)
+            if not req.pages:
+                # first chunk: the shared prefix pages acquired at
+                # admission land in the block table now; every page the
+                # request writes from here on is private
+                req.pages = list(req.shared_pages or [])
+                req.written = req.prefix_len   # page-aligned by construction
+            start = req.written
+            T = P - start
+            if self.max_prefill_tokens_per_step is not None:
+                limit = self.max_prefill_tokens_per_step
+                if cap is not None:
+                    # the scheduler's remaining step budget, floored at one
+                    # bucket so a scheduled request always makes progress
+                    limit = min(limit, max(self.prefill_bucket, int(cap)))
+                T = min(T, limit)
+            final = start + T >= P
+            # bucket the chunk, but never past the block table: a deep
+            # cached prefix (or a near-max_context prompt) leaves less than
+            # one full bucket of room, and the padding positions would
+            # overflow the [1, max_blocks] row (add_request guarantees the
+            # REAL tokens always fit, so clipping only ever drops padding).
+            pb = min(self._pbucket(T),
+                     self.max_blocks * self.page_size - start)
+            span.set(bucket=pb, tokens=T, start=start, final=int(final))
+            # allocate pages for REAL tokens only: block-table rows beyond
+            # them stay 0, so the chunk's bucket-padding writes land in the
+            # shared scratch page (the masked-lane convention) instead of
+            # transiently holding pool pages that would be trimmed right
+            # back — the request's peak page demand never exceeds its real
+            # token count, which is what _lifetime_pages charges
+            need = _ceil_div(start + T, self.page_size) - len(req.pages)
+            if self._fault("alloc.exhausted"):
+                # simulated admission race / fragmented pool: surfaces as
+                # pool pressure, which _safe_prefill turns into a clean
+                # budget-free requeue-and-retry
+                raise PoolPressure(
+                    f"injected pool exhaustion: sequence {req.req_id} "
+                    f"requested {need} page(s)")
+            if need > 0:
                 try:
                     priv = self._alloc.alloc(need, seq=req.req_id)
-                except RuntimeError as e2:
-                    raise PoolPressure(str(e2)) from e2
-            req.pages = req.pages + priv
-        bt_row = np.zeros((1, self.max_blocks), np.int32)
-        bt_row[0, :len(req.pages)] = req.pages
-        prompt = np.zeros((1, pb), np.int32)
-        prompt[0, :T] = toks[start:start + T]
-        p = req.params
-        # one timeline span per slice: the QUEUED (or PREEMPTED /
-        # MIGRATING) wait closes here, consecutive slices chain
-        self._open_span(req, tracing.PREFILL, slot=req.slot,
-                        start=int(start), tokens=int(T))
-        fn = self._get_prefill_fn(pb)
-        bt_dev = jnp.asarray(bt_row)
-        prompt_dev = jnp.asarray(prompt)
-        start_dev = jnp.asarray([start], jnp.int32)
-        self._fault_raise("prefill.device_error")
-        poison = jnp.asarray(
-            [float("nan") if self._fault("prefill.nan") else 0.0],
-            jnp.float32)
-        # windowed device attribution, same as the decode dispatches:
-        # the chunk's dispatch→ready span is device-busy even on a
-        # client whose dispatch call runs the computation inline —
-        # without the window the whole prefill forward would read as
-        # HOST time in the host-share gate
-        mark = self._device_s
-        t0 = time.perf_counter()
-        tok, key2, okf, self._pools = fn(
-            self._st, self._pools, bt_dev, prompt_dev,
-            jnp.asarray([T], jnp.int32), start_dev,
-            jnp.asarray([p.temperature], jnp.float32),
-            jnp.asarray([p.top_k], jnp.int32),
-            jnp.asarray([p.top_p], jnp.float32),
-            jnp.asarray(req.key[None]), poison)
-        if self._spec is not None:
-            # mirror the chunk into the draft pools (same pages, same
-            # positions) so drafting attends the full context
-            self._spec.prefill(pb, bt_dev, prompt_dev, start_dev)
-        # key2 rides in the sync set: the fresh-request path below
-        # reads it (np.asarray) and an unsynced fetch would be an
-        # un-attributed host sync (hotpath.host-sync-in-tick)
-        self._sync_timed((tok, key2, okf), dispatch_t=t0, dev_mark=mark)
-        self._mon.counter("serving.prefill_tokens").increase(pb)
-        self._mon.counter("serving.prefill_slices").increase()
-        self._pf_step_tokens += pb
-        if start == req.prefix_len:
-            monitor.counter(
-                "serving.prefix_tokens_reused").increase(start)
-        if not bool(np.asarray(okf)[0]):
-            # NaN/inf on the chunk's sampling logits: quarantine the
-            # request (pages freed, nothing enters the prefix cache)
-            # — the other slots never see it
-            self._mon.counter("serving.nan_quarantines").increase()
-            return self._fail(req, "nan_logits")
-        req.written = start + T
-        if not final:
-            return None       # stays PREFILL; a later tick continues
-        if self._prefix is not None:
-            # register this prefix's full pages (newly computed chunks
-            # only; chunks matched at admission are already cached)
-            self._prefix.insert(toks, req.pages, P)
-        if fresh:
-            t = int(np.asarray(tok)[0])
-            req.key = np.asarray(key2)[0].astype(np.uint32)
-            req.generated.append(t)
-            req.first_token_t = self._clock()
-            self._mon.counter("serving.tokens").increase()
-            reason = self._finish_reason(req, t)
-            if reason:
-                return self._finish(req, reason)
-        self._activate(req)
-        return None
+                except RuntimeError:
+                    # admission charged only the first slice (or a test may
+                    # drive _prefill directly): reclaim idle cached pages,
+                    # then surface ANY remaining shortfall as backpressure
+                    # (a partial evict must not turn into a retry-budget-
+                    # burning RuntimeError)
+                    if self._prefix is not None:
+                        self._prefix.evict(need)
+                    try:
+                        priv = self._alloc.alloc(need, seq=req.req_id)
+                    except RuntimeError as e2:
+                        raise PoolPressure(str(e2)) from e2
+                req.pages = req.pages + priv
+            bt_row = np.zeros((1, self.max_blocks), np.int32)
+            bt_row[0, :len(req.pages)] = req.pages
+            prompt = np.zeros((1, pb), np.int32)
+            prompt[0, :T] = toks[start:start + T]
+            p = req.params
+            # one timeline span per slice: the QUEUED (or PREEMPTED /
+            # MIGRATING) wait closes here, consecutive slices chain
+            self._open_span(req, tracing.PREFILL, slot=req.slot,
+                            start=int(start), tokens=int(T))
+            fn = self._get_prefill_fn(pb)
+            bt_dev = jnp.asarray(bt_row)
+            prompt_dev = jnp.asarray(prompt)
+            start_dev = jnp.asarray([start], jnp.int32)
+            self._fault_raise("prefill.device_error")
+            poison = jnp.asarray(
+                [float("nan") if self._fault("prefill.nan") else 0.0],
+                jnp.float32)
+            # windowed device attribution, same as the decode dispatches:
+            # the chunk's dispatch→ready span is device-busy even on a
+            # client whose dispatch call runs the computation inline —
+            # without the window the whole prefill forward would read as
+            # HOST time in the host-share gate
+            mark = self._device_s
+            t0 = time.perf_counter()
+            tok, key2, okf, self._pools = fn(
+                self._st, self._pools, bt_dev, prompt_dev,
+                jnp.asarray([T], jnp.int32), start_dev,
+                jnp.asarray([p.temperature], jnp.float32),
+                jnp.asarray([p.top_k], jnp.int32),
+                jnp.asarray([p.top_p], jnp.float32),
+                jnp.asarray(req.key[None]), poison)
+            if self._spec is not None:
+                # mirror the chunk into the draft pools (same pages, same
+                # positions) so drafting attends the full context
+                self._spec.prefill(pb, bt_dev, prompt_dev, start_dev)
+            # key2 rides in the sync set: the fresh-request path below
+            # reads it (np.asarray) and an unsynced fetch would be an
+            # un-attributed host sync (hotpath.host-sync-in-tick)
+            with RecordEvent("engine.prefill.wait"):
+                self._sync_timed((tok, key2, okf), dispatch_t=t0,
+                                 dev_mark=mark)
+            self._mon.counter("serving.prefill_tokens").increase(pb)
+            self._mon.counter("serving.prefill_slices").increase()
+            self._pf_step_tokens += pb
+            if start == req.prefix_len:
+                monitor.counter(
+                    "serving.prefix_tokens_reused").increase(start)
+            if not bool(np.asarray(okf)[0]):
+                # NaN/inf on the chunk's sampling logits: quarantine the
+                # request (pages freed, nothing enters the prefix cache)
+                # — the other slots never see it
+                self._mon.counter("serving.nan_quarantines").increase()
+                return self._fail(req, "nan_logits")
+            req.written = start + T
+            if not final:
+                return None       # stays PREFILL; a later tick continues
+            if self._prefix is not None:
+                # register this prefix's full pages (newly computed chunks
+                # only; chunks matched at admission are already cached)
+                self._prefix.insert(toks, req.pages, P)
+            if fresh:
+                t = int(np.asarray(tok)[0])
+                req.key = np.asarray(key2)[0].astype(np.uint32)
+                req.generated.append(t)
+                req.first_token_t = req.last_token_t = self._clock()
+                self._mon.counter("serving.tokens").increase()
+                reason = self._finish_reason(req, t)
+                if reason:
+                    return self._finish(req, reason)
+            self._activate(req)
+            return None
 
     def _activate(self, req: Request):
         i = req.slot
@@ -2102,36 +2182,44 @@ class Engine:
         — but only from FREE pages (no eviction, no preemption): a
         short coverage just clamps the fused k, it never costs another
         request its cache."""
-        for i in range(self.max_slots):
-            req = self._slots[i]
-            if req is None or req.state != DECODE:
-                continue
-            need = _ceil_div(req.written + self._lookahead,
-                             self.page_size)
-            while len(req.pages) < need:
-                page = self._alloc_or_preempt(req)
-                if page is None:      # req itself got preempted
-                    break
-                req.pages.extend(page)
-                self._bt[i, :len(req.pages)] = req.pages
-                self._bt_dirty = True
-        if self.multi_tick > 1 and self._spec is None:
+        with RecordEvent("engine.ensure_pages") as span:
+            allocated = 0
+            # a preemption is the only thing here that queues a request
+            waiting0 = len(self._waiting)
             for i in range(self.max_slots):
                 req = self._slots[i]
                 if req is None or req.state != DECODE:
                     continue
-                rem = int(req.params.max_new_tokens) \
-                    - len(req.generated)
-                want = _ceil_div(
-                    req.written + min(max(rem, 1), self.multi_tick),
-                    self.page_size)
-                while len(req.pages) < want \
-                        and self._alloc.can_alloc(1,
-                                                  self.watermark_pages):
-                    req.pages.extend(
-                        self._alloc.alloc(1, seq=req.req_id))
+                need = _ceil_div(req.written + self._lookahead,
+                                 self.page_size)
+                while len(req.pages) < need:
+                    page = self._alloc_or_preempt(req)
+                    if page is None:      # req itself got preempted
+                        break
+                    req.pages.extend(page)
+                    allocated += len(page)
                     self._bt[i, :len(req.pages)] = req.pages
                     self._bt_dirty = True
+            if self.multi_tick > 1 and self._spec is None:
+                for i in range(self.max_slots):
+                    req = self._slots[i]
+                    if req is None or req.state != DECODE:
+                        continue
+                    rem = int(req.params.max_new_tokens) \
+                        - len(req.generated)
+                    want = _ceil_div(
+                        req.written + min(max(rem, 1), self.multi_tick),
+                        self.page_size)
+                    while len(req.pages) < want and \
+                            self._alloc.can_alloc(
+                                1, self.watermark_pages):
+                        req.pages.extend(
+                            self._alloc.alloc(1, seq=req.req_id))
+                        allocated += 1
+                        self._bt[i, :len(req.pages)] = req.pages
+                        self._bt_dirty = True
+            span.set(allocated=allocated,
+                     preempted=len(self._waiting) - waiting0)
 
     def _alloc_or_preempt(self, req: Request):
         while True:
@@ -2196,19 +2284,22 @@ class Engine:
         finishes) plus the block table when a sequence crossed a page
         boundary. A steady-state decode tick — no scheduling events,
         no page growth — uploads NOTHING."""
-        if self._dirty:
-            mask = np.zeros((self.max_slots,), bool)
-            mask[list(self._dirty)] = True
-            host = (self._up(self._last), self._up(self._pos),
-                    self._up(self._temps),
-                    self._up(self._topks),
-                    self._up(self._topps), self._up(self._keys),
-                    self._up(self._live))
-            self._dev = _merge_rows(self._dev, host, self._up(mask))
-            self._dirty.clear()
-        if self._bt_dirty:
-            self._bt_dev = self._up(self._bt)
-            self._bt_dirty = False
+        with RecordEvent("engine.flush_state", rows=len(self._dirty),
+                         block_table=int(self._bt_dirty)):
+            if self._dirty:
+                mask = np.zeros((self.max_slots,), bool)
+                mask[list(self._dirty)] = True
+                host = (self._up(self._last), self._up(self._pos),
+                        self._up(self._temps),
+                        self._up(self._topks),
+                        self._up(self._topps), self._up(self._keys),
+                        self._up(self._live))
+                self._dev = _merge_rows(self._dev, host,
+                                        self._up(mask))
+                self._dirty.clear()
+            if self._bt_dirty:
+                self._bt_dev = self._up(self._bt)
+                self._bt_dirty = False
 
     def _decode_dispatch(self) -> Optional[_PendingTick]:
         """Dispatch this step's decode work and return WITHOUT
@@ -2265,7 +2356,7 @@ class Engine:
         self._unpoison()
         return _PendingTick(kind="single", data=(nxt, okv),
                             active=snap, ticks=1, t_dispatch=t0,
-                            dev_mark=mark)
+                            dev_mark=mark, variant=variant)
 
     def _multi_k(self, active: List[int], variant: str) -> int:
         """Eligibility ladder + per-dispatch clamp for the fused
@@ -2398,15 +2489,21 @@ class Engine:
         the sequential expire-before-decode order produced."""
         if pend is None:
             return []
-        self._sync_timed(pend.data, dispatch_t=pend.t_dispatch,
-                         dev_mark=pend.dev_mark)
-        if pend.kind == "multi":
-            return self._harvest_multi(pend)
-        if pend.kind == "spec":
-            return self._harvest_spec(pend)
-        return self._harvest_single(pend)
+        with RecordEvent("engine.decode.wait"):
+            self._sync_timed(pend.data, dispatch_t=pend.t_dispatch,
+                             dev_mark=pend.dev_mark)
+        harvest = {"multi": self._harvest_multi,
+                   "spec": self._harvest_spec}.get(
+                       pend.kind, self._harvest_single)
+        with RecordEvent("engine.harvest") as span:
+            emitted = _Emitted(self._clock())
+            outs = harvest(pend, emitted)
+            emitted.record(self._mon)
+            span.set(tokens=emitted.tokens, finished=len(outs))
+        return outs
 
-    def _harvest_single(self, pend: _PendingTick) -> List[Output]:
+    def _harvest_single(self, pend: _PendingTick,
+                        emitted: _Emitted) -> List[Output]:
         nxt = np.asarray(pend.data[0])
         okv = np.asarray(pend.data[1])
         # tokens appended here move budgets the device-resident
@@ -2429,17 +2526,15 @@ class Engine:
             # device already holds these values; the mirrors keep the
             # scheduler's view coherent for later dirty merges)
             self._pos[i] = req.written
-            req.generated.append(tok)
+            emitted.append(req, tok)
             self._last[i] = tok
-            if req.first_token_t == 0.0:
-                req.first_token_t = self._clock()
-            self._mon.counter("serving.tokens").increase()
             reason = self._finish_reason(req, tok)
             if reason:
                 outs.append(self._finish(req, reason))
         return outs
 
-    def _harvest_multi(self, pend: _PendingTick) -> List[Output]:
+    def _harvest_multi(self, pend: _PendingTick,
+                       emitted: _Emitted) -> List[Output]:
         """Walk the fused dispatch's [S, k] token/ok matrices exactly
         as k single-tick harvests would: append until the row's eos or
         length exit (the same condition that froze it in-graph — the
@@ -2469,11 +2564,8 @@ class Engine:
                 tok = int(toks[i, j])
                 req.written += 1
                 self._pos[i] = req.written
-                req.generated.append(tok)
+                emitted.append(req, tok)
                 self._last[i] = tok
-                if req.first_token_t == 0.0:
-                    req.first_token_t = self._clock()
-                self._mon.counter("serving.tokens").increase()
                 reason = self._finish_reason(req, tok)
                 if reason:
                     self._mon.counter(
@@ -2544,9 +2636,10 @@ class Engine:
         self._unpoison()
         return _PendingTick(kind="spec", data=(toks, acc, okv),
                             active=snap, ticks=1, t_dispatch=t0,
-                            dev_mark=mark, k=k)
+                            dev_mark=mark, k=k, variant=variant)
 
-    def _harvest_spec(self, pend: _PendingTick) -> List[Output]:
+    def _harvest_spec(self, pend: _PendingTick,
+                      emitted: _Emitted) -> List[Output]:
         toks = np.asarray(pend.data[0])
         acc = np.asarray(pend.data[1])
         okv = np.asarray(pend.data[2])
@@ -2574,10 +2667,7 @@ class Engine:
             for j in range(n_acc + 1):
                 tok = int(toks[i, j])
                 req.written += 1      # position pos+j held this input
-                req.generated.append(tok)
-                if req.first_token_t == 0.0:
-                    req.first_token_t = self._clock()
-                self._mon.counter("serving.tokens").increase()
+                emitted.append(req, tok)
                 reason = self._finish_reason(req, tok)
                 if reason:
                     # mid-chain eos/budget: the tail of the chain is

@@ -25,6 +25,7 @@ from ..core import random as random_mod
 from ..core import tape as tape_mod
 from ..core.dispatch import run_op, unwrap, wrap
 from ..core.tensor import Tensor
+from ..profiler.profiler import RecordEvent
 from .functional import (functional_call, get_buffers, get_frozen,
                          get_params, write_back)
 
@@ -617,33 +618,38 @@ class TrainStep:
             jax.ShapeDtypeStruct((), jnp.float32), *data)
 
     def __call__(self, inputs, labels):
-        if not isinstance(inputs, (list, tuple)):
-            inputs = (inputs,)
-        in_arrays = tuple(unwrap(x) for x in inputs)
-        lab_arrays = jax.tree_util.tree_map(
-            lambda t: unwrap(t), labels,
-            is_leaf=lambda t: isinstance(t, Tensor))
-        # label leaves are part of the executable's signature too: a
-        # label shape/dtype change must not silently reuse (and retrace
-        # under) the executable cached for the old labels
-        sig = (self._leaf_sig(in_arrays), self._leaf_sig(lab_arrays))
-        fn = self._compiled.get(sig)
-        if fn is None:
-            fn = self._make_step()
-            self._compiled[sig] = fn
-            from ..analysis import lint_on_first_compile
-            lint_on_first_compile(self.inspect, inputs, labels)
-        key = random_mod.next_key()
-        lr = jnp.asarray(self._opt.get_lr(), jnp.float32)
-        self._params, self._buffers, self._opt_state, loss = fn(
-            self._params, self._buffers, self._frozen, self._opt_state, key,
-            lr, in_arrays, lab_arrays)
+        # three host phases, named on the profiler's clock (inert when
+        # nothing records): what the host does around the one program
+        with RecordEvent("trainstep.prepare"):
+            if not isinstance(inputs, (list, tuple)):
+                inputs = (inputs,)
+            in_arrays = tuple(unwrap(x) for x in inputs)
+            lab_arrays = jax.tree_util.tree_map(
+                lambda t: unwrap(t), labels,
+                is_leaf=lambda t: isinstance(t, Tensor))
+            # label leaves are part of the executable's signature too: a
+            # label shape/dtype change must not silently reuse (and
+            # retrace under) the executable cached for the old labels
+            sig = (self._leaf_sig(in_arrays), self._leaf_sig(lab_arrays))
+            fn = self._compiled.get(sig)
+            if fn is None:
+                fn = self._make_step()
+                self._compiled[sig] = fn
+                from ..analysis import lint_on_first_compile
+                lint_on_first_compile(self.inspect, inputs, labels)
+            key = random_mod.next_key()
+            lr = jnp.asarray(self._opt.get_lr(), jnp.float32)
+        with RecordEvent("trainstep.dispatch"):
+            self._params, self._buffers, self._opt_state, loss = fn(
+                self._params, self._buffers, self._frozen,
+                self._opt_state, key, lr, in_arrays, lab_arrays)
         # re-point the Layer's tensors at the fresh outputs (reference
         # swap, no copies) — the donated inputs they held are now deleted,
         # and any eager read (state_dict/checkpoint/print) must see live
         # arrays without an explicit sync_to_model call
-        write_back(self._model, self._params, self._buffers,
-                   registry=self._registry)
+        with RecordEvent("trainstep.write_back"):
+            write_back(self._model, self._params, self._buffers,
+                       registry=self._registry)
         from ..distributed import watchdog
         watchdog.maybe_start_and_tick()
         return wrap(loss)

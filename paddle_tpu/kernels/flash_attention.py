@@ -358,6 +358,7 @@ def _flash_pallas_fwd(q, k, v, causal, scale, interpret=False,
             out_specs=out_specs,
             out_shape=out_shape,
             interpret=interpret,
+            name="flash_fwd",
         )(*inputs)
     if want_lse:
         out, lse = res
@@ -562,6 +563,7 @@ def _flash_pallas_bwd(q, k, v, do, lse, delta, causal, scale,
             out_specs=pl.BlockSpec((None, bq, d), lambda i, j: (i, j, 0)),
             out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
             interpret=interpret,
+            name="flash_dq",
         )(*dq_inputs)
 
     dkv_kernel = functools.partial(
@@ -601,6 +603,7 @@ def _flash_pallas_bwd(q, k, v, do, lse, delta, causal, scale,
                 jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
             ],
             interpret=interpret,
+            name="flash_dkv",
         )(*dkv_inputs)
     dq = dq.reshape(b, h, sq, d)
     if h_kv != h:

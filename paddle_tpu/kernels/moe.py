@@ -310,6 +310,7 @@ def _grouped_ffn_fwd_pallas(x, w1, b1, w2, b2, ws, counts, activation,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary")),
             interpret=interpret,
+            name="moe_ffn_fwd",
         )(jnp.asarray(counts, jnp.int32), jnp.zeros((1,), jnp.int32),
           jnp.zeros((1,), jnp.int32), x, b1, b2, ws, w1, w2)
 
@@ -575,6 +576,7 @@ def _grouped_ffn_bwd_pallas(x, w1, b1, w2, b2, ws, counts, g, activation,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary")),
             interpret=interpret,
+            name="moe_ffn_dx",
         )(counts, jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
           x, g, b1, b2, ws, w1, w2)
 
@@ -620,6 +622,7 @@ def _grouped_ffn_bwd_pallas(x, w1, b1, w2, b2, ws, counts, g, activation,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary")),
             interpret=interpret,
+            name="moe_ffn_dw",
         )(counts, x, gw, w1, w2, b1)
     return dx, dw1, db1, dw2, db2.astype(b2.dtype), dws
 

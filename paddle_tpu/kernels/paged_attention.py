@@ -573,6 +573,7 @@ def paged_decode_pallas(q, k_cache, v_cache, block_tables, context_lens,
                 dimension_semantics=("arbitrary", "arbitrary",
                                      "arbitrary")),
             interpret=interpret,
+            name="paged_decode",
         )(bt, cl, jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
           *inputs)
     return out.reshape(b, h, d)

@@ -47,7 +47,7 @@ def build_trace(profiler, worker_name: Optional[str] = None) -> dict:
 
     # one common origin so user events, op spans, and memory counters
     # line up; chrome-trace wants microseconds
-    starts = ([s for _, s, _ in store_events] + [s for _, s, _ in spans]
+    starts = ([s for _, s, _, _ in store_events] + [s for _, s, _ in spans]
               + [m["t"] for m in mem if "t" in m])
     t0 = min(starts) if starts else 0.0
 
@@ -65,10 +65,13 @@ def build_trace(profiler, worker_name: Optional[str] = None) -> dict:
         {"ph": "M", "name": "thread_name", "pid": pid,
          "tid": TID_DISPATCH, "args": {"name": "op dispatch"}},
     ]
-    for ev_name, s, e in store_events:
-        events.append({"ph": "X", "cat": "user", "name": ev_name,
-                       "pid": pid, "tid": TID_USER, "ts": us(s),
-                       "dur": round((e - s) * 1e6, 3)})
+    for ev_name, s, e, ev_args in store_events:
+        ev = {"ph": "X", "cat": "user", "name": ev_name,
+              "pid": pid, "tid": TID_USER, "ts": us(s),
+              "dur": round((e - s) * 1e6, 3)}
+        if ev_args:
+            ev["args"] = ev_args
+        events.append(ev)
     for op_name, s, e in spans:
         events.append({"ph": "X", "cat": "op", "name": op_name,
                        "pid": pid, "tid": TID_DISPATCH, "ts": us(s),

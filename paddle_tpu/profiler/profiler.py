@@ -7,6 +7,8 @@ import time
 from collections import defaultdict
 from typing import Callable, Iterable, Optional
 
+import jax
+
 
 class ProfilerState(enum.Enum):
     CLOSED = 0
@@ -54,14 +56,14 @@ class _HostEventStore:
     """In-process host event aggregation (reference host_tracer role)."""
 
     def __init__(self):
-        self.events = []  # (name, start, end)
+        self.events = []  # (name, start, end, args)
 
-    def add(self, name, start, end):
-        self.events.append((name, start, end))
+    def add(self, name, start, end, args=None):
+        self.events.append((name, start, end, dict(args or ())))
 
     def aggregate(self):
         agg = defaultdict(lambda: [0, 0.0, float("inf"), 0.0])
-        for name, s, e in self.events:
+        for name, s, e, _ in self.events:
             d = (e - s) * 1e3  # ms
             a = agg[name]
             a[0] += 1
@@ -78,10 +80,19 @@ _current_store: Optional[_HostEventStore] = None
 
 class RecordEvent:
     """User annotation (reference utils.py:47): shows on the device trace
-    via jax.profiler.TraceAnnotation and in host summaries."""
+    via jax.profiler.TraceAnnotation and in host summaries.
 
-    def __init__(self, name: str, event_type=None):
+    Keyword ``args`` (small ints/strings read from host state) ride
+    along: they come back as the event's ``stats`` in a
+    ``jax.profiler`` trace and as the row's args in the Profiler's host
+    store. ``set(**args)`` adds the ones only known once the work is
+    done (tokens harvested, pages allocated). A span is recorded while
+    a ``jax.profiler`` trace or a ``Profiler`` is running and is inert
+    (under a microsecond) otherwise."""
+
+    def __init__(self, name: str, event_type=None, **args):
         self.name = name
+        self.args = args
         self._ann = None
         self._start = None
 
@@ -91,9 +102,13 @@ class RecordEvent:
     def end(self):
         self.__exit__(None, None, None)
 
+    def set(self, **args):
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
     def __enter__(self):
-        import jax
-        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann = jax.profiler.TraceAnnotation(self.name, **self.args)
         self._ann.__enter__()
         self._start = time.perf_counter()
         return self
@@ -103,7 +118,8 @@ class RecordEvent:
             self._ann.__exit__(*(exc or (None, None, None)))
             self._ann = None
         if _current_store is not None and self._start is not None:
-            _current_store.add(self.name, self._start, time.perf_counter())
+            _current_store.add(self.name, self._start, time.perf_counter(),
+                               self.args)
         return False
 
 
@@ -226,7 +242,6 @@ class Profiler:
             self._stop_trace()
 
     def _start_trace(self):
-        import jax
         os.makedirs(self._log_dir, exist_ok=True)
         try:
             jax.profiler.start_trace(self._log_dir)
@@ -235,7 +250,6 @@ class Profiler:
             self._tracing = False  # tracing unavailable (e.g. nested)
 
     def _stop_trace(self):
-        import jax
         try:
             jax.profiler.stop_trace()
         finally:

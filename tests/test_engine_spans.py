@@ -1,0 +1,291 @@
+"""Tick spans (docs/OBSERVABILITY.md "Tick spans"): what the host does
+inside ``Engine.step()`` and ``TrainStep.__call__`` is named on the
+profiler's clock through ``profiler.RecordEvent`` — recorded while a
+``Profiler`` or a ``jax.profiler`` trace runs, inert otherwise — the
+engine's XLA programs carry names, and the per-token gap is counted
+where tokens are appended (``serving.hist.itl_ms``)."""
+import glob
+
+import jax
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import monitor
+from paddle_tpu.inference.engine import Engine, SamplingParams
+from paddle_tpu.profiler import Profiler, RecordEvent, chrome_trace
+from paddle_tpu.text.models import LlamaConfig, LlamaForCausalLM
+
+# span -> the arguments the table in docs/OBSERVABILITY.md names
+STEP_SPANS = {
+    "engine.step": {"step", "active", "waiting", "prefilling"},
+    "engine.decode.dispatch": {"variant", "slots", "ctx_tokens", "ticks"},
+    "engine.flush_state": {"rows", "block_table"},
+    "engine.expire": set(),
+    "engine.admit": {"admitted"},
+    "engine.prefill": {"req", "bucket", "tokens", "start", "final"},
+    "engine.prefill.wait": set(),
+    "engine.decode.wait": set(),
+    "engine.harvest": {"tokens", "finished"},
+    "engine.ensure_pages": {"allocated", "preempted"},
+    "engine.bookkeeping": set(),
+}
+PARENT = {"engine.flush_state": "engine.decode.dispatch",
+          "engine.prefill.wait": "engine.prefill"}
+
+
+def _net(seed=0):
+    paddle.seed(seed)
+    cfg = LlamaConfig.tiny(vocab=64, hidden=64, layers=2, heads=4)
+    cfg.use_flash_attention = False
+    net = LlamaForCausalLM(cfg)
+    net.eval()
+    return net
+
+
+def _engine(**kw):
+    return Engine(_net(), max_slots=2, page_size=8, pool_pages=64,
+                  max_context=64, **kw)
+
+
+def _prompt(n, lo=1):
+    return np.arange(lo, lo + n, dtype=np.int64)
+
+
+@pytest.fixture(scope="module")
+def one_step():
+    """One step() that holds a decode dispatch AND a prefill, recorded
+    by a Profiler: (rows of the host store, outputs of the whole run,
+    the engine)."""
+    eng = _engine()
+    first = eng.add_request(_prompt(5), SamplingParams(max_new_tokens=6))
+    eng.step()                                   # warm: prefill + compile
+    eng.step()                                   # warm: decode compile
+    with Profiler(timer_only=True) as prof:
+        second = eng.add_request(_prompt(7, 2),
+                                 SamplingParams(max_new_tokens=3))
+        outs = eng.step()
+    rows = list(prof._store.events)
+    while not eng.idle:
+        outs += eng.step()
+    return rows, {o.req_id: o for o in outs}, (first, second)
+
+
+def _by_name(rows):
+    out = {}
+    for name, t0, t1, args in rows:
+        out.setdefault(name, []).append((t0, t1, args))
+    return out
+
+
+def test_one_step_yields_every_span_with_its_args(one_step):
+    rows, _, _ = one_step
+    spans = _by_name(rows)
+    for name, want in STEP_SPANS.items():
+        assert name in spans, f"{name} missing from {sorted(spans)}"
+        assert len(spans[name]) == 1, name
+        assert set(spans[name][0][2]) == want, name
+    t0, t1, args = spans["engine.decode.dispatch"][0]
+    assert args["variant"] == "greedy" and args["slots"] == 1
+    assert args["ticks"] == 1
+    # the first request: 5 prompt tokens + the token decoded before
+    assert args["ctx_tokens"] == 6
+    assert spans["engine.admit"][0][2]["admitted"] == 1
+    assert spans["engine.harvest"][0][2] == {"tokens": 1, "finished": 0}
+    pf = spans["engine.prefill"][0][2]
+    assert (pf["tokens"], pf["start"], pf["final"]) == (7, 0, 1)
+    assert pf["bucket"] >= 7
+
+
+def test_add_request_span_lies_outside_step(one_step):
+    rows, _, (_, second) = one_step
+    spans = _by_name(rows)
+    a0, a1, args = spans["engine.add_request"][0]
+    assert args == {"req": second, "prompt_tokens": 7}
+    s0, _, _ = spans["engine.step"][0]
+    assert a1 <= s0
+
+
+def test_spans_nest_and_cover_the_step(one_step):
+    rows, _, _ = one_step
+    spans = _by_name(rows)
+    s0, s1, _ = spans["engine.step"][0]
+    children = []
+    for name in STEP_SPANS:
+        if name == "engine.step":
+            continue
+        c0, c1, _ = spans[name][0]
+        assert s0 <= c0 <= c1 <= s1, name
+        if name in PARENT:
+            p0, p1, _ = spans[PARENT[name]][0]
+            assert p0 <= c0 <= c1 <= p1, name
+        else:
+            children.append((c0, c1))
+    # top-level children do not overlap (one thread, nested by `with`)
+    children.sort()
+    assert all(a[1] <= b[0] for a, b in zip(children, children[1:]))
+    covered = sum(c1 - c0 for c0, c1 in children)
+    assert covered >= 0.95 * (s1 - s0)
+
+
+def test_prefill_span_req_joins_the_request_timeline(one_step):
+    rows, outs, (_, second) = one_step
+    req = _by_name(rows)["engine.prefill"][0][2]["req"]
+    assert req == second
+    phases = [s["phase"] for s in outs[req].spans]
+    assert "PREFILL" in phases and phases[0] == "QUEUED"
+
+
+def _run_virtual(record):
+    """Three staggered requests on a virtual clock; returns what a
+    client can see of every Output."""
+    vt = [0.0]
+    eng = _engine(clock=lambda: vt[0])
+    outs, plan = [], {0: (5, 6), 2: (9, 4), 3: (3, 5)}
+    prof = Profiler(timer_only=True) if record else None
+    if prof is not None:
+        prof.start()
+    try:
+        for step in range(40):
+            if step in plan:
+                n, new = plan[step]
+                eng.add_request(_prompt(n, step + 1),
+                                SamplingParams(max_new_tokens=new))
+            outs += eng.step()
+            vt[0] += 0.004
+    finally:
+        if prof is not None:
+            prof.stop()
+    assert len(outs) == 3
+    return [(o.req_id, o.token_ids, o.finish_reason, o.ttft_ms, o.tpot_ms,
+             o.spans) for o in sorted(outs, key=lambda o: o.req_id)]
+
+
+def test_recording_changes_no_token_and_no_timeline():
+    assert _run_virtual(record=False) == _run_virtual(record=True)
+
+
+@pytest.mark.parametrize("multi_tick", [1, 4])
+def test_itl_histogram_counts_tokens_minus_requests(multi_tick):
+    hist = monitor.histogram("serving.hist.itl_ms")
+    tokens = monitor.counter("serving.tokens")
+    h0, z0, t0 = hist.count, hist.to_dict()["zeros"], tokens.get()
+    vt = [1.0]
+    eng = _engine(clock=lambda: vt[0], multi_tick=multi_tick)
+    news = (6, 9, 4)
+    for i, new in enumerate(news):
+        eng.add_request(_prompt(4 + i, i + 1),
+                        SamplingParams(max_new_tokens=new))
+    outs = []
+    while not eng.idle:
+        outs += eng.step()
+        vt[0] += 0.01
+    assert sorted(len(o.token_ids) for o in outs) == sorted(news)
+    assert tokens.get() - t0 == sum(news)
+    assert hist.count - h0 == sum(news) - len(news)
+    if multi_tick == 1:
+        # one token a step, the clock moves between steps: no 0 gap
+        assert hist.to_dict()["zeros"] == z0
+    else:
+        # the k tokens of a fused dispatch arrive together
+        assert hist.to_dict()["zeros"] > z0
+
+
+def test_extract_request_resets_the_gap_stamp():
+    eng = _engine()
+    rid = eng.add_request(_prompt(5), SamplingParams(max_new_tokens=8))
+    for _ in range(3):
+        eng.step()
+    assert eng.requests[rid].last_token_t > 0.0
+    req = eng.extract_request(rid)
+    assert req.generated and req.last_token_t == 0.0
+    assert req.first_token_t > 0.0
+
+
+def _trace_events(tmp_path, work):
+    """Names -> list of stats dicts of every host event of a
+    jax.profiler trace around `work()`."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        work()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    seen = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                seen.setdefault(ev.name, []).append(dict(ev.stats))
+    return seen
+
+
+def test_jax_trace_shows_span_args_and_program_names(tmp_path):
+    eng = _engine()
+    eng.add_request(_prompt(5), SamplingParams(max_new_tokens=4))
+    eng.step()
+    eng.step()
+
+    def work():
+        eng.add_request(_prompt(7, 2), SamplingParams(max_new_tokens=2))
+        eng.step()
+
+    seen = _trace_events(tmp_path, work)
+    dispatch, = seen["engine.decode.dispatch"]
+    assert dispatch["ctx_tokens"] == 6 and dispatch["variant"] == "greedy"
+    assert seen["engine.prefill"][0]["bucket"] == eng._pbucket(7)
+    names = " ".join(seen)
+    assert "serve_decode_greedy" in names
+    assert f"serve_prefill_{eng._pbucket(7)}" in names
+    assert "jit_body" not in names and "(body)" not in names
+
+
+def test_program_names_of_every_executable_family():
+    eng = _engine(multi_tick=4)
+    assert eng._get_decode_fn("plain").__name__ == "serve_decode_plain"
+    assert eng._get_prefill_fn(32).__name__ == "serve_prefill_32"
+    assert eng._get_multi_fn(4).__name__ == "serve_multi_4"
+    assert eng._get_verify_fn("greedy").__name__ == "serve_verify_greedy"
+
+
+def test_trainstep_yields_its_three_spans():
+    paddle.seed(0)
+    net = paddle.nn.Linear(8, 4)
+    opt = paddle.optimizer.SGD(learning_rate=0.1,
+                               parameters=net.parameters())
+    step = paddle.jit.TrainStep(
+        net, lambda out, lab: ((out - lab) ** 2).mean(), opt)
+    x = paddle.to_tensor(np.ones((2, 8), np.float32))
+    y = paddle.to_tensor(np.zeros((2, 4), np.float32))
+    step(x, y)
+    with Profiler(timer_only=True) as prof:
+        loss = step(x, y)
+    assert np.isfinite(float(loss.numpy()))
+    names = [row[0] for row in prof._store.events]
+    assert names == ["trainstep.prepare", "trainstep.dispatch",
+                     "trainstep.write_back"]
+    rows = prof._store.events
+    assert all(a[2] <= b[1] for a, b in zip(rows, rows[1:]))
+
+
+def test_record_event_keeps_args_and_exports_them():
+    with Profiler(timer_only=True) as prof:
+        with RecordEvent("phase", step=3) as ev:
+            ev.set(rows=2)
+        with RecordEvent("bare"):
+            pass
+    (name, t0, t1, args), bare = prof._store.events
+    assert (name, args) == ("phase", {"step": 3, "rows": 2}) and t1 >= t0
+    assert bare[3] == {}
+    assert prof._store.aggregate()["phase"]["calls"] == 1
+    user = [e for e in chrome_trace.build_trace(prof)["traceEvents"]
+            if e.get("cat") == "user"]
+    assert user[0]["args"] == {"step": 3, "rows": 2}
+    assert "args" not in user[1]
+    # with nothing recording a span is inert: no store, no error
+    with RecordEvent("after", x=1) as ev:
+        ev.set(y=2)
+    assert len(prof._store.events) == 2
