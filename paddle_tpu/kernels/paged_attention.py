@@ -146,8 +146,9 @@ def paged_attention_arrays(q, k_cache, v_cache, block_tables, context_lens,
 def gather_pages(cache, block_tables):
     """Materialize each sequence's pages as a contiguous [b, L, h_kv, d]
     view (L = max_blocks * block_size) from the head-major pool. ONE
-    XLA gather — but it COPIES the visible cache, which is why the
-    decode hot path uses paged_decode_pallas instead."""
+    XLA gather — it copies the pages the block table names (never the
+    pool), which is still the whole visible cache: the decode hot path
+    uses paged_decode_pallas instead, prefill chunks come here."""
     nb, h_kv, bs, d = cache.shape
     b = block_tables.shape[0]
     L = block_tables.shape[1] * bs
@@ -178,16 +179,12 @@ def paged_write_arrays(k, v, k_cache, v_cache, block_tables, positions):
                 pos % block_size.
     Returns the updated (k_cache, v_cache).
     """
-    nb, h_kv, bs, d = k_cache.shape
-    squeeze = k.ndim == 3
-    if squeeze:
+    if k.ndim == 3:
         k, v = k[:, None], v[:, None]
-    page, slot = _page_slots(block_tables, positions, k.shape[1], bs)
-    # advanced indices (page, slot) straddle the ':' head slice, so the
-    # result axes are [b, s, h_kv, d] — exactly k/v's layout
-    k_cache = k_cache.at[page, :, slot].set(k.astype(k_cache.dtype))
-    v_cache = v_cache.at[page, :, slot].set(v.astype(v_cache.dtype))
-    return k_cache, v_cache
+    return _scatter_tokens(
+        (k_cache, v_cache),
+        (k.astype(k_cache.dtype), v.astype(v_cache.dtype)),
+        block_tables, positions)
 
 
 def _page_slots(block_tables, positions, s, bs):
@@ -219,6 +216,65 @@ def _page_slots(block_tables, positions, s, bs):
     return page, pos % bs
 
 
+def _scatter_rows(pool, x, page, slot):
+    """pool[page, :, slot] = x for x [b, s, h_kv, ...], one row per
+    (token, kv head), indexed on the pool's [num_blocks,
+    h_kv * block_size, ...] view: the two indexed dimensions are then
+    the pool's two major ones and the reshape is a bitcast, so the TPU
+    compiler scatters into the (donated) pool where it lies. Indexed as
+    ``.at[page, :, slot]`` the scatter straddles the head dimension,
+    gets an operand layout with page and slot major, and every call
+    transposes the WHOLE pool there and back (docs/DECODE.md "the KV
+    write"). The page index keeps ``.at``'s own semantics — a negative
+    id wraps, an id past the pool is dropped — and the column is always
+    in range."""
+    nb, h_kv, bs = pool.shape[:3]
+    col = jnp.arange(h_kv, dtype=slot.dtype) * bs + slot[..., None]
+    flat = pool.reshape((nb, h_kv * bs) + pool.shape[3:])
+    return flat.at[page[..., None], col].set(x).reshape(pool.shape)
+
+
+def _scatter_pages(pool, x, page):
+    """pool[page] = x for whole pages: x [b, n * block_size, h_kv, ...]
+    whose token 0 sits on slot 0, page [b, n]. One index row per page
+    where _scatter_rows has block_size * h_kv (a TPU scatter walks its
+    indices: 0.29 ms against 15.6 for the 16 pools of a 1792-token
+    chunk, PERF.md section 6, PR 28)."""
+    _, h_kv, bs = pool.shape[:3]
+    b, n = page.shape
+    tiles = x.reshape((b, n, bs, h_kv) + x.shape[3:])
+    return pool.at[page].set(jnp.swapaxes(tiles, 2, 3))
+
+
+def _scatter_tokens(pools, chunks, block_tables, positions):
+    """Write each [b, s, h_kv, ...] chunk into its head-major pool at
+    the (page, slot) of ``positions``, in place in the pool's own
+    layout. The granularity follows what the chunk is: rows
+    (_scatter_rows) for a chunk shorter than a page; for a longer one,
+    whole-page tiles (_scatter_pages) plus rows for the ragged end when
+    every sequence starts on a page boundary — a property of the traced
+    ``positions``, so the choice is a ``lax.cond`` — and rows
+    otherwise. Both branches write the same elements."""
+    bs = pools[0].shape[2]
+    s = chunks[0].shape[1]
+    page, slot = _page_slots(block_tables, positions, s, bs)
+
+    def rows(pools, lo=0):
+        return tuple(_scatter_rows(p, x[:, lo:], page[:, lo:], slot[:, lo:])
+                     for p, x in zip(pools, chunks))
+
+    whole = s - s % bs
+    if not whole:
+        return rows(pools)
+
+    def pages(pools):
+        pools = tuple(_scatter_pages(p, x[:, :whole], page[:, :whole:bs])
+                      for p, x in zip(pools, chunks))
+        return rows(pools, whole) if whole < s else pools
+
+    return jax.lax.cond(jnp.all(slot[:, 0] == 0), pages, rows, pools)
+
+
 def paged_write_quant_arrays(k, v, k_cache, v_cache, k_scale, v_scale,
                              block_tables, positions):
     """paged_write_arrays for an int8 pool: quantizes the float chunk
@@ -228,18 +284,12 @@ def paged_write_quant_arrays(k, v, k_cache, v_cache, k_scale, v_scale,
     [num_blocks, h_kv, block_size]. Returns the four updated pools."""
     from ..quantization.functional import kv_quantize_arrays
 
-    nb, h_kv, bs, d = k_cache.shape
-    squeeze = k.ndim == 3
-    if squeeze:
+    if k.ndim == 3:
         k, v = k[:, None], v[:, None]
     qk, sk = kv_quantize_arrays(k)     # [b, s, h_kv, d] / [b, s, h_kv]
     qv, sv = kv_quantize_arrays(v)
-    page, slot = _page_slots(block_tables, positions, k.shape[1], bs)
-    k_cache = k_cache.at[page, :, slot].set(qk)
-    v_cache = v_cache.at[page, :, slot].set(qv)
-    k_scale = k_scale.at[page, :, slot].set(sk)
-    v_scale = v_scale.at[page, :, slot].set(sv)
-    return k_cache, v_cache, k_scale, v_scale
+    return _scatter_tokens((k_cache, v_cache, k_scale, v_scale),
+                           (qk, qv, sk, sv), block_tables, positions)
 
 
 # Multi-sequence-grid kernel tiling (paged_decode_pallas): target

@@ -427,8 +427,10 @@ class LlamaAttention(Layer):
 
             # single-token decode steps take the Pallas kernel: pages
             # stream from the pool via scalar-prefetched block tables —
-            # the XLA path below re-gathers (copies) the WHOLE cache
-            # every step, which measured 2.8x slower at b32. The
+            # the XLA path below gathers (copies) every page the block
+            # table names every step, which measured 2.8x slower at
+            # b32. Neither path copies the pool: the write above
+            # updates it in place (docs/DECODE.md "The KV write"). The
             # counters record, at trace time, which path the compiled
             # loop actually baked in (bench extras.telemetry reads the
             # deltas — docs/OBSERVABILITY.md).

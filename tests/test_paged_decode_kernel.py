@@ -180,3 +180,135 @@ def test_chunk_geometry_and_requirements():
     why = paged_pallas_requirements(64, 8, jnp.bfloat16)
     assert "head_dim 64" in why and "sublane" in why
     assert paged_pallas_requirements(128, 8, jnp.bfloat16) is not None
+
+
+# -- the KV write (paged_write_arrays / paged_write_quant_arrays) ----------
+
+def _write_today(pool, x, page, slot):
+    """The write as it was before it moved to the pool's flat view: the
+    reference for "lands where it landed"."""
+    return pool.at[page, :, slot].set(x.astype(pool.dtype))
+
+
+def _write_loop(pool, x, bt, positions):
+    """Plain loop over (sequence, token, head) with the index rules
+    spelled out: a position past the block table is dropped, a page id
+    past the pool is dropped, a negative one counts from the end."""
+    nb, h_kv, bs = pool.shape[:3]
+    mb = bt.shape[1]
+    out = np.array(pool)
+    for seq in range(x.shape[0]):
+        for i in range(x.shape[1]):
+            pos = int(positions[seq]) + i
+            j = pos // bs
+            if not -mb <= j < mb:
+                continue
+            page = int(bt[seq, j])
+            if not -nb <= page < nb:
+                continue
+            for head in range(h_kv):
+                out[page, head, pos % bs] = x[seq, i, head]
+    return out
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(np.uint16) if a.dtype.itemsize == 2 else a
+
+
+def _pools_for(rng, pool, nb, h_kv, bs, d):
+    if pool == "bf16":
+        return [jnp.asarray(rng.standard_normal((nb, h_kv, bs, d)),
+                            jnp.bfloat16) for _ in range(2)]
+    return [jnp.asarray(rng.integers(-127, 128, (nb, h_kv, bs, d)),
+                        jnp.int8) for _ in range(2)] \
+        + [jnp.asarray(rng.random((nb, h_kv, bs)), jnp.float32)
+           for _ in range(2)]
+
+
+@jax.jit
+def _write_and_reference(k, v, pools, bt, pos):
+    """(the pools the write returns, the [b, s, h_kv, ...] chunks it was
+    to store, the pools `.at[page, :, slot].set` gives). One program, so
+    the int8 path's quantisation is the same arithmetic on both sides;
+    traced positions skip the eager capacity check."""
+    from paddle_tpu.kernels.paged_attention import (
+        _page_slots, paged_write_arrays, paged_write_quant_arrays)
+    b, h_kv, d = k.shape[0], k.shape[-2], k.shape[-1]
+    k4, v4 = k.reshape(b, -1, h_kv, d), v.reshape(b, -1, h_kv, d)
+    if len(pools) == 2:
+        got = paged_write_arrays(k, v, *pools, bt, pos)
+        chunks = [k4.astype(pools[0].dtype), v4.astype(pools[1].dtype)]
+    else:
+        got = paged_write_quant_arrays(k, v, *pools, bt, pos)
+        (qk, sk), (qv, sv) = kv_quantize_arrays(k4), kv_quantize_arrays(v4)
+        chunks = [qk, qv, sk, sv]
+    page, slot = _page_slots(bt, pos, k4.shape[1], pools[0].shape[2])
+    today = [_write_today(p, x, page, slot) for p, x in zip(pools, chunks)]
+    return got, chunks, today
+
+
+# (block table or None for 3 sequences x 3 distinct pages of 12, first
+# position of each sequence, tokens a sequence or None for the
+# one-token form); page size 4, page 0 = the engine's scratch page
+WRITE_CASES = {
+    "one-token": (None, [5, 0, 11], None),
+    "short-chunk-crossing-a-page": (None, [2, 6, 0], 3),
+    "long-chunk-off-the-boundary": (None, [3, 1, 2], 6),
+    "whole-pages": (None, [4, 0, 4], 8),
+    "whole-pages-and-ragged-end": (None, [0, 0, 0], 11),
+    "one-sequence-off-the-boundary": (None, [4, 1, 0], 8),
+    # cache_index -1: page = block_tables[row, -1], slot = page_size - 1
+    "dead-lane": ([[3, 4, 0], [5, 1, 2]], [-1, 6], None),
+    "dead-lane-chunk": ([[3, 4, 0], [5, 1, 2]], [-1, 4], 3),
+    "page-id-past-the-pool": ([[3, 6, 2], [5, 1, 7]], [5, 2], None),
+    "page-id-past-the-pool-chunk": ([[3, 6, 2], [5, 1, 600]], [4, 4], 8),
+    "negative-page-id-wraps": ([[3, -1, 2], [5, 1, -6]], [6, 8], None),
+    "negative-page-id-wraps-chunk": ([[-2, 4, 0], [5, -7, 2]], [0, 0], 8),
+    # the padded tail of a chunk that runs past the block table must
+    # land nowhere
+    "past-the-block-table": ([[3, 4, 2], [5, 1, 0]], [8, 4], 6),
+    "past-the-block-table-whole-pages": ([[3, 4, 2], [5, 1, 0]], [8, 4],
+                                         8),
+}
+
+
+@pytest.mark.parametrize("h_kv", [8, 1], ids=["gqa8", "mqa1"])
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("case", WRITE_CASES)
+def test_paged_write_matches_loop(rng, case, pool, h_kv):
+    """Every element the write stores is the one a plain loop stores and
+    the one `.at[page, :, slot].set` stored, at every granularity the
+    chunk's shape selects (rows; whole-page tiles + rows for the ragged
+    end when every sequence starts on a page boundary): a dead lane
+    (position -1), a page id past the pool (dropped), a negative one
+    (wraps) and a chunk that runs past the block table land where they
+    landed, and pages the block table does not name are bit-unchanged."""
+    bt, pos, s = WRITE_CASES[case]
+    bs, d = 4, 8
+    if bt is None:
+        nb = 12
+        bt = rng.permutation(np.arange(1, nb))[:9].reshape(3, 3)
+    else:
+        nb = 6
+    bt, pos = np.asarray(bt, np.int32), np.asarray(pos, np.int32)
+    b = len(pos)
+    shape = (b, h_kv, d) if s is None else (b, s, h_kv, d)
+    k = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    v = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    pools = _pools_for(rng, pool, nb, h_kv, bs, d)
+    got, chunks, today = _write_and_reference(k, v, pools, bt, pos)
+    assert len(got) == len(pools)
+    named = np.isin(np.arange(nb), bt % nb)
+    for new, old, x, was in zip(got, pools, chunks, today):
+        assert new.dtype == old.dtype and new.shape == old.shape
+        np.testing.assert_array_equal(
+            _bits(new), _write_loop(_bits(old), _bits(x), bt, pos))
+        np.testing.assert_array_equal(_bits(new), _bits(was))
+        np.testing.assert_array_equal(_bits(new)[~named],
+                                      _bits(old)[~named])
+        assert (_bits(new)[named] != _bits(old)[named]).any()
+    if case.startswith("dead-lane"):
+        # the dead lane's first token: scratch page 0, last slot
+        np.testing.assert_array_equal(_bits(got[0])[0, :, bs - 1],
+                                      _bits(chunks[0])[0, 0])

@@ -11,6 +11,9 @@ never at import, in a skipif, in parametrize or in conftest.py), and every
 compile happens in this process with the persistent cache off (an entry
 written for a described chip cannot be read back without one).
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -19,8 +22,12 @@ from jax.sharding import SingleDeviceSharding
 from paddle_tpu.distributed import mesh as mesh_mod
 from paddle_tpu.kernels import moe
 from paddle_tpu.kernels.flash_attention import flash_attention_arrays
-from paddle_tpu.kernels.paged_attention import (paged_decode_pallas,
-                                                paged_pallas_requirements)
+from paddle_tpu.kernels.paged_attention import (gather_page_scales,
+                                                gather_pages,
+                                                paged_decode_pallas,
+                                                paged_pallas_requirements,
+                                                paged_write_arrays,
+                                                paged_write_quant_arrays)
 
 
 @pytest.fixture(scope="module")
@@ -114,6 +121,71 @@ def test_paged_decode_int8_narrow_page_is_refused(one_chip):
     assert "128 lanes" in paged_pallas_requirements(128, 32, jnp.int8)
     with pytest.raises(Exception, match="aligned to tiling"):
         _compiled_text(_paged, one_chip, *_paged_shapes(jnp.int8, 32))
+
+
+# the serve cell's pools (benchmark/configs/mistral-7b-serve-8l.json):
+# 48 slots x 18 pages + the scratch page, 8 kv heads, page 128, d 128
+POOL, BT_WIDTH, Q_HEADS = (865, 8, 128, 128), 18, 32
+
+
+def _write_then_attend(q, k, v, bt, pos, *pools):
+    """One layer's cache step as `_paged_cached_attention` runs it: the
+    write, then the decode kernel for one token a slot or the page
+    gather for a prefill chunk; the pools go back out (donated)."""
+    if len(pools) == 4:
+        pools = paged_write_quant_arrays(k, v, *pools, bt, pos)
+    else:
+        pools = paged_write_arrays(k, v, *pools, bt, pos)
+    kc, vc, *scales = pools
+    if k.ndim == 3:
+        ks, vs = scales or (None, None)
+        out = paged_decode_pallas(q, kc, vc, bt, pos + 1,
+                                  k_scale=ks, v_scale=vs)
+    else:
+        out = [gather_pages(kc, bt), gather_pages(vc, bt)] \
+            + [gather_page_scales(s, bt) for s in scales]
+    return out, pools
+
+
+@pytest.mark.parametrize("tokens", [None, 512],
+                         ids=["one-token-48-slots", "chunk-512"])
+@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8-and-scales"])
+def test_paged_write_updates_the_pool_in_place(one_chip, pool_dtype,
+                                               tokens):
+    """The KV write must land in the donated pool where it lies: no
+    `copy` of a pool's shape in the compiled program, temporaries under
+    a tenth of one pool, both pools aliased. Fails on the write as it
+    was up to PR 26 (`pool.at[page, :, slot].set(x)`: the scatter
+    straddles the head dimension, gets an operand layout with page and
+    slot major, and the compiler transposes the whole pool there and
+    back: 2 copies a pool, temp_size_in_bytes = one pool, 22 ms of a 31
+    ms decode tick on the chip)."""
+    b = 48 if tokens is None else 1
+    kv = (b, POOL[1], POOL[3]) if tokens is None \
+        else (b, tokens, POOL[1], POOL[3])
+    pools = [(POOL, pool_dtype)] * 2
+    if pool_dtype == jnp.int8:
+        pools += [(POOL[:3], jnp.float32)] * 2
+    shapes = [((b, Q_HEADS, POOL[3]), jnp.bfloat16), (kv, jnp.bfloat16),
+              (kv, jnp.bfloat16), ((b, BT_WIDTH), jnp.int32),
+              ((b,), jnp.int32)] + pools
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    compiled = jax.jit(_write_then_attend,
+                       donate_argnums=tuple(range(5, len(args)))
+                       ).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == (1 if tokens is None else 0)
+    pages, h_kv, page, d = POOL             # value pools and scale pools
+    pool_copy = re.compile(
+        rf"= \w+\[{pages},{h_kv},{page}(,{d})?\]\{{[^}}]*\}} copy\(")
+    assert [line.strip()[:120] for line in text.splitlines()
+            if pool_copy.search(line)] == []
+    one_pool = math.prod(POOL) * jnp.dtype(pool_dtype).itemsize
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < one_pool / 10
+    assert mem.alias_size_in_bytes >= 2 * one_pool
 
 
 def _moe_shapes(e=8, cap=8192, h=768, dff=3072):
