@@ -1,3 +1,3 @@
 from .gate import (BaseGate, GShardGate, NaiveGate,  # noqa: F401
-                   SwitchGate, topk_gating)
+                   SigmoidTopKGate, SwitchGate, topk_gating)
 from .moe_layer import GroupedExpertsFFN, MoELayer  # noqa: F401

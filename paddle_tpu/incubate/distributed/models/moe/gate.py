@@ -206,3 +206,33 @@ class GShardGate(BaseGate):
 
     def __init__(self, num_experts, capacity_factor=2.0):
         super().__init__(num_experts, 2, capacity_factor, 0.0)
+
+
+def sigmoid_topk_routing(logits, bias, top_k: int, norm_topk_prob=True,
+                         scaling: float = 1.0):
+    """Sigmoid-score routing with a selection bias (DeepSeek-V3's
+    `noaux_tc`): p = sigmoid(logits) [N, E]; the top_k experts of
+    largest p + bias are chosen, and weighted by p itself (the bias
+    steers the choice only), over the sum of the chosen p when
+    norm_topk_prob, times `scaling`. No capacity: every pick is kept.
+    Returns (expert_idx [N, k] int32, weights [N, k] float32)."""
+    p = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(p + bias.astype(jnp.float32)[None], top_k)
+    w = jnp.take_along_axis(p, idx, axis=1)
+    if norm_topk_prob:
+        w = w / jnp.maximum(jnp.sum(w, axis=1, keepdims=True), 1e-20)
+    return idx.astype(jnp.int32), w * jnp.float32(scaling)
+
+
+class SigmoidTopKGate(BaseGate):
+    """Sigmoid scores, top-k of score + selection bias, normalised
+    weights; routes without capacity (see sigmoid_topk_routing)."""
+
+    def __init__(self, num_experts, top_k=8, norm_topk_prob=True,
+                 routed_scaling_factor=1.0):
+        super().__init__(num_experts, top_k, 1.0, 0.0)
+        self.norm_topk_prob = bool(norm_topk_prob)
+        self.routed_scaling_factor = float(routed_scaling_factor)
+
+    def capacity(self, num_tokens: int) -> int:
+        return int(num_tokens)      # nothing is ever dropped

@@ -29,7 +29,8 @@ from .....distributed.auto_parallel import Replicate, Shard, shard_tensor
 from .....distributed.auto_parallel.process_mesh import ProcessMesh
 from .....distributed.fleet.layers.mpu.mp_ops import mark_sharding
 from .....nn.layer.layers import Layer
-from .gate import BaseGate, GShardGate, NaiveGate, SwitchGate
+from .gate import (BaseGate, GShardGate, NaiveGate, SigmoidTopKGate,
+                   SwitchGate)
 
 
 def _shard_expert_param(layer: Layer, name: str, axis: str = "ep"):
@@ -56,20 +57,43 @@ class GroupedExpertsFFN(Layer):
                  activation="gelu", ep_axis: str = "ep"):
         super().__init__()
         self.num_experts = num_experts
-        self.w1 = self.create_parameter([num_experts, d_model, d_hidden])
-        self.b1 = self.create_parameter([num_experts, 1, d_hidden],
-                                        is_bias=True)
-        self.w2 = self.create_parameter([num_experts, d_hidden, d_model])
-        self.b2 = self.create_parameter([num_experts, 1, d_model],
-                                        is_bias=True)
-        for n in ("w1", "b1", "w2", "b2"):
-            _shard_expert_param(self, n, ep_axis)
         self._act = activation
+        if activation == "swiglu":
+            # gated three-matrix experts, no biases:
+            # (silu(x w1) * (x w3)) w2, the LLaMA / DeepSeek expert
+            from .....nn.initializer import Normal
+            init = Normal(0.0, 0.02)
+            self.w1 = self.create_parameter(
+                [num_experts, d_model, d_hidden], default_initializer=init)
+            self.w3 = self.create_parameter(
+                [num_experts, d_model, d_hidden], default_initializer=init)
+            self.w2 = self.create_parameter(
+                [num_experts, d_hidden, d_model], default_initializer=init)
+            names = ("w1", "w3", "w2")
+        else:
+            self.w1 = self.create_parameter(
+                [num_experts, d_model, d_hidden])
+            self.b1 = self.create_parameter([num_experts, 1, d_hidden],
+                                            is_bias=True)
+            self.w2 = self.create_parameter(
+                [num_experts, d_hidden, d_model])
+            self.b2 = self.create_parameter([num_experts, 1, d_model],
+                                            is_bias=True)
+            names = ("w1", "b1", "w2", "b2")
+        for n in names:
+            _shard_expert_param(self, n, ep_axis)
 
     def forward(self, x):
         """x: [E, C, h] -> [E, C, h] (batched per-expert GEMMs)."""
+        if self._act == "swiglu":
+            def gated(x, w1, w3, w2):
+                mid = jax.nn.silu(jnp.einsum("ech,ehf->ecf", x, w1)) \
+                    * jnp.einsum("ech,ehf->ecf", x, w3)
+                return jnp.einsum("ecf,efh->ech", mid, w2)
+            return run_op("grouped_experts_ffn", gated,
+                          [x, self.w1, self.w3, self.w2])
+
         def fn(x, w1, b1, w2, b2):
-            import jax
             h = jnp.einsum("ech,ehf->ecf", x, w1) + b1
             h = jax.nn.gelu(h) if self._act == "gelu" else jnp.maximum(h, 0)
             return jnp.einsum("ecf,efh->ech", h, w2) + b2
@@ -198,6 +222,24 @@ class MoELayer(Layer):
             combine, O(N * k * H) with no E- or C-proportional term;
             group_size is ignored, the cost is already linear in
             tokens). Routing decisions are identical in all three.
+        gate="sigmoid_topk" (or a SigmoidTopKGate): sigmoid scores, the
+            top_k of score + `e_score_correction_bias` (a buffer, zero
+            until something sets it), weights normalised over the picks.
+            It has no capacity, so it takes its own dispatch whatever
+            dispatch_mode says: picks sorted by expert into one
+            ragged grouped matmul (`kernels.moe.grouped_ffn_gated`),
+            nothing dropped, in training and serving alike.
+        activation: "gelu" | "relu" (two matrices and biases) or
+            "swiglu" (gated, three matrices, no biases; sorted dispatch
+            only).
+        expert_share: (index, of) — this layer HOLDS the experts
+            [index * E/of, (index + 1) * E/of) of the E it routes over
+            (one chip's share of an expert-parallel layer). The router
+            keeps its E outputs and its top_k; a pick that falls on an
+            expert held elsewhere adds nothing here, and nothing stands
+            in for it. Sorted dispatch only.
+        shared_experts: a Layer applied to every token and added to the
+            routed result (what every chip of the layer computes alike).
 
     After forward, `self.l_aux` holds the load-balancing auxiliary loss
     (add `layer.l_aux * coeff` to the training loss, as the reference's
@@ -209,7 +251,9 @@ class MoELayer(Layer):
                  capacity_factor: Optional[float] = None,
                  experts: Optional[Layer] = None, moe_group=None,
                  ep_axis: str = "ep", group_size: Optional[int] = None,
-                 dispatch_mode: str = "pallas", name=None):
+                 dispatch_mode: str = "pallas", name=None,
+                 activation: str = "gelu", expert_share=None,
+                 shared_experts: Optional[Layer] = None):
         super().__init__()
         if dispatch_mode not in ("pallas", "einsum", "scatter"):
             raise ValueError(
@@ -230,17 +274,44 @@ class MoELayer(Layer):
                                   capacity_factor or 1.25)
         elif gate == "gshard":
             self.gate = GShardGate(num_experts, capacity_factor or 2.0)
+        elif gate == "sigmoid_topk":
+            self.gate = SigmoidTopKGate(num_experts, top_k or 8)
         else:
             raise ValueError(
                 f"unknown gate {gate!r}: expected 'gshard', 'switch', "
-                "'naive', or a BaseGate instance")
+                "'naive', 'sigmoid_topk', or a BaseGate instance")
         if top_k is not None:
             self.gate.top_k = top_k
+        self._sorted = isinstance(self.gate, SigmoidTopKGate)
+        index, of = expert_share or (0, 1)
+        if of < 1 or num_experts % of or not 0 <= index < of:
+            raise ValueError(
+                f"expert_share=({index}, {of}): `of` must divide the "
+                f"{num_experts} experts and 0 <= index < of")
+        if not self._sorted and (of > 1 or activation == "swiglu"):
+            raise ValueError(
+                "expert_share and activation='swiglu' need the capacity-"
+                "free sorted dispatch: pass gate='sigmoid_topk'")
+        self.expert_share = (int(index), int(of))
+        self.num_held = num_experts // of
+        self.first_held = index * self.num_held
         self.experts = experts if experts is not None else \
-            GroupedExpertsFFN(num_experts, d_model, d_hidden,
-                              ep_axis=ep_axis)
+            GroupedExpertsFFN(self.num_held, d_model, d_hidden,
+                              activation=activation, ep_axis=ep_axis)
+        if self._sorted:
+            if getattr(self.experts, "_act", None) != "swiglu":
+                raise ValueError(
+                    "gate='sigmoid_topk' dispatches to gated experts "
+                    "only: pass activation='swiglu'")
+            self.register_buffer("e_score_correction_bias", wrap(
+                jnp.zeros((num_experts,), jnp.float32)))
+        self.shared_experts = shared_experts
         self._ep_axis = ep_axis
         self.l_aux = None
+        # [picks held here, picks made, held experts touched] of the
+        # last sorted forward: traced values inside a compiled step,
+        # which the serving engine returns with the tick
+        self.last_stats = None
 
     def _n_groups(self, n):
         return _n_groups_cached(n, self._group_size)
@@ -459,6 +530,58 @@ class MoELayer(Layer):
         return self._forward_scatter(tokens, orig_shape,
                                      token_mask=mask, cap=n)
 
+    def _forward_sorted(self, tokens, orig_shape, token_mask=None):
+        """Capacity-free dispatch for the sigmoid top-k gate: every
+        (token, pick) whose expert is held here becomes one row; the
+        rows are sorted by expert, run through ONE ragged grouped
+        matmul per matrix (kernels.moe.grouped_ffn_gated), weighted, and
+        summed back onto their tokens by the inverse permutation (a
+        gather, not a scatter). Picks on experts held elsewhere, and the
+        picks of dead tokens (``token_mask`` False), become rows past
+        the last group: no weights are read for them and they add zero.
+        The buffer is sized for the worst case, every pick held
+        (N * top_k rows); what is computed follows the group sizes."""
+        from .....kernels.moe import grouped_ffn_gated
+        from .gate import sigmoid_topk_routing
+        gate, ex = self.gate, self.experts
+        k, lo, held_n = gate.top_k, self.first_held, self.num_held
+
+        def fn(tok, wr, bias, w1, w3, w2, *rest):
+            n = tok.shape[0]
+            live = (rest[0].reshape(-1).astype(bool) if rest
+                    else jnp.ones((n,), bool))
+            logits = jnp.dot(tok, wr.astype(tok.dtype),
+                             preferred_element_type=jnp.float32)
+            idx, w = sigmoid_topk_routing(
+                logits, bias, k, gate.norm_topk_prob,
+                gate.routed_scaling_factor)
+            local = idx - lo
+            held = (local >= 0) & (local < held_n) & live[:, None]
+            key = jnp.where(held, local, held_n).reshape(-1)
+            order = jnp.argsort(key, stable=True)
+            counts = jnp.sum(
+                key[:, None] == jnp.arange(held_n, dtype=key.dtype)[None],
+                axis=0, dtype=jnp.int32)
+            rows = grouped_ffn_gated(jnp.take(tok, order // k, axis=0),
+                                     w1, w3, w2, counts)
+            w_rows = jnp.where(held, w, 0.0).reshape(-1)[order]
+            rows = rows.astype(jnp.float32) * w_rows[:, None]
+            out = jnp.take(rows, jnp.argsort(order), axis=0) \
+                .reshape(n, k, -1).sum(axis=1)
+            stats = jnp.stack([jnp.sum(held), jnp.sum(live) * k,
+                               jnp.sum(counts > 0)]).astype(jnp.int32)
+            return out.astype(tok.dtype), stats
+
+        args = [tokens, self.gate_weight, self.e_score_correction_bias,
+                ex.w1, ex.w3, ex.w2]
+        if token_mask is not None:
+            args.append(token_mask)
+        out, self.last_stats = run_op("moe_sorted_ffn", fn, args)
+        out = out.reshape(orig_shape)
+        if self.shared_experts is not None:
+            out = out + self.shared_experts(tokens).reshape(orig_shape)
+        return out
+
     def forward(self, x, token_mask=None, decode_mode=False):
         """x: [batch, seq, h] or [N, h]. Bumps the trace-time
         `kernels.moe.dispatch_path.*` counter for whichever dispatch
@@ -474,6 +597,11 @@ class MoELayer(Layer):
         orig_shape = list(x.shape)
         h = orig_shape[-1]
         tokens = x.reshape([-1, h])
+        if self._sorted:
+            monitor.counter(
+                "kernels.moe.decode_path.ragged" if decode_mode
+                else "kernels.moe.dispatch_path.ragged").increase()
+            return self._forward_sorted(tokens, orig_shape, token_mask)
         if decode_mode:
             return self._forward_decode(tokens, orig_shape, token_mask)
         mode = self._dispatch_mode

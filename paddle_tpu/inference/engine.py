@@ -189,6 +189,9 @@ class SamplingParams:
     # occupying capacity forever
     deadline_ms: Optional[float] = None
     max_queue_steps: Optional[int] = None
+    # keep the float32 logits row each of this request's tokens was
+    # sampled from (Output.logits); needs Engine(keep_logits=True)
+    return_logits: bool = False
 
     def validate(self):
         if int(self.max_new_tokens) < 1:
@@ -229,6 +232,8 @@ class Output:
     # contiguous on the engine's injectable clock, origin-labeled per
     # span across migrations and failovers
     spans: List[dict] = field(default_factory=list)
+    # SamplingParams.return_logits: one [vocab] float32 row a token
+    logits: Optional[List[np.ndarray]] = None
 
     @property
     def ok(self) -> bool:
@@ -270,6 +275,8 @@ class Request:
     # clock, so the timeline serializes through snapshot/restore and
     # rides extract_request across workers/replicas untouched
     spans: List[dict] = field(default_factory=list)
+    # SamplingParams.return_logits: the rows, one a generated token
+    logits: List[np.ndarray] = field(default_factory=list)
 
     def resume_tokens(self) -> List[int]:
         """The prefix a (re-)prefill must write into the cache: the
@@ -305,7 +312,9 @@ def serving_model_spec(model) -> dict:
     ``kind`` ("decoder" | "encoder") plus, for decoders, the KV
     geometry (``num_layers`` / ``kv_heads`` / ``head_dim`` /
     ``max_context`` / ``vocab_size``) and optionally a ``moe`` block
-    (fused-dispatch eligibility diagnostics). Models WITHOUT the hook
+    (fused-dispatch eligibility diagnostics). A decoder whose layers
+    do not share one geometry gives ``cache_layers`` in place of
+    ``kv_heads`` / ``head_dim`` (see _make_spec_pools). Models WITHOUT the hook
     fall back to the llama-shaped config attribute read that used to
     be inlined in ``Engine.__init__`` — with a loud error naming the
     missing attributes instead of an AttributeError mid-constructor."""
@@ -313,8 +322,13 @@ def serving_model_spec(model) -> dict:
     if callable(fn):
         spec = dict(fn())
         if spec.get("kind") == "decoder":
-            missing = [k for k in ("num_layers", "kv_heads", "head_dim",
-                                   "max_context")
+            # a spec that gives its cache PER LAYER ("cache_layers": one
+            # {"kind": "latent", "rows": (width, ...)} a layer) has no
+            # one kv_heads x head_dim to name
+            geometry = ("num_layers", "max_context") \
+                if spec.get("cache_layers") is not None else \
+                ("num_layers", "kv_heads", "head_dim", "max_context")
+            missing = [k for k in geometry
                        if spec.get(k) is None]
             if missing:
                 raise ValueError(
@@ -378,6 +392,30 @@ def _make_paged_pools(layers, rows, hkv, page_size, hd, dtype, quant):
         for _ in range(layers)]
 
 
+def _make_spec_pools(spec, rows, page_size, dtype, quant):
+    """The page pools a serving spec asks for, one tuple a layer. A
+    spec with ONE geometry (``kv_heads`` x ``head_dim``: LLaMA, Mistral,
+    ERNIE-MoE) gets _make_paged_pools' (k, v[, ks, vs]) exactly. A spec
+    with ``cache_layers`` gets, for each layer, one pool
+    [rows, page_size, width] per entry of its ``rows``: one vector a
+    token and no head dimension (a latent-attention layer's
+    [c_kv ; k_rope] row, its indexer's key). The same block tables, the
+    same in-place write (_scatter_tokens) and the same page walk serve
+    both kinds."""
+    layers = spec.get("cache_layers")
+    if layers is None:
+        return _make_paged_pools(
+            int(spec["num_layers"]), rows, int(spec["kv_heads"]),
+            page_size, int(spec["head_dim"]), dtype, quant)
+    for layer in layers:
+        if layer.get("kind") != "latent":
+            raise ValueError(
+                f"cache_layers entry {layer!r}: only kind 'latent' (one "
+                f"row a token per pool) is known")
+    return [tuple(jnp.zeros((rows, page_size, int(w)), dtype)
+                  for w in layer["rows"]) for layer in layers]
+
+
 @dataclass
 class _PendingTick:
     """One in-flight decode dispatch (the pipelined tick loop's
@@ -397,6 +435,7 @@ class _PendingTick:
     dev_mark: float           # self._device_s at dispatch
     k: int = 0                # spec: draft len / multi: fused ticks
     variant: str = "greedy"   # sampler variant of the executable
+    extras: tuple = ()        # _tick_extras outputs, still on the device
 
 
 class _Emitted:
@@ -495,7 +534,8 @@ class Engine:
                  debug_invariants: Optional[bool] = None,
                  max_prefill_tokens_per_step: Optional[int] = None,
                  multi_tick: int = 1,
-                 label: Optional[str] = None):
+                 label: Optional[str] = None,
+                 keep_logits: bool = False):
         # model polymorphism (docs/SERVING.md): geometry comes from the
         # serving_spec probe, not hard-coded llama config attribute
         # names — an encoder or a spec-less model gets a pointed error
@@ -522,6 +562,26 @@ class Engine:
                 "generation instead")
         self.serving_spec = spec
         self.model = model
+        # a per-layer (latent) cache spec: what this engine does not yet
+        # do for it is refused by name, not run wrong
+        self._latent = spec.get("cache_layers") is not None
+        if self._latent:
+            for option, on in (("prefix_cache", bool(prefix_cache)),
+                               ("draft_model", draft_model is not None),
+                               ("multi_tick", int(multi_tick) > 1),
+                               ("cache_dtype='int8'",
+                                str(cache_dtype) == "int8")):
+                if on:
+                    raise ValueError(
+                        f"{option} is not supported for "
+                        f"{type(model).__name__}'s per-layer latent "
+                        f"cache spec (docs/SERVING.md 'Model "
+                        f"polymorphism')")
+        # keep_logits: the decode and prefill programs also return the
+        # float32 logits they sampled from (left on the device; a row is
+        # fetched only for a request that set return_logits)
+        self.keep_logits = bool(keep_logits)
+        self._tick_stats = tuple(spec.get("tick_stats") or ())
         self.max_slots = int(max_slots)
         self.page_size = int(page_size)
         self.prefill_bucket = int(prefill_bucket)
@@ -574,8 +634,8 @@ class Engine:
                     get_frozen(model))
         self.cache_dtype = _resolve_cache_dtype(cache_dtype, self._st[0])
         self._quant = self.cache_dtype == jnp.dtype(jnp.int8)
-        hkv = int(spec["kv_heads"])
-        hd = int(spec["head_dim"])
+        hkv = 1 if self._latent else int(spec["kv_heads"])
+        hd = None if self._latent else int(spec["head_dim"])
         # pool row 0 is the scratch page (inactive lanes) — the
         # allocator hands out ids [1, pool_pages]
         rows = self.pool_pages + 1
@@ -633,9 +693,13 @@ class Engine:
                     self._mp_rep = NamedSharding(sh.mesh,
                                                  PartitionSpec())
                     break
-        self._pools = self._commit_pools(_make_paged_pools(
-            int(spec["num_layers"]), rows, hkv, self.page_size, hd,
-            self.cache_dtype, self._quant), hkv)
+        if self._latent and self._mp_degree > 1:
+            raise ValueError(
+                f"an mp={self._mp_degree} mesh is not supported for "
+                f"{type(model).__name__}'s per-layer latent cache spec")
+        self._pools = self._commit_pools(_make_spec_pools(
+            spec, rows, self.page_size, self.cache_dtype, self._quant),
+            hkv)
         S, MB = self.max_slots, self.max_blocks
         self._bt = np.zeros((S, MB), np.int32)
         self._pos = np.zeros((S,), np.int32)
@@ -753,8 +817,11 @@ class Engine:
         # instead of letting every decode step silently gather: an
         # ineligible geometry on a TPU backend costs a full-cache copy
         # per token and previously only showed up as slow numbers.
-        self.decode_fallback_reason = paged_pallas_requirements(
-            hd, self.page_size, self.cache_dtype)
+        # (a per-layer latent spec's kernel is the model's own matter:
+        # on a TPU it raises at trace time for a pool it cannot take)
+        self.decode_fallback_reason = None if self._latent else \
+            paged_pallas_requirements(hd, self.page_size,
+                                      self.cache_dtype)
         self.pallas_eligible = self.decode_fallback_reason is None
         if not self.pallas_eligible:
             monitor.counter("serving.decode_fallback").increase()
@@ -863,11 +930,41 @@ class Engine:
     def _inject_bt(self, caches, bt):
         """Pool tuples -> the model's per-layer paged cache tuples:
         (k, v, bt[, ks, vs]) — the block table is engine state, shared
-        by every layer, injected at call time."""
+        by every layer, injected at call time. A latent layer's tuple
+        is its pools with the block table last."""
+        if self._latent:
+            return [tuple(c) + (bt,) for c in caches]
         return [(c[0], c[1], bt) + tuple(c[2:]) for c in caches]
 
     def _strip_bt(self, kv):
+        if self._latent:
+            return [tuple(t[:-1]) for t in kv]
         return [(t[0], t[1]) + tuple(t[3:]) for t in kv]
+
+    def _tick_extras(self, cur, stats=True):
+        """What a decode or prefill program returns beyond today's
+        outputs, as one tuple (empty for a model and an engine that ask
+        for neither, so their programs are unchanged): the model's
+        ``tick_stats`` vector (decode ticks only), read off the model
+        right after its forward inside the same trace, and the logits
+        `cur` when keep_logits."""
+        extras = ()
+        if stats and self._tick_stats:
+            extras += (self.model.serving_tick_stats(),)
+        if self.keep_logits:
+            extras += (cur,)
+        return extras
+
+    def _take_extras(self, extras, stats=True):
+        """Host side of _tick_extras: add the stats to their counters
+        (one small fetch, with the tick's sync already behind it) and
+        hand back the logits array, still on the device, or None."""
+        extras = list(extras)
+        if stats and self._tick_stats:
+            for name, v in zip(self._tick_stats,
+                               np.asarray(extras.pop(0))):
+                self._mon.counter(name).increase(int(v))
+        return extras.pop(0) if self.keep_logits else None
 
     def _get_decode_fn(self, variant: str):
         """The fused [max_slots] decode executable — ONE compiled step
@@ -941,7 +1038,8 @@ class Engine:
                     use_filters=variant == "filtered")
             state2 = (nxt, pos + live, temps, topks, topps, keys2,
                       live)
-            return nxt, ok, state2, self._strip_bt(new_kv)
+            return (nxt, ok, state2, self._strip_bt(new_kv)) \
+                + self._tick_extras(cur)
 
         return body
 
@@ -1090,7 +1188,8 @@ class Engine:
             ok = jnp.isfinite(cur).all(axis=-1)
             nxt, keys2 = sample_token_arrays(
                 cur, keys, temps, topks, topps)
-            return nxt, keys2, ok, self._strip_bt(new_kv)
+            return (nxt, keys2, ok, self._strip_bt(new_kv)) \
+                + self._tick_extras(cur, stats=False)
 
         return body
 
@@ -1186,6 +1285,21 @@ class Engine:
         return hotpath_lint.emit_hotpath(
             hotpath_lint.lint_inventory(self._hotpath_inventory()))
 
+    def _latent_span_args(self, active) -> dict:
+        """`engine.decode.dispatch` arguments for a spec with a sparse
+        selection or a window: the tokens this dispatch's attention has
+        to read, a slot's context counted up to `index_topk` on the
+        layers that select (sel_tokens) and up to `window` on the
+        layers that slide (win_tokens)."""
+        spec = self.serving_spec
+        out = {}
+        for name, cap in (("sel_tokens", spec.get("index_topk")),
+                          ("win_tokens", spec.get("window"))):
+            if cap is not None:
+                out[name] = int(sum(min(int(self._pos[i]) + 1, int(cap))
+                                    for i, _ in active))
+        return out
+
     def _dispatch_steady(self, steady, fn, *args):
         """Dispatch one tick executable. On a STEADY tick (warm
         executable, no dirty rows, no fault poison) with
@@ -1211,6 +1325,11 @@ class Engine:
             if isinstance(params, dict):
                 params = SamplingParams(**params)
             params.validate()
+            if params.return_logits and not self.keep_logits:
+                raise ValueError(
+                    "SamplingParams.return_logits needs an engine built "
+                    "with keep_logits=True (its programs then return "
+                    "the logits they sample from)")
             prompt = _normalize_prompt(ids)
             # validate the whole lifetime's page demand UP FRONT, naming
             # the request and the pages it needs — an oversized request
@@ -1307,7 +1426,8 @@ class Engine:
                             slots=len(pending.active),
                             ctx_tokens=int(sum(
                                 self._pos[i] for i, _ in pending.active)),
-                            ticks=pending.ticks)
+                            ticks=pending.ticks,
+                            **self._latent_span_args(pending.active))
                 # (b) overlap window: tick-t+1 host scheduling runs while
                 # the device executes. Exactness is order-insensitive here
                 # (rows are independent; a request admitted now joins the
@@ -2102,7 +2222,7 @@ class Engine:
             # HOST time in the host-share gate
             mark = self._device_s
             t0 = time.perf_counter()
-            tok, key2, okf, self._pools = fn(
+            tok, key2, okf, self._pools, *extras = fn(
                 self._st, self._pools, bt_dev, prompt_dev,
                 jnp.asarray([T], jnp.int32), start_dev,
                 jnp.asarray([p.temperature], jnp.float32),
@@ -2119,6 +2239,7 @@ class Engine:
             with RecordEvent("engine.prefill.wait"):
                 self._sync_timed((tok, key2, okf), dispatch_t=t0,
                                  dev_mark=mark)
+            row = self._take_extras(extras, stats=False)
             self._mon.counter("serving.prefill_tokens").increase(pb)
             self._mon.counter("serving.prefill_slices").increase()
             self._pf_step_tokens += pb
@@ -2142,6 +2263,8 @@ class Engine:
                 t = int(np.asarray(tok)[0])
                 req.key = np.asarray(key2)[0].astype(np.uint32)
                 req.generated.append(t)
+                if req.params.return_logits:
+                    req.logits.append(np.asarray(row[0]))
                 req.first_token_t = req.last_token_t = self._clock()
                 self._mon.counter("serving.tokens").increase()
                 reason = self._finish_reason(req, t)
@@ -2350,13 +2473,15 @@ class Engine:
         # the fused step: forward + per-slot sampling + state advance
         # in ONE executable; only the emitted tokens (and the tiny
         # NaN-quarantine flags) come back
-        nxt, okv, self._dev, self._pools = self._dispatch_steady(
-            steady, fn, self._st, self._pools, self._bt_dev, self._dev,
-            self._poison_dev)
+        nxt, okv, self._dev, self._pools, *extras = \
+            self._dispatch_steady(
+                steady, fn, self._st, self._pools, self._bt_dev,
+                self._dev, self._poison_dev)
         self._unpoison()
         return _PendingTick(kind="single", data=(nxt, okv),
                             active=snap, ticks=1, t_dispatch=t0,
-                            dev_mark=mark, variant=variant)
+                            dev_mark=mark, variant=variant,
+                            extras=tuple(extras))
 
     def _multi_k(self, active: List[int], variant: str) -> int:
         """Eligibility ladder + per-dispatch clamp for the fused
@@ -2506,6 +2631,7 @@ class Engine:
                         emitted: _Emitted) -> List[Output]:
         nxt = np.asarray(pend.data[0])
         okv = np.asarray(pend.data[1])
+        rows = self._take_extras(pend.extras)
         # tokens appended here move budgets the device-resident
         # multi-tick aux never saw — next fused dispatch re-uploads
         self._aux_clean = False
@@ -2528,6 +2654,8 @@ class Engine:
             self._pos[i] = req.written
             emitted.append(req, tok)
             self._last[i] = tok
+            if req.params.return_logits:
+                req.logits.append(np.asarray(rows[i]))
             reason = self._finish_reason(req, tok)
             if reason:
                 outs.append(self._finish(req, reason))
@@ -2775,7 +2903,9 @@ class Engine:
                       finish_reason=reason, ttft_ms=ttft_ms,
                       tpot_ms=tpot_ms, preemptions=req.preemptions,
                       error=None if state == FINISHED else reason,
-                      spans=tracing.copy_spans(req.spans))
+                      spans=tracing.copy_spans(req.spans),
+                      logits=list(req.logits)
+                      if req.params.return_logits else None)
 
     def _publish_gauges(self):
         mon = self._mon
@@ -2784,6 +2914,15 @@ class Engine:
         mon.gauge("serving.queue_depth").set(len(self._waiting))
         mon.gauge("serving.prefill_tokens_per_step").set(
             self._pf_step_tokens)
+        window = self.serving_spec.get("window")
+        if window is not None:
+            # pages a windowed layer's pool holds that no later query
+            # can read any more (they stay allocated: one block table
+            # serves every layer), over the slots that are decoding
+            mon.gauge("serving.cache.swa_pages_outside_window").set(sum(
+                max(0, r.written - (int(window) - 1)) // self.page_size
+                for r in self._slots
+                if r is not None and r.state == DECODE))
         if self._prefix is not None:
             mon.gauge("serving.prefix_hit_rate").set(
                 self._prefix.hit_rate)

@@ -246,7 +246,8 @@ def _scatter_pages(pool, x, page):
     return pool.at[page].set(jnp.swapaxes(tiles, 2, 3))
 
 
-def _scatter_tokens(pools, chunks, block_tables, positions):
+def _scatter_tokens(pools, chunks, block_tables, positions,
+                    headless=False):
     """Write each [b, s, h_kv, ...] chunk into its head-major pool at
     the (page, slot) of ``positions``, in place in the pool's own
     layout. The granularity follows what the chunk is: rows
@@ -254,13 +255,30 @@ def _scatter_tokens(pools, chunks, block_tables, positions):
     whole-page tiles (_scatter_pages) plus rows for the ragged end when
     every sequence starts on a page boundary — a property of the traced
     ``positions``, so the choice is a ``lax.cond`` — and rows
-    otherwise. Both branches write the same elements."""
-    bs = pools[0].shape[2]
+    otherwise. Both branches write the same elements.
+
+    ``headless``: the pools are [num_blocks, block_size, w] and the
+    chunks [b, s, w], one vector a token and no head dimension (a
+    latent-attention layer's rows). The two indexed dimensions are then
+    the pool's two major ones as it stands. (A head dimension of size 1
+    would do in arithmetic, but gives the TPU compiler two names for one
+    layout, and it copies the whole pool from one to the other at the
+    ``cond``.)"""
+    bs = pools[0].shape[1 if headless else 2]
     s = chunks[0].shape[1]
     page, slot = _page_slots(block_tables, positions, s, bs)
+    if headless:
+        def row_fn(p, x, pg, sl):
+            return p.at[pg, sl].set(x)
+
+        def page_fn(p, x, pg):
+            b, n = pg.shape
+            return p.at[pg].set(x.reshape((b, n, bs) + x.shape[2:]))
+    else:
+        row_fn, page_fn = _scatter_rows, _scatter_pages
 
     def rows(pools, lo=0):
-        return tuple(_scatter_rows(p, x[:, lo:], page[:, lo:], slot[:, lo:])
+        return tuple(row_fn(p, x[:, lo:], page[:, lo:], slot[:, lo:])
                      for p, x in zip(pools, chunks))
 
     whole = s - s % bs
@@ -268,11 +286,30 @@ def _scatter_tokens(pools, chunks, block_tables, positions):
         return rows(pools)
 
     def pages(pools):
-        pools = tuple(_scatter_pages(p, x[:, :whole], page[:, :whole:bs])
+        pools = tuple(page_fn(p, x[:, :whole], page[:, :whole:bs])
                       for p, x in zip(pools, chunks))
         return rows(pools, whole) if whole < s else pools
 
     return jax.lax.cond(jnp.all(slot[:, 0] == 0), pages, rows, pools)
+
+
+def paged_write_rows(rows, pools, block_tables, positions):
+    """Append per-token vectors into head-less paged pools: rows[i]
+    [b, s, w_i] into pools[i] [num_blocks, block_size, w_i], token j of
+    sequence b at position positions[b] + j. The same in-place write as
+    paged_write_arrays (docs/DECODE.md "The KV write"). Returns the
+    updated pools."""
+    return _scatter_tokens(
+        tuple(pools), tuple(r.astype(p.dtype) for r, p in zip(rows, pools)),
+        block_tables, positions, headless=True)
+
+
+def gather_rows(pool, block_tables):
+    """gather_pages for a head-less pool [num_blocks, block_size, w]:
+    each sequence's pages as [b, L, w]."""
+    b, mb = block_tables.shape
+    g = jnp.take(pool, block_tables, axis=0)          # [b, mb, bs, w]
+    return g.reshape(b, mb * pool.shape[1], pool.shape[2])
 
 
 def paged_write_quant_arrays(k, v, k_cache, v_cache, k_scale, v_scale,
@@ -334,9 +371,10 @@ def _chunk_geometry(nblocks, bs, h_kv, d, itemsize,
 
 
 def _paged_decode_kernel(bt_ref, cl_ref, buf_ref, step_ref, q_ref,
-                         k_hbm, v_hbm, *refs,
+                         *refs,
                          batch, h_kv, bs, ppc, hpb, nchunks,
-                         scale, window, quant):
+                         scale, window, quant, latent_dv=None,
+                         masked=False):
     """One (slot, kv-head-block, page-chunk) program of multi-sequence
     single-token paged decode.
 
@@ -367,16 +405,29 @@ def _paged_decode_kernel(bt_ref, cl_ref, buf_ref, step_ref, q_ref,
     [2, hpb, ppc, bs, d] (+ scale buffers [2, hpb, ppc, 1, bs]), one DMA
     semaphore per buffer slot, online-softmax m/l [hpb, rep, 128] and
     acc [hpb, rep, d].
+
+    latent_dv (paged_mla_decode): the pool holds ONE latent row a token
+    and no heads (h_kv 1, every query head reads the same row): keys are
+    the whole row, values its first latent_dv entries, so there is no V
+    pool, no V copy and no V buffer, and o/acc are latent_dv wide. The
+    dots then keep the pool's dtype as operands (f32 accumulation): at
+    128 query heads a row the kernel is as near the MXU's bound as the
+    HBM's. masked adds a [1, T] int32 keep-row per (slot, chunk) before
+    the pools: the selected set of a sparse-attention layer.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if quant:
-        (ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf, sems,
-         m_ref, l_ref, acc_ref) = refs
-    else:
-        ks_hbm = vs_hbm = ksbuf = vsbuf = None
-        o_ref, kbuf, vbuf, sems, m_ref, l_ref, acc_ref = refs
+    refs = list(refs)
+    latent = latent_dv is not None
+    mask_ref = refs.pop(0) if masked else None
+    k_hbm = refs.pop(0)
+    v_hbm = None if latent else refs.pop(0)
+    ks_hbm, vs_hbm = (refs.pop(0), refs.pop(0)) if quant else (None, None)
+    o_ref, kbuf = refs.pop(0), refs.pop(0)
+    vbuf = None if latent else refs.pop(0)
+    ksbuf, vsbuf = (refs.pop(0), refs.pop(0)) if quant else (None, None)
+    sems, m_ref, l_ref, acc_ref = refs
 
     i = pl.program_id(0)          # slot (sequence / decode lane)
     hb = pl.program_id(1)         # kv-head block
@@ -400,9 +451,10 @@ def _paged_decode_kernel(bt_ref, cl_ref, buf_ref, step_ref, q_ref,
             out.append(pltpu.make_async_copy(
                 k_hbm.at[page, pl.ds(hs, hpb)],
                 kbuf.at[buf, :, p], sems.at[buf]))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[page, pl.ds(hs, hpb)],
-                vbuf.at[buf, :, p], sems.at[buf]))
+            if not latent:
+                out.append(pltpu.make_async_copy(
+                    v_hbm.at[page, pl.ds(hs, hpb)],
+                    vbuf.at[buf, :, p], sems.at[buf]))
             if quant:
                 out.append(pltpu.make_async_copy(
                     ks_hbm.at[page, pl.ds(hs, hpb)],
@@ -473,8 +525,13 @@ def _paged_decode_kernel(bt_ref, cl_ref, buf_ref, step_ref, q_ref,
         for c in copies(i, hb, j, buf):
             c.wait()
         q = q_ref[...].astype(jnp.float32) * jnp.float32(scale)
-        k = kbuf[buf].reshape(hpb, T, d).astype(jnp.float32)
-        v = vbuf[buf].reshape(hpb, T, d).astype(jnp.float32)
+        if latent:
+            k = kbuf[buf].reshape(hpb, T, d)
+            v = k[:, :, :latent_dv]
+            q = q.astype(k.dtype)
+        else:
+            k = kbuf[buf].reshape(hpb, T, d).astype(jnp.float32)
+            v = vbuf[buf].reshape(hpb, T, d).astype(jnp.float32)
         # batched-over-heads skinny dots, f32 accumulation
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
@@ -497,6 +554,8 @@ def _paged_decode_kernel(bt_ref, cl_ref, buf_ref, step_ref, q_ref,
         keep = k_pos <= pos
         if window is not None:
             keep = jnp.logical_and(keep, pos - k_pos < jnp.int32(window))
+        if masked:
+            keep = jnp.logical_and(keep, (mask_ref[...] != 0)[None])
         s = jnp.where(keep, s, neg_inf)
 
         m_prev = m_ref[:, :, :1]
@@ -506,6 +565,8 @@ def _paged_decode_kernel(bt_ref, cl_ref, buf_ref, step_ref, q_ref,
         p = jnp.where(s > neg_inf * 0.5, p, 0.0)
         alpha = jnp.exp(m_prev - m_cur)
         l_cur = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
+        if latent:
+            p = p.astype(v.dtype)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p * lane_row(vsbuf) if quant else p, v,
             (((2,), (1,)), ((0,), (0,))),
@@ -541,6 +602,23 @@ def paged_decode_pallas(q, k_cache, v_cache, block_tables, context_lens,
     paged_pallas_eligible(d, block_size, k_cache.dtype);
     pages_per_chunk/kv_heads_per_block override the auto tiling (each
     must divide its dimension)."""
+    return _paged_decode_call(
+        "paged_decode", q, k_cache, v_cache, block_tables, context_lens,
+        scale=scale, window=window, interpret=interpret, k_scale=k_scale,
+        v_scale=v_scale, pages_per_chunk=pages_per_chunk,
+        kv_heads_per_block=kv_heads_per_block)
+
+
+def _paged_decode_call(name, q, k_cache, v_cache, block_tables,
+                       context_lens, scale=None, window=None,
+                       interpret=False, k_scale=None, v_scale=None,
+                       pages_per_chunk=None, kv_heads_per_block=None,
+                       latent_dv=None, mask=None):
+    """The one pallas_call behind paged_decode_pallas and
+    paged_mla_decode: the same grid, page walk and online softmax;
+    `v_cache` None with `latent_dv` set is the latent pool whose rows
+    are keys and (their first latent_dv entries) values, `mask`
+    [b, max_blocks * block_size] a keep-set on top of the causal one."""
     import functools
 
     from jax.experimental import pallas as pl
@@ -553,6 +631,8 @@ def paged_decode_pallas(q, k_cache, v_cache, block_tables, context_lens,
     nblocks = block_tables.shape[1]
     rep = h // h_kv
     quant = k_scale is not None
+    latent = latent_dv is not None
+    dv = int(latent_dv) if latent else d
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     ppc, hpb = _chunk_geometry(nblocks, bs, h_kv, d,
@@ -572,29 +652,33 @@ def paged_decode_pallas(q, k_cache, v_cache, block_tables, context_lens,
     kernel = functools.partial(
         _paged_decode_kernel, batch=b, h_kv=h_kv, bs=bs, ppc=ppc,
         hpb=hpb, nchunks=nchunks, scale=float(scale),
-        window=None if window is None else int(window), quant=quant)
-    blk = pl.BlockSpec((None, hpb, rep, d),
-                       lambda i, hb, j, *_: (i, hb, 0, 0))
-    in_specs = [
-        blk,                                               # q
-        pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-        pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-    ]
-    inputs = [qr, k_cache, v_cache]
+        window=None if window is None else int(window), quant=quant,
+        latent_dv=dv if latent else None, masked=mask is not None)
+    any_space = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+    in_specs = [pl.BlockSpec((None, hpb, rep, d),
+                             lambda i, hb, j, *_: (i, hb, 0, 0))]   # q
+    inputs = [qr]
+    if mask is not None:
+        # one [1, T] keep-row per (slot, chunk), tokens on lanes like s
+        in_specs.append(pl.BlockSpec((None, 1, ppc * bs),
+                                     lambda i, hb, j, *_: (i, 0, j)))
+        inputs.append(jnp.asarray(mask, jnp.int32)
+                      .reshape(b, 1, nblocks * bs))
+    in_specs.append(any_space)
+    inputs.append(k_cache)
+    if not latent:
+        in_specs.append(any_space)
+        inputs.append(v_cache)
     if quant:
-        in_specs += [
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-        ]
+        in_specs += [any_space, any_space]
         # one [1, bs] row per (page, head): the page index stays a
         # major dimension of the VMEM buffer the rows are copied into,
         # so picking a page there never cuts through a tile
         inputs += [k_scale.reshape(nb, h_kv, 1, bs),
                    v_scale.reshape(nb, h_kv, 1, bs)]
-    scratch = [
-        pltpu.VMEM((2, hpb, ppc, bs, d), k_cache.dtype),
-        pltpu.VMEM((2, hpb, ppc, bs, d), v_cache.dtype),
-    ]
+    scratch = [pltpu.VMEM((2, hpb, ppc, bs, d), k_cache.dtype)]
+    if not latent:
+        scratch.append(pltpu.VMEM((2, hpb, ppc, bs, d), v_cache.dtype))
     if quant:
         scratch += [pltpu.VMEM((2, hpb, ppc, 1, bs), jnp.float32),
                     pltpu.VMEM((2, hpb, ppc, 1, bs), jnp.float32)]
@@ -602,7 +686,7 @@ def paged_decode_pallas(q, k_cache, v_cache, block_tables, context_lens,
         pltpu.SemaphoreType.DMA((2,)),
         pltpu.VMEM((hpb, rep, 128), jnp.float32),
         pltpu.VMEM((hpb, rep, 128), jnp.float32),
-        pltpu.VMEM((hpb, rep, d), jnp.float32),
+        pltpu.VMEM((hpb, rep, dv), jnp.float32),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # bt, cl, plus two MUTABLE scalar cells the kernel uses as
@@ -611,22 +695,102 @@ def paged_decode_pallas(q, k_cache, v_cache, block_tables, context_lens,
         num_scalar_prefetch=4,
         grid=(b, nhb, nchunks),
         in_specs=in_specs,
-        out_specs=blk,
+        out_specs=pl.BlockSpec((None, hpb, rep, dv),
+                               lambda i, hb, j, *_: (i, hb, 0, 0)),
         scratch_shapes=scratch,
     )
     with _x32_trace():
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, h_kv, rep, d), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((b, h_kv, rep, dv), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary",
                                      "arbitrary")),
             interpret=interpret,
-            name="paged_decode",
+            name=name,
         )(bt, cl, jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
           *inputs)
-    return out.reshape(b, h, d)
+    return out.reshape(b, h, dv)
+
+
+def paged_mla_requirements(row_width, latent_dv, block_size, cache_dtype):
+    """Which constraint a latent page pool misses for paged_mla_decode,
+    or None: rows and their value part must fill whole 128-lane tiles
+    (the model pads its row to that, see Dots3NoteForCausalLM), pages
+    whole sublane tiles of the pool's dtype."""
+    name = jnp.dtype(cache_dtype).name
+    sublane = {"bfloat16": 16, "float16": 16}.get(name, 8)
+    problems = []
+    if name == "int8":
+        problems.append("an int8 latent pool is not supported")
+    if row_width % 128 or latent_dv % 128:
+        problems.append(
+            f"row width {row_width} / value width {latent_dv} is not a "
+            f"multiple of the 128 lane width")
+    if block_size % sublane:
+        problems.append(
+            f"page_size {block_size} is not a multiple of the {name} "
+            f"sublane minimum {sublane}")
+    return "; ".join(problems) if problems else None
+
+
+def window_pages(block_tables, first_page, n_pages):
+    """The `n_pages` block-table entries from column `first_page` [b]
+    on (clamped into the table): the pages a layer has to read whose
+    keys start on that page."""
+    cols = jnp.minimum(first_page[:, None] + jnp.arange(n_pages)[None],
+                       block_tables.shape[1] - 1)
+    return jnp.take_along_axis(block_tables, cols, axis=1)
+
+
+def paged_mla_arrays(q, pool, block_tables, context_lens, latent_dv,
+                     scale, window=None, mask=None):
+    """XLA formulation of paged_mla_decode (the reference the kernel is
+    tested against, and the path off the TPU): q [b, h, w] against each
+    slot's gathered latent rows [b, L, w]; values are the rows' first
+    latent_dv entries. Gathers (copies) every page the table names."""
+    rows = gather_rows(pool, block_tables)                    # [b, L, w]
+    L = rows.shape[1]
+    cdt = rows.dtype if rows.dtype in (jnp.bfloat16, jnp.float16) \
+        else jnp.float32
+    qs = (q.astype(jnp.float32) * jnp.float32(scale)).astype(cdt)
+    logits = jnp.einsum("bhw,bLw->bhL", qs, rows.astype(cdt),
+                        preferred_element_type=jnp.float32)
+    k_pos = jnp.arange(L, dtype=jnp.int32)[None]
+    pos = context_lens[:, None].astype(jnp.int32) - 1
+    keep = k_pos <= pos
+    if window is not None:
+        keep &= pos - k_pos < window
+    if mask is not None:
+        keep &= mask != 0
+    logits = jnp.where(keep[:, None], logits, NEG_INF)
+    p = jax.nn.softmax(logits, axis=-1)
+    p = jnp.where(keep[:, None], p, 0.0)
+    out = jnp.einsum("bhL,bLc->bhc", p.astype(cdt),
+                     rows[..., :latent_dv].astype(cdt),
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
+def paged_mla_decode(q, pool, block_tables, context_lens, latent_dv,
+                     scale, window=None, mask=None, interpret=False,
+                     pages_per_chunk=None):
+    """Pallas paged LATENT decode (multi-head latent attention with the
+    up-projection absorbed into q and the output): q [b, h, w], one
+    token a slot and all h heads, against the slot's pages of the latent
+    pool [num_blocks, block_size, w]. Every page is read once; a
+    row is the key of all heads and its first latent_dv entries their
+    value. Keys are masked to context_lens, to `window` (the last
+    `window` positions, the query's included) and to `mask`
+    [b, max_blocks * block_size] (nonzero = in the selected set).
+    Returns [b, h, latent_dv]. Shares paged_decode_pallas's kernel body
+    and page walk; dead slots (context_len 0) cost nothing."""
+    return _paged_decode_call(
+        "paged_mla_decode", q, pool[:, None], None, block_tables,
+        context_lens,
+        scale=scale, window=window, interpret=interpret,
+        pages_per_chunk=pages_per_chunk, latent_dv=latent_dv, mask=mask)
 
 
 def paged_attention(query, k_cache, v_cache, block_tables, context_lens,

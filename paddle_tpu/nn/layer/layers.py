@@ -11,6 +11,7 @@ without a separate static-graph world.
 from __future__ import annotations
 
 import collections
+import contextlib
 from typing import Callable, Iterator, Optional
 
 import jax.numpy as jnp
@@ -29,6 +30,24 @@ class HookRemoveHelper:
 
     def remove(self):
         self._hooks.pop(self._hook_id, None)
+
+
+# dtype that create_parameter gives a parameter no layer named one for,
+# while a `param_dtype` block is open (None: the layer's own default)
+_construct_dtype = None
+
+
+@contextlib.contextmanager
+def param_dtype(dtype):
+    """Build the layers constructed inside in `dtype` from the start: a
+    model too large to exist in float32 first (``net.astype`` needs the
+    float32 copy to fit beside the result) is built under this."""
+    global _construct_dtype
+    before, _construct_dtype = _construct_dtype, dtype
+    try:
+        yield
+    finally:
+        _construct_dtype = before
 
 
 class Layer:
@@ -121,7 +140,7 @@ class Layer:
         attr = ParamAttr._to_attr(attr)
         if attr is False:
             return None
-        dtype = dtype or self._dtype or "float32"
+        dtype = dtype or _construct_dtype or self._dtype or "float32"
         initializer = attr.initializer or default_initializer
         if initializer is None:
             glob = (init_mod.global_bias_initializer() if is_bias

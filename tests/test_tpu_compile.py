@@ -23,11 +23,14 @@ from paddle_tpu.distributed import mesh as mesh_mod
 from paddle_tpu.kernels import moe
 from paddle_tpu.kernels.flash_attention import flash_attention_arrays
 from paddle_tpu.kernels.paged_attention import (gather_page_scales,
-                                                gather_pages,
+                                                gather_pages, gather_rows,
                                                 paged_decode_pallas,
+                                                paged_mla_decode,
                                                 paged_pallas_requirements,
                                                 paged_write_arrays,
-                                                paged_write_quant_arrays)
+                                                paged_write_quant_arrays,
+                                                paged_write_rows,
+                                                window_pages)
 
 
 @pytest.fixture(scope="module")
@@ -186,6 +189,82 @@ def test_paged_write_updates_the_pool_in_place(one_chip, pool_dtype,
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < one_pool / 10
     assert mem.alias_size_in_bytes >= 2 * one_pool
+
+
+# the latent cell's pools (benchmark/configs/dots3-note-serve-5l.json):
+# 48 slots x 40 pages + the scratch page, page 128, one vector a token:
+# a full layer's 640-wide row and its indexer's 128-wide key, a sliding
+# layer's 1152-wide row read through its 5 window pages
+LATENT_PAGES, LATENT_BT = 1921, 40
+LATENT_LAYERS = {
+    "full": dict(heads=128, rows=(640, 128), dv=512, window=None),
+    "sliding": dict(heads=64, rows=(1152,), dv=1024, window=513),
+}
+
+
+def _latent_write_then_attend(kind, q, new, bt, pos, mask, *pools):
+    """One latent layer's cache step as Dots3LatentAttention runs it:
+    the head-less write, then the latent decode kernel for one token a
+    slot (a full layer under the selected-set mask, a sliding one over
+    its window's pages) or the gathers of a prefill chunk."""
+    cfg = LATENT_LAYERS[kind]
+    pools = paged_write_rows(new, pools, bt, pos)
+    window, page = cfg["window"], pools[0].shape[1]
+    s = new[0].shape[1]
+    bt_r, base = bt, jnp.zeros_like(pos)
+    if window is not None:
+        first = jnp.maximum(pos - (window - 1), 0) // page
+        n_win = (window + s - 3) // page + 2
+        bt_r, base = window_pages(bt, first, n_win), first * page
+    if s == 1:
+        out = paged_mla_decode(
+            q, pools[0], bt_r, pos + 1 - base, cfg["dv"], 0.07,
+            window=window, mask=mask if window is None else None,
+            pages_per_chunk=None if window is None else bt_r.shape[1])
+        out = [out] + [gather_rows(p, bt) for p in pools[1:]]
+    else:
+        out = [gather_rows(pools[0], bt_r)] \
+            + [gather_rows(p, bt) for p in pools[1:]]
+    return out, pools
+
+
+@pytest.mark.parametrize("tokens", [1, 512, 1024],
+                         ids=["one-token-48-slots", "chunk-512",
+                              "chunk-1024"])
+@pytest.mark.parametrize("kind", list(LATENT_LAYERS))
+def test_latent_layer_cache_step_compiles_in_place(one_chip, kind, tokens):
+    """`paged_mla_decode` compiles at the published widths (128 heads on
+    a 640-wide row under a mask, 64 heads on a 1152-wide row over the
+    window's 5 pages) and the head-less write lands in the donated pools
+    where they lie: no copy of a pool's shape, decode or prefill chunk.
+    (With the pools laid out [pages, 1, page, width] the engine's
+    compiled prefill programs copied every pool at the write's `cond`:
+    the size-1 head dimension gives the compiler two names for one
+    layout. PERF.md section 6, PR 29.)"""
+    import functools
+    cfg = LATENT_LAYERS[kind]
+    b = 48 if tokens == 1 else 1
+    pools = [((LATENT_PAGES, 128, w), jnp.bfloat16) for w in cfg["rows"]]
+    shapes = [((b, cfg["heads"], cfg["rows"][0]), jnp.bfloat16),
+              [((b, tokens, w), jnp.bfloat16) for w in cfg["rows"]],
+              ((b, LATENT_BT), jnp.int32), ((b,), jnp.int32),
+              ((b, LATENT_BT * 128), jnp.int32)] + pools
+    def struct(sd):
+        return jax.ShapeDtypeStruct(sd[0], sd[1], sharding=one_chip)
+    args = [[struct(x) for x in sd] if isinstance(sd, list) else struct(sd)
+            for sd in shapes]
+    compiled = jax.jit(
+        functools.partial(_latent_write_then_attend, kind),
+        donate_argnums=tuple(range(5, 5 + len(pools)))).lower(*args).compile()
+    text = compiled.as_text()
+    assert ("%paged_mla_decode" in text) == (tokens == 1)
+    pool_copy = re.compile(
+        rf"= \w+\[{LATENT_PAGES},(1,)?128,\d+\]\{{[^}}]*\}} copy\(")
+    assert [line.strip()[:120] for line in text.splitlines()
+            if pool_copy.search(line)] == []
+    mem = compiled.memory_analysis()
+    all_pools = sum(math.prod(s) * 2 for s, _ in pools)
+    assert mem.alias_size_in_bytes >= all_pools
 
 
 def _moe_shapes(e=8, cap=8192, h=768, dff=3072):
