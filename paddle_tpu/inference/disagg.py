@@ -89,7 +89,7 @@ from ..profiler.stats import CompileTracker
 from . import tracing
 from .engine import (FAILED, FINISHED, PREEMPTED, WAITING, Engine,
                      Output, Request, SamplingParams, _ceil_div,
-                     _normalize_prompt)
+                     _normalize_prompt, host_prng_key)
 
 #: lifecycle state between a finished prefill and decode admission:
 #: the request holds its prefill-worker pages (the migration source)
@@ -113,7 +113,7 @@ def replay_rng_key(seed: int, n_generated: int,
     ``split(key)[0]`` as the chain. So a dead worker's in-flight rng
     state is a pure function of (seed, tokens emitted) — the
     failover path re-admits without ever reading the lost device."""
-    key = jax.random.PRNGKey(int(seed))
+    key = host_prng_key(seed)
     if float(temperature) > 0.0:
         for _ in range(int(n_generated)):
             key = jax.random.split(key)[0]
@@ -482,8 +482,7 @@ class DisaggEngine:
                 f"prefill worker pool has {ppool}")
         req = Request(req_id=rid, prompt=prompt, params=params,
                       arrival_t=self._clock(), queued_step=self._steps)
-        req.key = np.asarray(jax.random.PRNGKey(int(params.seed)),
-                             np.uint32)
+        req.key = host_prng_key(params.seed)
         tracing.open_span(req.spans, tracing.QUEUED,
                           req.arrival_t * 1e3, self.label)
         self._next_id += 1
@@ -1025,6 +1024,9 @@ class DisaggEngine:
                 q = self._queues[tenant] = deque()
                 self._rr.append(tenant)
             q.appendleft(req)
+        # the tick in flight dies with the worker, unharvested: every
+        # request above left with the tokens the host held
+        w._inflight = None
         w.close()
         fleet[index] = None
         return n
@@ -1309,7 +1311,9 @@ class DisaggEngine:
     def idle(self) -> bool:
         return (self.num_waiting == 0 and self.num_active == 0
                 and self.num_prefilling == 0
-                and self.num_migrating == 0)
+                and self.num_migrating == 0
+                # a decode worker's tick in flight is still to harvest
+                and all(w.idle for w in self.decode if w is not None))
 
     @property
     def pages_free(self) -> Dict[str, int]:

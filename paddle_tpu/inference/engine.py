@@ -208,6 +208,9 @@ class SamplingParams:
             raise ValueError(
                 f"max_queue_steps must be >= 1, got "
                 f"{self.max_queue_steps}")
+        if not -2 ** 63 <= int(self.seed) < 2 ** 63:
+            raise ValueError(
+                f"seed must fit 64 signed bits, got {self.seed}")
 
 
 @dataclass
@@ -377,6 +380,25 @@ def _normalize_prompt(ids) -> List[int]:
     return prompt
 
 
+def host_prng_key(seed: int) -> np.ndarray:
+    """``jax.random.PRNGKey(seed)`` as a [2] uint32 host array, built
+    WITHOUT the device: ``PRNGKey`` is a device program plus a fetch,
+    and with a decode tick always in flight (Engine.step) that fetch
+    queues behind the tick and stalls the caller of ``add_request``
+    for the rest of it. For the default threefry implementation the
+    key is the seed's two 32-bit halves (``jax_random_seed_offset``
+    added first, as jax does); any other configured implementation
+    falls back to ``PRNGKey`` itself. The one key constructor of every
+    serving front door (Engine, DisaggEngine, ServingFleet)."""
+    seed = int(seed)
+    if jax.config.jax_default_prng_impl == "threefry2x32" \
+            and jax.config.jax_enable_x64:
+        bits = (seed + int(jax.config.jax_random_seed_offset)) \
+            & (2 ** 64 - 1)
+        return np.array([bits >> 32, bits & 0xFFFFFFFF], np.uint32)
+    return np.asarray(jax.random.PRNGKey(seed), np.uint32)
+
+
 def _make_paged_pools(layers, rows, hkv, page_size, hd, dtype, quant):
     """Per-layer paged KV pool tuples — (k, v[, ks, vs]) zeros in the
     head-major layout kernels/paged_attention.py expects. The ONE
@@ -436,6 +458,8 @@ class _PendingTick:
     k: int = 0                # spec: draft len / multi: fused ticks
     variant: str = "greedy"   # sampler variant of the executable
     extras: tuple = ()        # _tick_extras outputs, still on the device
+    # `engine.decode.dispatch` arguments: what the program reads
+    span_args: dict = field(default_factory=dict)
 
 
 class _Emitted:
@@ -709,17 +733,23 @@ class Engine:
         self._topps = np.zeros((S,), np.float32)
         self._keys = np.zeros((S, 2), np.uint32)
         self._live = np.zeros((S,), np.int32)
+        # per-slot eos token id (-1 = none; emitted ids are >= 0 so -1
+        # never matches) and the max_new_tokens budget left at
+        # activation: the programs count the budget down in-graph (an
+        # eos zeroes it), so a lane goes DEAD on the tick after its
+        # request's last token without the host saying so — what lets
+        # a tick be dispatched before the one before it is harvested
+        self._eos = np.full((S,), -1, np.int32)
+        self._bud = np.zeros((S,), np.int32)
         # the decode state — (last, pos, temps, topks, topps, keys,
-        # live) — LIVES ON DEVICE between ticks: the fused decode
-        # executable advances it in place (donated), so a steady-state
-        # tick ships nothing host→device and fetches only the emitted
-        # tokens. The numpy mirrors above are the scheduler's view;
-        # rows the scheduler touches are marked dirty and merged in
-        # before the next decode step (_flush_state).
-        self._dev = (self._up(self._last), self._up(self._pos),
-                     self._up(self._temps), self._up(self._topks),
-                     self._up(self._topps), self._up(self._keys),
-                     self._up(self._live))
+        # live, eos, budget) — LIVES ON DEVICE between ticks: the fused
+        # decode executable advances it in place (donated), so a
+        # steady-state tick ships nothing host→device and fetches only
+        # the emitted tokens. The numpy mirrors above are the
+        # scheduler's view; rows the scheduler touches are marked
+        # dirty and merged in before the next decode step
+        # (_flush_state).
+        self._dev = tuple(self._up(m) for m in self._mirrors())
         self._dirty: set = set()
         self._bt_dev = self._up(self._bt)
         self._bt_dirty = False
@@ -786,19 +816,15 @@ class Engine:
         self._poison_zeros = self._up(np.zeros((S,), np.float32))
         self._poison_dev = self._poison_zeros
         self._poisoned = False
-        # multi-tick aux state, DEVICE-RESIDENT between fused
-        # dispatches: per-slot eos token id (-1 = none; emitted ids
-        # are >= 0 so -1 never matches) and the remaining
-        # max_new_tokens budget. The scan decrements the budget
-        # in-graph (an eos zeroes it), so consecutive fused dispatches
-        # upload nothing; any host-side slot touch (_activate /
-        # _clear_slot) or token emitted OUTSIDE the fused path
-        # (single-tick / spec harvest) marks it stale and the next
-        # fused dispatch re-uploads the two [max_slots] vectors.
         self._multi_fns: Dict[int, object] = {}
-        self._aux_dev = (self._up(np.full((S,), -1, np.int32)),
-                        self._up(np.zeros((S,), np.int32)))
-        self._aux_clean = False
+        # run-ahead (docs/SERVING.md "Dispatch pipelining"): the
+        # single-tick dispatch still on the device when step() returns;
+        # the next step() dispatches its successor BEFORE waiting for
+        # it, so the device never idles through the host's harvest.
+        # _held: Outputs of ticks harvested outside a step (a forced
+        # drain), handed out by the next step()
+        self._inflight: Optional[_PendingTick] = None
+        self._held: List[Output] = []
         # dispatch-pipelining attribution (see _sync_timed): host work
         # that ran while the device was still executing the in-flight
         # dispatch — hidden under device time, published as the
@@ -875,6 +901,12 @@ class Engine:
                         RuntimeWarning, stacklevel=2)
 
     # -- compiled step shapes ------------------------------------------------
+
+    def _mirrors(self):
+        """The host mirrors, in the device state's order."""
+        return (self._last, self._pos, self._temps, self._topks,
+                self._topps, self._keys, self._live, self._eos,
+                self._bud)
 
     def _up(self, x):
         """Host→device upload of engine state, committed to the
@@ -1009,7 +1041,7 @@ class Engine:
         model = self.model
 
         def body(st, caches, bt, state, poison):
-            last, pos, temps, topks, topps, keys, live = state
+            last, pos, temps, topks, topps, keys, live, eosv, bud = state
             kv = self._inject_bt(caches, bt)
             # idle lanes ride at cache_index -1: their context_lens
             # (pos + 1) is then 0, so the multi-sequence decode kernel
@@ -1018,8 +1050,12 @@ class Engine:
             # lanes advance their position; an idle lane's pos must
             # not drift upward tick over tick (it would re-enter the
             # kernel as a growing fake context and stream scratch
-            # pages forever).
-            idx = jnp.where(live > 0, pos, -jnp.ones_like(pos))
+            # pages forever). A lane whose budget ran out (or whose
+            # last token was its eos) is idle the same way: the host
+            # learns of the finish a tick late (run-ahead) and this
+            # tick was dispatched with the lane still marked live.
+            alive = (live > 0) & (bud > 0)
+            idx = jnp.where(alive, pos, -jnp.ones_like(pos))
             logits, new_kv = _model_forward(model, st, last[:, None],
                                             kv, idx)
             # poison (normally all zeros, NaN at a fault-injected
@@ -1036,8 +1072,12 @@ class Engine:
                 nxt, keys2 = sample_token_arrays(
                     cur, keys, temps, topks, topps,
                     use_filters=variant == "filtered")
-            state2 = (nxt, pos + live, temps, topks, topps, keys2,
-                      live)
+            bud2 = jnp.where(alive, jnp.where(nxt == eosv,
+                                              jnp.zeros_like(bud),
+                                              bud - 1), bud)
+            state2 = (jnp.where(alive, nxt, last),
+                      pos + alive.astype(pos.dtype), temps, topks,
+                      topps, keys2, live, eosv, bud2)
             return (nxt, ok, state2, self._strip_bt(new_kv)) \
                 + self._tick_extras(cur)
 
@@ -1055,7 +1095,7 @@ class Engine:
         if fn is not None:
             return fn
         fn = jax.jit(_named(self._multi_body(k), f"serve_multi_{k}"),
-                     donate_argnums=(1, 3, 4))
+                     donate_argnums=(1, 3))
         self._multi_fns[k] = fn
         self._note_compile()
         return fn
@@ -1076,9 +1116,8 @@ class Engine:
         the exact step the NaN appeared."""
         model = self.model
 
-        def body(st, caches, bt, state, aux, poison):
-            last, pos, temps, topks, topps, keys, live = state
-            eosv, bud = aux
+        def body(st, caches, bt, state, poison):
+            last, pos, temps, topks, topps, keys, live, eosv, bud = state
 
             def step(carry, _):
                 tok, kv, p, b = carry
@@ -1100,10 +1139,11 @@ class Engine:
 
             (tok_f, caches, pos_f, bud_f), (toks, oks) = jax.lax.scan(
                 step, (last, caches, pos, bud), None, length=k)
-            state2 = (tok_f, pos_f, temps, topks, topps, keys, live)
+            state2 = (tok_f, pos_f, temps, topks, topps, keys, live,
+                      eosv, bud_f)
             # [S, k] per-step tokens + ok flags: the ONLY fetches
             return (jnp.swapaxes(toks, 0, 1), jnp.swapaxes(oks, 0, 1),
-                    state2, (eosv, bud_f), caches)
+                    state2, caches)
 
         return body
 
@@ -1131,7 +1171,7 @@ class Engine:
         model = self.model
 
         def body(st, caches, bt, state, drafts, poison):
-            last, pos, temps, topks, topps, keys, live = state
+            last, pos, temps, topks, topps, keys, live, eosv, bud = state
             kv = self._inject_bt(caches, bt)
             # idle lanes at cache_index -1 (context 0), like the plain
             # decode step — their k+1 scratch writes clip into page 0
@@ -1149,9 +1189,13 @@ class Engine:
             # not drift (same contract as the decode step)
             new_last = jnp.take_along_axis(toks, acc[:, None],
                                            axis=1)[:, 0]
+            # the budget follows the accepted chain; a chain that ends
+            # its request (eos, or the budget inside it) is retired by
+            # the harvest of this same step, which rewrites the row
             state2 = (jnp.where(live > 0, new_last, last),
                       pos + (acc + 1) * live, temps, topks, topps,
-                      jnp.where(live[:, None] > 0, keys2, keys), live)
+                      jnp.where(live[:, None] > 0, keys2, keys), live,
+                      eosv, bud - (acc + 1) * live)
             return toks, acc, ok, state2, self._strip_bt(new_kv)
 
         return body
@@ -1225,14 +1269,13 @@ class Engine:
                 name=f"decode[{v}]", body=self._decode_body(v),
                 args=(st, pools, bt, state, poison),
                 donate=(1, 3), fetched=(0, 1)))
-        aux = hp.struct_of(self._aux_dev)
         mks = tuple(sorted(self._multi_fns)) \
             or ((self.multi_tick,) if self.multi_tick > 1 else ())
         for mk in mks:
             specs.append(hp.ExecutableSpec(
                 name=f"decode-multi[k={mk}]", body=self._multi_body(mk),
-                args=(st, pools, bt, state, aux, poison),
-                donate=(1, 3, 4), fetched=(0, 1)))
+                args=(st, pools, bt, state, poison),
+                donate=(1, 3), fetched=(0, 1)))
         if self._spec is not None:
             k = self._spec.k
             for v in tuple(self._verify_fns) or variants:
@@ -1264,7 +1307,8 @@ class Engine:
                 self._ensure_pages, self._safe_decode,
                 self._decode_dispatch, self._dispatch_multi,
                 self._dispatch_spec, self._multi_k,
-                self._deadline_ticks, self._decode_harvest,
+                self._deadline_ticks, self._lanes, self._drain,
+                self._decode_harvest,
                 self._harvest_single, self._harvest_multi,
                 self._harvest_spec, self._flush_state,
                 self._poison_slot, self._unpoison]
@@ -1285,20 +1329,55 @@ class Engine:
         return hotpath_lint.emit_hotpath(
             hotpath_lint.lint_inventory(self._hotpath_inventory()))
 
-    def _latent_span_args(self, active) -> dict:
-        """`engine.decode.dispatch` arguments for a spec with a sparse
-        selection or a window: the tokens this dispatch's attention has
-        to read, a slot's context counted up to `index_topk` on the
+    def _lanes(self) -> list:
+        """(slot, request, ahead) of every lane the NEXT dispatch
+        computes: the DECODE slots the host does not already know to
+        be out of budget. ``ahead`` is 1 for a lane of the tick in
+        flight: the device is that one tick past the host's mirrors
+        (``req.written``, ``_pos``, ``len(req.generated)``), which
+        follow only at its harvest."""
+        flying = dict(self._inflight.active) \
+            if self._inflight is not None else {}
+        lanes = []
+        for i, req in enumerate(self._slots):
+            if req is None or req.state != DECODE:
+                continue
+            ahead = 1 if flying.get(i) is req else 0
+            if int(req.params.max_new_tokens) - len(req.generated) \
+                    - ahead > 0:
+                lanes.append((i, req, ahead))
+        return lanes
+
+    def _dispatch_span_args(self, lanes, variant: str,
+                            ticks: int) -> dict:
+        """`engine.decode.dispatch` arguments, for the positions the
+        dispatched program READS (the host mirrors plus the tick in
+        flight): its lanes, their context, and for a spec with a
+        sparse selection or a window the tokens its attention has to
+        read, a slot's context counted up to `index_topk` on the
         layers that select (sel_tokens) and up to `window` on the
         layers that slide (win_tokens)."""
+        pos = [int(self._pos[i]) + ahead for i, _, ahead in lanes]
+        args = dict(variant=variant, slots=len(lanes),
+                    ctx_tokens=sum(pos), ticks=ticks,
+                    inflight=int(self._inflight is not None))
         spec = self.serving_spec
-        out = {}
         for name, cap in (("sel_tokens", spec.get("index_topk")),
                           ("win_tokens", spec.get("window"))):
             if cap is not None:
-                out[name] = int(sum(min(int(self._pos[i]) + 1, int(cap))
-                                    for i, _ in active))
-        return out
+                args[name] = sum(min(p + 1, int(cap)) for p in pos)
+        return args
+
+    def _pending(self, kind: str, data: tuple, lanes, t0: float,
+                 mark: float, variant: str, ticks: int = 1,
+                 **more) -> _PendingTick:
+        """The handoff record of the dispatch just made over `lanes`."""
+        return _PendingTick(
+            kind=kind, data=data,
+            active=[(i, req) for i, req, _ in lanes], ticks=ticks,
+            t_dispatch=t0, dev_mark=mark, variant=variant,
+            span_args=self._dispatch_span_args(lanes, variant, ticks),
+            **more)
 
     def _dispatch_steady(self, steady, fn, *args):
         """Dispatch one tick executable. On a STEADY tick (warm
@@ -1364,8 +1443,7 @@ class Engine:
             req = Request(req_id=rid, prompt=prompt, params=params,
                           arrival_t=self._clock(),
                           queued_step=self._steps)
-            req.key = np.asarray(jax.random.PRNGKey(int(params.seed)),
-                                 np.uint32)
+            req.key = host_prng_key(params.seed)
             self._next_id += 1
             # LIVE requests only (see _finish)
             self.requests[req.req_id] = req
@@ -1376,31 +1454,46 @@ class Engine:
             return req.req_id
 
     def step(self) -> List[Output]:
-        """One scheduler tick, PIPELINED against the device (JAX async
-        dispatch): the decode work for the slots that were live at the
-        END of the last step is dispatched FIRST, then the host runs
-        the tick-t+1 scheduling — deadline sweeps, admission, prefill
-        slices, watchdog — in the overlap window while the device
-        executes, and only then syncs + harvests the token/ok vectors
-        and grows pages for the next dispatch. Returns the requests
-        that finished OR failed during this tick — a per-request
-        failure (deadline, NaN logits, prefill error) retires that
-        request and never raises out of here.
+        """One scheduler tick, run ONE TICK AHEAD of the device (JAX
+        async dispatch): the single-tick decode program for tick t is
+        dispatched FIRST and stays in flight across the return; only
+        then does the host wait for tick t-1 (dispatched by the LAST
+        step), harvest its tokens, and run the scheduling for tick
+        t+1 — deadline sweeps, admission, prefill slices, page growth
+        — all while the device executes tick t. The programs carry
+        each lane's eos id and token budget, so a lane whose request
+        ended at tick t-1 is dead in tick t without the host saying
+        so; the host learns of a finish one tick late and discards
+        nothing it would have kept (docs/SERVING.md "Dispatch
+        pipelining"). Returns the requests that finished OR failed
+        during this tick — a per-request failure (deadline, NaN
+        logits, prefill error) retires that request and never raises
+        out of here.
 
-        With ``multi_tick=k > 1`` a pure-greedy steady stretch runs up
-        to k device ticks per step as ONE fused scan dispatch —
-        deadline / queue-timeout enforcement then lands on dispatch
-        boundaries, so a request can overrun its deadline_ms by at
-        most one dispatch (k ticks) before _expire retires it."""
+        A speculative or fused (``multi_tick=k > 1``, pure-greedy
+        steady stretch, up to k device ticks as ONE scan) dispatch is
+        sized from what the last harvest left, so it is dispatched,
+        waited for and harvested inside one step, and a tick in
+        flight is drained before it. Deadline / queue-timeout
+        enforcement lands on dispatch boundaries, so a request can
+        overrun its deadline_ms by at most one dispatch (k ticks)
+        before _expire retires it."""
         with RecordEvent("engine.step", step=self._steps,
                          active=self.num_active,
                          waiting=self.num_waiting,
                          prefilling=self.num_prefilling):
-            outputs: List[Output] = []
+            # what forced drains since the last step retired comes out
+            # first; a drain inside this step appends here too
+            outputs = self._held
             wall0 = time.perf_counter()
             clk0 = self._clock()
             self._device_s = 0.0
             self._overlap_s = 0.0
+            if self._inflight is not None:
+                # the tick carried in: this step's device share counts
+                # its window from the step's start (_sync_timed)
+                self._inflight.t_dispatch = wall0
+                self._inflight.dev_mark = 0.0
             c0 = self._tracker.compiles
             if self._moe_layer is not None and c0 != self._moe_tracker_mark:
                 # compiles landed OUTSIDE our steps since the last sync
@@ -1417,25 +1510,27 @@ class Engine:
                 self._prefix_faults()
             with tape_mod.no_grad_guard():
                 # (a) dispatch the decode executable for the slots settled
-                # by the LAST step — the device starts tick t now
+                # by the LAST step — tick t queues behind tick t-1 and
+                # starts the instant that one ends
                 with RecordEvent("engine.decode.dispatch") as span:
                     pending = self._safe_decode()
                     if pending is not None:
-                        span.set(
-                            variant=pending.variant,
-                            slots=len(pending.active),
-                            ctx_tokens=int(sum(
-                                self._pos[i] for i, _ in pending.active)),
-                            ticks=pending.ticks,
-                            **self._latent_span_args(pending.active))
-                # (b) overlap window: tick-t+1 host scheduling runs while
-                # the device executes. Exactness is order-insensitive here
-                # (rows are independent; a request admitted now joins the
-                # NEXT dispatch, exactly as the sequential loop's same-step
-                # admission joined the decode after its prefill), and a
-                # request _expire retires mid-flight has its in-flight
-                # token discarded at harvest — the same token the
-                # sequential loop (expire before decode) never produced.
+                        span.set(**pending.span_args)
+                # (b) a single-tick dispatch stays in flight; the tick the
+                # last step left in flight is waited for and harvested now.
+                # (Nothing dispatched: the tick in flight, if any, is
+                # simply harvested. A spec / fused dispatch found none:
+                # _decode_dispatch drained it.)
+                ahead = pending is not None and pending.kind == "single"
+                carried, self._inflight = \
+                    self._inflight, pending if ahead else None
+                outputs.extend(self._decode_harvest(carried))
+                # (c) tick-t+1 host scheduling, beside the device.
+                # Exactness is order-insensitive here (rows are
+                # independent; a request admitted now joins the NEXT
+                # dispatch), and a request _expire retires with its lane
+                # in the tick in flight has that token discarded at
+                # harvest — a token the request's stream never held.
                 with RecordEvent("engine.expire"):
                     outputs.extend(self._expire())
                 self._pf_step_tokens = 0
@@ -1443,14 +1538,15 @@ class Engine:
                     span.set(admitted=len(self._admit()))
                 outputs.extend(self._run_prefills())
                 self._watchdog.maybe_start_and_tick()
-                # (c) sync + harvest: block on the dispatched outputs
-                # (attributed — host work above that hid under device
-                # execution lands in the overlap share), append tokens,
-                # retire finished rows
-                outputs.extend(self._decode_harvest(pending))
-                # (d) page growth for the NEXT dispatch (multi-tick
-                # horizon pre-allocates k ticks of headroom when free
-                # pages allow; preemption key reads are post-sync here)
+                if not ahead:
+                    # spec / fused: block on THIS step's dispatch
+                    # (attributed — host work above that hid under device
+                    # execution lands in the overlap share)
+                    outputs.extend(self._decode_harvest(pending))
+                # (d) page growth for the NEXT dispatch, counting the tick
+                # in flight (multi-tick horizon pre-allocates k ticks of
+                # headroom when free pages allow; a preemption drains the
+                # tick in flight before it reads the victim's key)
                 self._ensure_pages()
             if self._injector is not None and \
                     self._injector.fire("alloc.refcount_skew",
@@ -1525,6 +1621,7 @@ class Engine:
                     self._tick_est_ms = d_ms if self._tick_est_ms <= 0.0 \
                         else 0.7 * self._tick_est_ms + 0.3 * d_ms
                 self._steps += 1
+                self._held = []
                 return outputs
 
     def run(self, requests: Sequence, max_steps: int = 100_000,
@@ -1579,6 +1676,8 @@ class Engine:
         finally:
             if hb is not None:
                 hb.stop()
+        # an eos leaves one tick in flight behind its request
+        self._drain("api")
         return sorted(outs, key=lambda o: o.req_id)
 
     def cancel(self, req_id: int) -> Optional[Output]:
@@ -1588,6 +1687,8 @@ class Engine:
         or already-retired ids return None. Safe at any lifecycle
         point — waiting, preempted, or mid-decode (the fixed-shape
         decode step simply sees one more idle lane next tick)."""
+        # the Output holds every token the device has produced
+        self._drain("api")
         req = self.requests.get(int(req_id))
         if req is None or req.state in (FINISHED, FAILED):
             return None
@@ -1611,8 +1712,13 @@ class Engine:
         ``device_key=False`` skips the device read — the caller must
         then set ``req.key`` itself (the fleet replays it from
         (seed, tokens emitted) via ``disagg.replay_rng_key``, the
-        host-truth-only migration contract). Returns None for unknown
-        or already-retired ids."""
+        host-truth-only migration contract; the tick in flight is
+        then left alone too: the request leaves with the tokens the
+        host holds and that tick's token for its lane is discarded,
+        to be produced again where the request resumes). Returns None
+        for unknown or already-retired ids."""
+        if device_key:
+            self._drain("api")
         req = self.requests.get(int(req_id))
         if req is None or req.state in (FINISHED, FAILED):
             return None
@@ -1651,6 +1757,10 @@ class Engine:
         stall-dump path, where the device may be wedged) at the cost
         of exactness for mid-flight SAMPLING requests."""
         from .reliability import snapshot_engine
+        if sync:
+            # the rng rows fetched belong to the newest token; without
+            # the sync the snapshot is the host's view, one tick behind
+            self._drain("api")
         return snapshot_engine(self, sync=sync)
 
     def restore(self, snap: dict, strict: bool = True) -> int:
@@ -1659,6 +1769,7 @@ class Engine:
         restored run's outputs are bit-identical to the uninterrupted
         one. Returns the number of requests re-admitted."""
         from .reliability import restore_engine
+        self._drain("api")
         return restore_engine(self, snap, strict=strict)
 
     def snapshot_to(self, path: str, sync: bool = True) -> str:
@@ -1678,6 +1789,12 @@ class Engine:
         them (the chaos-recovery path). Auto-run each step under
         ``FLAGS_serving_debug_invariants`` (raise on findings) or an
         active fault injector (repair + count)."""
+        self._drain("api")
+        return self._audit(repair)
+
+    def _audit(self, repair: bool) -> List[str]:
+        """check_invariants on the host's view as it stands (inside a
+        step, with a tick in flight: the audit reads no device)."""
         expected: Dict[int, int] = {}
         for r in self.requests.values():
             held = r.pages if r.pages else (r.shared_pages or [])
@@ -1731,7 +1848,9 @@ class Engine:
     def close(self):
         """Detach the engine's compile tracker from the global
         jax.monitoring fan-out (listener hygiene for processes that
-        build many engines; also runs at garbage collection)."""
+        build many engines; also runs at garbage collection). A tick
+        in flight is harvested first."""
+        self._drain("api")
         self._tracker.stop()
 
     def __del__(self):
@@ -1761,11 +1880,14 @@ class Engine:
     @property
     def idle(self) -> bool:
         """True when a step() would do no work: nothing queued, nothing
-        decoding, nothing mid-prefill. The drive-loop check for replay
-        tools and offline batch drivers (fast-forwarding a virtual
-        clock, or sleeping to the next arrival, is only safe here)."""
+        decoding, nothing mid-prefill, no tick in flight to harvest and
+        no Output of a forced drain to hand out. The drive-loop check
+        for replay tools and offline batch drivers (fast-forwarding a
+        virtual clock, or sleeping to the next arrival, is only safe
+        here)."""
         return (not self._waiting and self.num_active == 0
-                and self.num_prefilling == 0)
+                and self.num_prefilling == 0
+                and self._inflight is None and not self._held)
 
     @property
     def pages_free(self) -> int:
@@ -1815,7 +1937,7 @@ class Engine:
         if not auditing:
             return
         repair = self._injector is not None
-        findings = self.check_invariants(repair=repair)
+        findings = self._audit(repair)
         if findings:
             if repair:
                 monitor.counter("serving.invariant_repairs").increase(
@@ -2284,11 +2406,11 @@ class Engine:
         self._topps[i] = req.params.top_p
         self._keys[i] = req.key
         self._live[i] = 1
+        eos = req.params.eos_token_id
+        self._eos[i] = -1 if eos is None else int(eos)
+        self._bud[i] = int(req.params.max_new_tokens) - len(req.generated)
         self._dirty.add(i)
         self._bt_dirty = True
-        # the device-resident multi-tick aux (eos/budget) doesn't know
-        # this row yet — next fused dispatch re-uploads
-        self._aux_clean = False
         req.state = DECODE
         # one tick-aggregated DECODE span from activation to
         # finish/preempt/migrate (not per tick — the timeline stays
@@ -2296,11 +2418,12 @@ class Engine:
         self._open_span(req, tracing.DECODE, slot=i)
 
     def _ensure_pages(self):
-        """Before the decode step, every active slot must own every
-        page this tick's writes land in — one position for the plain
-        decode step, k+1 for a speculative draft/verify tick; allocate
-        lazily, preempting the YOUNGEST sequence when the pool runs
-        dry (after reclaiming idle prefix-cache pages). With multi-tick
+        """Before the NEXT dispatch, every lane it computes must own
+        every page its writes land in — one position past the tick in
+        flight for the plain decode step, k+1 for a speculative
+        draft/verify tick; allocate lazily, preempting the YOUNGEST
+        sequence when the pool runs dry (after reclaiming idle
+        prefix-cache pages; a tick in flight is drained first). With multi-tick
         enabled the horizon stretches toward ``multi_tick`` positions
         — but only from FREE pages (no eviction, no preemption): a
         short coverage just clamps the fused k, it never costs another
@@ -2309,11 +2432,11 @@ class Engine:
             allocated = 0
             # a preemption is the only thing here that queues a request
             waiting0 = len(self._waiting)
-            for i in range(self.max_slots):
-                req = self._slots[i]
-                if req is None or req.state != DECODE:
-                    continue
-                need = _ceil_div(req.written + self._lookahead,
+            for i, req, ahead in self._lanes():
+                if req.state != DECODE:
+                    continue      # ended or preempted inside this loop
+                # req.written lags the device by the tick in flight
+                need = _ceil_div(req.written + ahead + self._lookahead,
                                  self.page_size)
                 while len(req.pages) < need:
                     page = self._alloc_or_preempt(req)
@@ -2366,6 +2489,15 @@ class Engine:
                 # against running decodes.
                 if self._prefix is not None and self._prefix.evict(1):
                     continue
+                if self._inflight is not None:
+                    # a victim's sampler key is read from the device
+                    # (_preempt), which the tick in flight has moved
+                    # past the tokens the host holds: harvest it first.
+                    # That may free pages, or end `req` itself
+                    self._drain("preempt")
+                    if req.state != DECODE:
+                        return None
+                    continue
                 victims = [r for r in self._slots
                            if r is not None
                            and r.state in (DECODE, PREFILL)]
@@ -2412,11 +2544,7 @@ class Engine:
             if self._dirty:
                 mask = np.zeros((self.max_slots,), bool)
                 mask[list(self._dirty)] = True
-                host = (self._up(self._last), self._up(self._pos),
-                        self._up(self._temps),
-                        self._up(self._topks),
-                        self._up(self._topps), self._up(self._keys),
-                        self._up(self._live))
+                host = tuple(self._up(m) for m in self._mirrors())
                 self._dev = _merge_rows(self._dev, host,
                                         self._up(mask))
                 self._dirty.clear()
@@ -2426,16 +2554,21 @@ class Engine:
 
     def _decode_dispatch(self) -> Optional[_PendingTick]:
         """Dispatch this step's decode work and return WITHOUT
-        waiting: the executable runs while step()'s overlap window
-        does the tick-t+1 host scheduling; _decode_harvest syncs and
-        retires. The sampler variant is chosen from the host mirrors
-        of the slots settled by the LAST step — exactly the rows the
-        dispatched executable reads."""
-        active = [i for i in range(self.max_slots)
-                  if self._slots[i] is not None
-                  and self._slots[i].state == DECODE]
-        if not active:
+        waiting. A single-tick dispatch runs AHEAD: the tick the last
+        step dispatched may still be in flight, and this one queues
+        behind it on the device-resident state that tick advances
+        (sampled rows carry their keys there), so nothing here may
+        need that tick's tokens. The lanes and the sampler variant
+        come from the host mirrors plus what the host knows of the
+        tick in flight (_lanes) — exactly the rows the dispatched
+        executable computes, but for a lane that tick ends by its eos.
+        A speculative or fused dispatch is sized from host decisions
+        made on the last harvest (drafts, the clamp on k): it finds no
+        tick in flight, or drains it first."""
+        lanes = self._lanes()
+        if not lanes:
             return None
+        active = [i for i, _, _ in lanes]
         sampling = [i for i in active if self._temps[i] > 0.0]
         if not sampling:
             variant = "greedy"
@@ -2448,7 +2581,6 @@ class Engine:
         # still coherent, _safe_decode skips the tick and retries
         self._fault_raise("decode.device_error")
         self._poison_slot(active)
-        snap = [(i, self._slots[i]) for i in active]
         if self._spec is not None:
             if self.multi_tick > 1:
                 # spec decode owns the draft/verify horizon: fused
@@ -2457,16 +2589,26 @@ class Engine:
                 # silent downgrade
                 self._mon.counter(
                     "serving.multi_tick.clamp.spec").increase()
-            return self._dispatch_spec(snap, variant)
+            return self._dispatch_spec(lanes, variant)
+        if self._inflight is not None and self._multi_eligible(variant):
+            # single -> fused: the clamp on k reads budgets, pages and
+            # deadlines as the last harvest left them
+            self._drain("kind_switch")
+            lanes = self._lanes()
+            if not lanes:
+                return None
+            active = [i for i, _, _ in lanes]
         mk = self._multi_k(active, variant)
         if mk > 1:
-            return self._dispatch_multi(snap, mk)
+            return self._dispatch_multi(lanes, mk)
         # steady = the dirty-row-merge discipline says this tick
         # uploads nothing and dispatches a warm executable — the
         # PADDLE_TPU_LINT transfer guard may wrap the dispatch
         steady = (variant in self._decode_fns and not self._dirty
                   and not self._bt_dirty and not self._poisoned)
         fn = self._get_decode_fn(variant)
+        if self._inflight is not None:
+            self._mon.counter("serving.runahead.dispatches").increase()
         self._flush_state()
         mark = self._device_s
         t0 = time.perf_counter()
@@ -2478,10 +2620,30 @@ class Engine:
                 steady, fn, self._st, self._pools, self._bt_dev,
                 self._dev, self._poison_dev)
         self._unpoison()
-        return _PendingTick(kind="single", data=(nxt, okv),
-                            active=snap, ticks=1, t_dispatch=t0,
-                            dev_mark=mark, variant=variant,
-                            extras=tuple(extras))
+        return self._pending("single", (nxt, okv), lanes, t0, mark,
+                             variant, extras=tuple(extras))
+
+    def _drain(self, cause: str) -> None:
+        """Wait for and harvest the tick in flight, NOW: what reads
+        the device-resident state or the host's view of a slot as of
+        the newest token (a preemption's key fetch, a fused dispatch's
+        clamp, the public entries that move requests) calls this
+        first. The Outputs it retires come out of the step() that is
+        running, or of the next one. Counted by cause:
+        ``serving.runahead.drains.<cause>``."""
+        pend, self._inflight = self._inflight, None
+        if pend is None:
+            return
+        self._mon.counter("serving.runahead.drains." + cause).increase()
+        self._held.extend(self._decode_harvest(pend))
+
+    def _multi_eligible(self, variant: str) -> bool:
+        """The static rungs of the fused decode's ladder (_multi_k):
+        every live slot in a pure-greedy stretch, nothing pending
+        host-side."""
+        return not (self.multi_tick <= 1 or self._spec is not None
+                    or variant != "greedy" or self._waiting
+                    or self._poisoned or self.num_prefilling)
 
     def _multi_k(self, active: List[int], variant: str) -> int:
         """Eligibility ladder + per-dispatch clamp for the fused
@@ -2497,9 +2659,7 @@ class Engine:
         bucket (the in-scan budget freeze makes running FEWER ticks
         than a row needs always exact)."""
         K = self.multi_tick
-        if (K <= 1 or self._spec is not None or variant != "greedy"
-                or self._waiting or self._poisoned
-                or self.num_prefilling):
+        if not self._multi_eligible(variant):
             return 1
         horizon = 0      # longest remaining budget over live rows
         cov = None       # tightest allocated-page coverage
@@ -2570,39 +2730,24 @@ class Engine:
             ticks = min(ticks, int(left // est))
         return max(1, ticks)
 
-    def _dispatch_multi(self, snap, k: int) -> _PendingTick:
-        """Dispatch ONE fused k-tick greedy scan. The aux vectors
-        (per-slot eos id + remaining-token budget) are device-resident
-        and advanced in-graph; they re-upload only after a host-side
-        slot change or tokens emitted outside the fused path
-        (_aux_clean), so back-to-back fused dispatches ship nothing
-        host-to-device."""
-        aux_clean0 = self._aux_clean
-        if not aux_clean0:
-            eos = np.full((self.max_slots,), -1, np.int32)
-            bud = np.zeros((self.max_slots,), np.int32)
-            for i, req in snap:
-                p = req.params
-                if p.eos_token_id is not None:
-                    eos[i] = int(p.eos_token_id)
-                bud[i] = int(p.max_new_tokens) - len(req.generated)
-            self._aux_dev = (self._up(eos), self._up(bud))
-            self._aux_clean = True
-        steady = (k in self._multi_fns and aux_clean0
+    def _dispatch_multi(self, lanes, k: int) -> _PendingTick:
+        """Dispatch ONE fused k-tick greedy scan. Each slot's eos id
+        and remaining-token budget are part of the device-resident
+        state every decode program advances in-graph, so back-to-back
+        fused dispatches ship nothing host-to-device."""
+        steady = (k in self._multi_fns
                   and not self._dirty and not self._bt_dirty)
         fn = self._get_multi_fn(k)
         self._flush_state()
         mark = self._device_s
         t0 = time.perf_counter()
-        toks, oks, self._dev, self._aux_dev, self._pools = \
-            self._dispatch_steady(
-                steady, fn, self._st, self._pools, self._bt_dev,
-                self._dev, self._aux_dev, self._poison_dev)
+        toks, oks, self._dev, self._pools = self._dispatch_steady(
+            steady, fn, self._st, self._pools, self._bt_dev,
+            self._dev, self._poison_dev)
         self._mon.counter("serving.multi_tick.dispatches").increase()
         self._mon.counter("serving.multi_tick.ticks").increase(k)
-        return _PendingTick(kind="multi", data=(toks, oks),
-                            active=snap, ticks=k, t_dispatch=t0,
-                            dev_mark=mark, k=k)
+        return self._pending("multi", (toks, oks), lanes, t0, mark,
+                             "greedy", ticks=k, k=k)
 
     def _decode_harvest(self, pend: Optional[_PendingTick]
                         ) -> List[Output]:
@@ -2632,13 +2777,15 @@ class Engine:
         nxt = np.asarray(pend.data[0])
         okv = np.asarray(pend.data[1])
         rows = self._take_extras(pend.extras)
-        # tokens appended here move budgets the device-resident
-        # multi-tick aux never saw — next fused dispatch re-uploads
-        self._aux_clean = False
         outs: List[Output] = []
+        dead = 0
         for i, req in pend.active:
             if self._slots[i] is not req or req.state != DECODE:
-                continue          # retired in the overlap window
+                # the request ended (eos, expiry, cancel, quarantine,
+                # preemption) after this tick was dispatched with its
+                # lane in it: the lane's token is no part of its stream
+                dead += 1
+                continue
             if not bool(okv[i]):
                 # NaN/inf logits on THIS slot only: quarantine it
                 # (token discarded, pages freed, slot back to the
@@ -2658,7 +2805,18 @@ class Engine:
                 req.logits.append(np.asarray(rows[i]))
             reason = self._finish_reason(req, tok)
             if reason:
+                # its pages are free from here on, while the tick in
+                # flight may still hold the lane (an eos the host had
+                # not seen); that lane is dead in-graph and writes the
+                # scratch page, and a lane retired live (cancel,
+                # expiry) writes a page it owned at dispatch: the
+                # device runs its programs in order, so a prefill or
+                # tick that is handed the page is dispatched, and
+                # writes, after it
                 outs.append(self._finish(req, reason))
+        if dead:
+            self._mon.counter(
+                "serving.runahead.dead_lane_ticks").increase(dead)
         return outs
 
     def _harvest_multi(self, pend: _PendingTick,
@@ -2731,7 +2889,7 @@ class Engine:
             self._poison_dev = self._poison_zeros
             self._poisoned = False
 
-    def _dispatch_spec(self, snap, variant: str) -> _PendingTick:
+    def _dispatch_spec(self, lanes, variant: str) -> _PendingTick:
         """Dispatch one draft/verify tick: the draft loop proposes k
         tokens per slot (one executable), the target scores all k+1
         positions in ONE batched forward — the accept walk happens at
@@ -2762,9 +2920,8 @@ class Engine:
             steady, fn, self._st, self._pools, self._bt_dev, self._dev,
             drafts, self._poison_dev)
         self._unpoison()
-        return _PendingTick(kind="spec", data=(toks, acc, okv),
-                            active=snap, ticks=1, t_dispatch=t0,
-                            dev_mark=mark, k=k, variant=variant)
+        return self._pending("spec", (toks, acc, okv), lanes, t0, mark,
+                             variant, k=k)
 
     def _harvest_spec(self, pend: _PendingTick,
                       emitted: _Emitted) -> List[Output]:
@@ -2772,9 +2929,6 @@ class Engine:
         acc = np.asarray(pend.data[1])
         okv = np.asarray(pend.data[2])
         k = pend.k
-        # accepted chains move budgets the device-resident multi-tick
-        # aux never saw — the next fused dispatch re-uploads
-        self._aux_clean = False
         outs: List[Output] = []
         for i, req in pend.active:
             if self._slots[i] is not req or req.state != DECODE:
@@ -2830,10 +2984,11 @@ class Engine:
             self._topks[i] = 0
             self._topps[i] = 0.0
             self._live[i] = 0
+            self._eos[i] = -1
+            self._bud[i] = 0
             self._slots[i] = None
             self._dirty.add(i)
             self._bt_dirty = True
-            self._aux_clean = False
             req.slot = None
         if req.pages:
             # one reference drop per page: private pages return to the

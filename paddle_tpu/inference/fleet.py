@@ -96,7 +96,7 @@ from . import tracing
 from .disagg import replay_rng_key
 from .engine import (FAILED, FINISHED, PREEMPTED, WAITING, Engine,
                      Output, Request, SamplingParams, _ceil_div,
-                     _normalize_prompt)
+                     _normalize_prompt, host_prng_key)
 from .prefix_cache import _chunk_hash
 
 FLEET_SNAPSHOT_VERSION = 1
@@ -275,7 +275,7 @@ class ServingFleet:
         self._compiles = 0
         self._warm_compiles = 0
         self._replay_used = False
-        # precompile the rng-replay surface (PRNGKey + split) so a
+        # precompile the rng-replay surface (the key split) so a
         # steady-state migration/failover tick introduces no new
         # driver executable
         replay_rng_key(0, 1, 1.0)
@@ -387,9 +387,7 @@ class ServingFleet:
                 f"{self.pool_pages}")
         req = Request(req_id=rid, prompt=prompt, params=params,
                       arrival_t=self._clock(), queued_step=self._steps)
-        import jax
-        req.key = np.asarray(jax.random.PRNGKey(int(params.seed)),
-                             np.uint32)
+        req.key = host_prng_key(params.seed)
         tracing.open_span(req.spans, tracing.QUEUED,
                           req.arrival_t * 1e3, self.label)
         self._next_id += 1
@@ -860,6 +858,9 @@ class ServingFleet:
                 q = self._queues[tenant] = deque()
                 self._rr.append(tenant)
             q.appendleft(req)
+        # the dead replica's tick in flight dies with it, unharvested:
+        # every request left with the tokens the host held
+        w._inflight = None
         self._remove_replica(index)
         return n
 

@@ -304,8 +304,13 @@ def test_decode_span_arguments_and_window_gauge(tiny):
                  max_context=48)
     try:
         eng._pos[:] = [20, 3]
-        args = eng._latent_span_args([(0, None), (1, None)])
-        assert args == {"sel_tokens": 8 + 4, "win_tokens": 5 + 4}
+        # lane 1 rides the tick in flight: the program reads it one
+        # position past the host's mirror
+        args = eng._dispatch_span_args([(0, None, 0), (1, None, 1)],
+                                       "greedy", 1)
+        assert args == {"variant": "greedy", "slots": 2, "ticks": 1,
+                        "inflight": 0, "ctx_tokens": 20 + 4,
+                        "sel_tokens": 8 + 5, "win_tokens": 5 + 5}
         eng.add_request(_ids(30), SamplingParams(max_new_tokens=3))
         eng.step()
         eng.step()

@@ -19,7 +19,8 @@ from paddle_tpu.text.models import LlamaConfig, LlamaForCausalLM
 # span -> the arguments the table in docs/OBSERVABILITY.md names
 STEP_SPANS = {
     "engine.step": {"step", "active", "waiting", "prefilling"},
-    "engine.decode.dispatch": {"variant", "slots", "ctx_tokens", "ticks"},
+    "engine.decode.dispatch": {"variant", "slots", "ctx_tokens", "ticks",
+                               "inflight"},
     "engine.flush_state": {"rows", "block_table"},
     "engine.expire": set(),
     "engine.admit": {"admitted"},
@@ -134,6 +135,100 @@ def test_prefill_span_req_joins_the_request_timeline(one_step):
     assert req == second
     phases = [s["phase"] for s in outs[req].spans]
     assert "PREFILL" in phases and phases[0] == "QUEUED"
+
+
+def test_dispatch_of_tick_t_opens_before_the_wait_for_tick_t_minus_1():
+    """Run-ahead: inside one step() the dispatch span (tick t, made with
+    tick t-1 in flight) comes first, then `engine.decode.wait` and
+    `engine.harvest`, which belong to tick t-1."""
+    eng = _engine()
+    eng.add_request(_prompt(5), SamplingParams(max_new_tokens=8))
+    eng.step()                              # prefill
+    with Profiler(timer_only=True) as prof:
+        eng.step()                          # tick 1: nothing to harvest
+    first = _by_name(prof._store.events)
+    assert first["engine.decode.dispatch"][0][2]["inflight"] == 0
+    assert "engine.decode.wait" not in first
+    with Profiler(timer_only=True) as prof:
+        eng.step()                          # tick 2 out, tick 1 in
+    spans = _by_name(prof._store.events)
+    d0, d1, args = spans["engine.decode.dispatch"][0]
+    w0, w1, _ = spans["engine.decode.wait"][0]
+    h0, _, hargs = spans["engine.harvest"][0]
+    assert args["inflight"] == 1
+    assert d0 <= d1 <= w0 <= w1 <= h0
+    assert hargs == {"tokens": 1, "finished": 0}
+    assert spans["engine.ensure_pages"][0][0] >= h0
+    while not eng.idle:
+        eng.step()
+
+
+def test_dispatch_args_are_what_the_program_reads():
+    """`ctx_tokens` / `slots` describe the dispatched program's input:
+    the device-resident positions of the lanes alive in-graph, one tick
+    past the host's mirrors while a tick is in flight, and without the
+    lane the host knows to be out of budget."""
+    eng = _engine()
+    long_ = eng.add_request(_prompt(5), SamplingParams(max_new_tokens=12))
+    short = eng.add_request(_prompt(7, 2), SamplingParams(max_new_tokens=4))
+    seen = []
+    for _ in range(8):
+        # what the next dispatch will read (the fetch waits for the tick
+        # in flight; it harvests nothing)
+        pos, live, bud = (np.asarray(eng._dev[j]) for j in (1, 6, 8))
+        dirty = set(eng._dirty)
+        with Profiler(timer_only=True) as prof:
+            eng.step()
+        rows = _by_name(prof._store.events)["engine.decode.dispatch"]
+        args = rows[0][2]
+        if not args or dirty:
+            continue            # nothing dispatched / rows merged first
+        alive = (live > 0) & (bud > 0)
+        assert args["slots"] == int(alive.sum())
+        assert args["ctx_tokens"] == int(pos[alive].sum())
+        seen.append((args["slots"], args["inflight"]))
+    # both lanes, then the long one alone once the short one's budget
+    # is known to be spent, all with a tick in flight
+    assert (2, 1) in seen and (1, 1) in seen
+    assert eng.requests.keys() == {long_}
+    assert short not in eng.requests
+    while not eng.idle:
+        eng.step()
+
+
+def test_runahead_counters_count_what_the_case_did():
+    names = ("dispatches", "drains.api", "drains.preempt",
+             "drains.kind_switch", "dead_lane_ticks")
+
+    def read():
+        snap = monitor.snapshot()
+        return [int(snap.get("serving.runahead." + n, 0)) for n in names]
+
+    c0, s0 = read(), monitor.counter("serving.steps").get()
+    eng = _engine()
+    rid = eng.add_request(_prompt(5), SamplingParams(max_new_tokens=6))
+    outs = []
+    for _ in range(4):                      # prefill, ticks 1..3
+        outs += eng.step()
+    # ticks 2 and 3 were dispatched behind a tick in flight
+    assert [a - b for a, b in zip(read(), c0)] == [2, 0, 0, 0, 0]
+    held = len(eng.requests[rid].generated)
+    out = eng.cancel(rid)                   # drains tick 3 first
+    assert len(out.token_ids) == held + 1
+    assert [a - b for a, b in zip(read(), c0)] == [2, 1, 0, 0, 0]
+    # an eos the host cannot foresee: its lane rides one dead tick
+    ref = eng.run([(_prompt(5), SamplingParams(max_new_tokens=6))])[0]
+    eos = ref.token_ids[2]
+    assert eos not in ref.token_ids[:2]
+    c1 = read()
+    out, = eng.run([(_prompt(5), SamplingParams(max_new_tokens=6,
+                                                eos_token_id=eos))])
+    assert out.token_ids == ref.token_ids[:3]
+    # tick 1 alone, ticks 2 and the dead tick 3 behind one in flight;
+    # run() ends by harvesting the dead tick
+    assert [a - b for a, b in zip(read(), c1)] == [2, 1, 0, 0, 1]
+    assert monitor.counter("serving.steps").get() > s0
+    assert eng.idle and eng.leaked_pages() == 0
 
 
 def _run_virtual(record):

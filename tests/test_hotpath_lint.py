@@ -20,6 +20,7 @@ import json
 import os
 import sys
 
+import jax
 import numpy as np
 import pytest
 
@@ -198,6 +199,35 @@ def test_dirty_ticks_are_not_guarded(monkeypatch):
     # steady=False must not enter the guard (probe runs bare)
     out = eng._dispatch_steady(False, probe, 1, 2)
     assert out == (1, 2) and calls
+
+
+@pytest.mark.parametrize("front", ["engine", "disagg", "fleet"])
+def test_add_request_runs_no_device_program(rng, front):
+    """With a decode tick always in flight, anything `add_request`
+    fetched from the device would wait for the rest of that tick: the
+    request's sampler key is built on the host (`host_prng_key`), so
+    the whole call passes under jax.transfer_guard('disallow') on every
+    front door, with a tick of the engine in flight."""
+    from paddle_tpu.inference.disagg import DisaggEngine
+    from paddle_tpu.inference.fleet import ServingFleet
+    kw = dict(max_slots=2, page_size=8, pool_pages=32, max_context=64)
+    net = _tiny_net()
+    eng = {"engine": lambda: Engine(net, **kw),
+           "disagg": lambda: DisaggEngine(net, **kw),
+           "fleet": lambda: ServingFleet(net, replicas=2, **kw)}[front]()
+    first, second = _prompts(rng, (5, 7))
+    eng.add_request(first, SamplingParams(max_new_tokens=12))
+    for _ in range(4):
+        eng.step()
+    if front == "engine":
+        assert eng._inflight is not None
+    with jax.transfer_guard("disallow"):
+        rid = eng.add_request(second, SamplingParams(
+            max_new_tokens=4, temperature=0.8, seed=2 ** 40 + 7))
+    np.testing.assert_array_equal(
+        eng.requests[rid].key,
+        np.asarray(jax.random.PRNGKey(2 ** 40 + 7)))
+    eng.close()
 
 
 # -- serving_replay gate ------------------------------------------------------

@@ -363,9 +363,11 @@ def test_replay_rng_key_matches_device_chain(rng):
     req = eng.requests[rid]
     for _ in range(4):
         eng.step()
-    # pull the device chain exactly like preemption does
+    # pull the device chain exactly like preemption does; the device
+    # is one tick past the tokens the host holds (run-ahead)
+    assert eng._inflight is not None
     key_dev = np.asarray(eng._dev[5])[req.slot].astype(np.uint32)
-    key_replayed = replay_rng_key(11, len(req.generated), 0.9)
+    key_replayed = replay_rng_key(11, len(req.generated) + 1, 0.9)
     np.testing.assert_array_equal(key_dev, key_replayed)
     assert (replay_rng_key(11, 5, 0.0)
             == np.asarray(jax.random.PRNGKey(11), np.uint32)).all()

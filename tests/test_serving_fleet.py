@@ -193,8 +193,10 @@ def test_extract_request_hook(rng):
     n_gen = len(eng.requests[rid].generated)
     req = eng.extract_request(rid)               # device-key pull
     assert req is not None and req.state == "PREEMPTED"
+    # the pull harvested the tick in flight first: one token more
+    assert len(req.generated) == n_gen + 1
     np.testing.assert_array_equal(
-        req.key, replay_rng_key(11, n_gen, 0.9))
+        req.key, replay_rng_key(11, n_gen + 1, 0.9))
     assert rid not in eng.requests
     assert not req.pages and req.slot is None
     assert eng.leaked_pages() == 0
