@@ -527,8 +527,7 @@ def bench_llama_serving(n_requests=24, max_slots=16, prompt_lo=64,
                         fault_rate=0.0, fault_seed=0,
                         whale_every=0, whale_prompt=0,
                         max_prefill_tokens=None,
-                        prefill_workers=0, decode_workers=0,
-                        multi_tick=8):
+                        prefill_workers=0, decode_workers=0):
     """Continuous-batching serving throughput on the 1B model
     (paddle_tpu.inference.Engine over the paged KV stack,
     docs/SERVING.md): a fixed-seed Poisson-ish arrival trace
@@ -568,14 +567,7 @@ def bench_llama_serving(n_requests=24, max_slots=16, prompt_lo=64,
     DISAGGREGATED engine (inference/disagg.py, docs/SERVING.md
     "Disaggregated serving"): that many prefill/decode workers as
     independent compiled surfaces, KV pages migrating between their
-    pools — the serving point for the MPMD split.
-
-    multi_tick=K (default 8, docs/SERVING.md "Dispatch pipelining &
-    multi-tick decode") lets the engine run up to K greedy device
-    ticks per host round-trip as one fused scan executable — the
-    trace is all-greedy (temperature 0), so steady decode stretches
-    fuse and the host-share key moves with it. multi_tick=1 restores
-    the one-tick-per-step loop."""
+    pools — the serving point for the MPMD split."""
     import paddle_tpu as paddle
     from paddle_tpu.inference.engine import Engine, SamplingParams
     from paddle_tpu.text.models import LlamaConfig, LlamaForCausalLM
@@ -638,8 +630,7 @@ def bench_llama_serving(n_requests=24, max_slots=16, prompt_lo=64,
                   cache_dtype=cache_dtype, prefix_cache=prefix_cache,
                   draft_model=draft, spec_k=spec_k,
                   fault_injector=injector,
-                  max_prefill_tokens_per_step=max_prefill_tokens,
-                  multi_tick=multi_tick)
+                  max_prefill_tokens_per_step=max_prefill_tokens)
     if prefill_workers > 0 or decode_workers > 0:
         from paddle_tpu.inference.disagg import DisaggEngine
         eng = DisaggEngine(net, prefill_workers=max(prefill_workers, 1),
@@ -1343,40 +1334,12 @@ def main():
         # pass (compiles excluded), so the share over exactly those
         # ticks costs no extra run. A high share at max_slots means
         # the serving loop is host-bound, the thing the tokens/sec
-        # headline can't distinguish from a slow chip. With multi-tick
-        # fused decode on by default (k=8) the share is per DEVICE
-        # tick — host work amortizes over each fused stretch.
+        # headline can't distinguish from a slow chip.
         tok = _record_decode_path("serving", bench_llama_serving)
         result["extras"]["llama_1b_serving_tokens_per_sec"] = \
             round(tok, 1)
         result["extras"]["llama_1b_serving_host_share_per_tick"] = \
             round(_LAST_SERVING_HOST_SHARE, 4)
-
-    def add_serving_multi_tick():
-        # the raw-speed point (docs/SERVING.md "Dispatch pipelining &
-        # multi-tick decode", docs/PERF.md "Host share"): the standard
-        # greedy arrival trace with multi-tick fused decode pinned to
-        # k=8, and the host-share budget enforced IN-BENCH — a chip
-        # run where host work still eats >= 10% of (host+device) tick
-        # time fails loudly instead of recording a pretty tokens/sec.
-        # (On the CPU backend "device" time is the same host's XLA
-        # threads, so the gate only records there — same convention
-        # as the MoE fallback-counter gate.)
-        tok = _record_decode_path(
-            "serving_multi_tick",
-            lambda: bench_llama_serving(multi_tick=8))
-        result["extras"]["llama_1b_serving_multi_tick_tokens_per_sec"] \
-            = round(tok, 1)
-        share = _LAST_SERVING_HOST_SHARE
-        result["extras"]["llama_1b_serving_multi_tick_host_share"] = \
-            round(share, 4)
-        import jax
-        on_cpu = jax.devices()[0].platform == "cpu"
-        if not on_cpu and share >= 0.10:
-            raise RuntimeError(
-                f"multi-tick serving is host-bound: host share "
-                f"{share:.4f} >= 0.10 of (host+device) tick time over "
-                f"the measured pass (docs/PERF.md 'Host share')")
 
     def add_serving_int8kv():
         # the engine bench finally exercises int8-KV: same arrival
@@ -1563,7 +1526,6 @@ def main():
         ("llama_decode_paged_int8", add_decode_paged_int8, 240),
         ("llama_decode_rolling", add_decode_window, 240),
         ("llama_serving", add_serving, 300),
-        ("llama_serving_multi_tick", add_serving_multi_tick, 300),
         ("llama_serving_int8kv", add_serving_int8kv, 300),
         ("llama_serving_prefix", add_serving_prefix, 300),
         ("llama_serving_spec", add_serving_spec, 300),

@@ -463,7 +463,7 @@ def sweep_serving_stack(surfaces=("engine", "disagg", "fleet",
     if "engine" in surfaces:
         from paddle_tpu.inference import Engine, SamplingParams
         eng = Engine(llama(), max_slots=2, page_size=8, pool_pages=32,
-                     max_context=64, multi_tick=4)
+                     max_context=64)
         if drive:
             eng.run([(p, SamplingParams(max_new_tokens=3))
                      for p in prompts])

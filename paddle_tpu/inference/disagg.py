@@ -326,8 +326,7 @@ class DisaggEngine:
                  prefix_cache: bool = False,
                  draft_model=None, spec_k: int = 4,
                  clock=None, fault_injector=None,
-                 max_prefill_tokens_per_step: Optional[int] = None,
-                 multi_tick: int = 1):
+                 max_prefill_tokens_per_step: Optional[int] = None):
         if int(prefill_workers) < 1 or int(decode_workers) < 1:
             raise ValueError(
                 f"need at least one worker of each kind, got "
@@ -367,12 +366,9 @@ class DisaggEngine:
                 max_prefill_tokens_per_step=max_prefill_tokens_per_step,
                 label=f"prefill{i}", **common)
             for i in range(int(prefill_workers))]
-        # only DECODE workers fuse ticks — prefill workers never run
-        # the decode loop, so multi_tick would be dead weight there
         self.decode: List[Optional[DecodeWorker]] = [
             DecodeWorker(model, max_slots=max_slots,
                          pool_pages=pool_pages, prefix_cache=False,
-                         multi_tick=multi_tick,
                          label=f"decode{i}", **common)
             for i in range(int(decode_workers))]
         w0 = self.decode[0]

@@ -449,14 +449,11 @@ class _PendingTick:
     work that ran hidden under device execution as OVERLAP instead of
     double-counting it)."""
 
-    kind: str                 # "single" | "spec" | "multi"
+    kind: str                 # "single" | "spec"
     data: tuple               # device outputs to sync + fetch
     active: list              # [(slot, Request)] snapshot at dispatch
-    ticks: int                # device ticks this dispatch covers
     t_dispatch: float         # perf_counter at dispatch
     dev_mark: float           # self._device_s at dispatch
-    k: int = 0                # spec: draft len / multi: fused ticks
-    variant: str = "greedy"   # sampler variant of the executable
     extras: tuple = ()        # _tick_extras outputs, still on the device
     # `engine.decode.dispatch` arguments: what the program reads
     span_args: dict = field(default_factory=dict)
@@ -466,9 +463,7 @@ class _Emitted:
     """The tokens one harvest appends. They share one clock reading,
     so their gaps to each request's previous token are grouped by
     value: a 48-slot tick records one histogram entry of weight 48,
-    not 48 locked calls. Under ``multi_tick`` the k tokens of one
-    dispatch arrive together and k-1 of their gaps are 0 — what a
-    client sees."""
+    not 48 locked calls."""
 
     __slots__ = ("now", "tokens", "gaps")
 
@@ -557,7 +552,6 @@ class Engine:
                  clock=None, fault_injector=None,
                  debug_invariants: Optional[bool] = None,
                  max_prefill_tokens_per_step: Optional[int] = None,
-                 multi_tick: int = 1,
                  label: Optional[str] = None,
                  keep_logits: bool = False):
         # model polymorphism (docs/SERVING.md): geometry comes from the
@@ -592,7 +586,6 @@ class Engine:
         if self._latent:
             for option, on in (("prefix_cache", bool(prefix_cache)),
                                ("draft_model", draft_model is not None),
-                               ("multi_tick", int(multi_tick) > 1),
                                ("cache_dtype='int8'",
                                 str(cache_dtype) == "int8")):
                 if on:
@@ -625,17 +618,6 @@ class Engine:
                 int(max_prefill_tokens_per_step))
         self.max_prefill_tokens_per_step = max_prefill_tokens_per_step
         self._pf_step_tokens = 0
-        # multi-tick fused decode (docs/SERVING.md "Dispatch
-        # pipelining & multi-tick decode"): when every live slot is in
-        # a pure-greedy decode stretch, up to this many device ticks
-        # run per host round trip as ONE lax.scan executable (in-scan
-        # eos/budget freeze keeps the output token-exact vs the
-        # single-tick loop). 1 = off (the default: one dispatch per
-        # tick, still pipelined against the host scheduling window).
-        if int(multi_tick) < 1:
-            raise ValueError(
-                f"multi_tick must be >= 1, got {multi_tick}")
-        self.multi_tick = int(multi_tick)
         self.max_context = int(max_context or spec["max_context"])
         # speculative decoding writes k+1 positions per tick (the
         # drafted chunk), so the block tables carry that lookahead of
@@ -816,7 +798,6 @@ class Engine:
         self._poison_zeros = self._up(np.zeros((S,), np.float32))
         self._poison_dev = self._poison_zeros
         self._poisoned = False
-        self._multi_fns: Dict[int, object] = {}
         # run-ahead (docs/SERVING.md "Dispatch pipelining"): the
         # single-tick dispatch still on the device when step() returns;
         # the next step() dispatches its successor BEFORE waiting for
@@ -830,10 +811,6 @@ class Engine:
         # dispatch — hidden under device time, published as the
         # serving.overlap_ms_per_tick gauge, never double-counted
         self._overlap_s = 0.0
-        # EWMA of per-device-tick duration on the INJECTABLE clock —
-        # the deadline clamp's horizon unit (deterministic under the
-        # replay tools' virtual clocks)
-        self._tick_est_ms = 0.0
         self.last_stall_snapshot: Optional[dict] = None
         from ..distributed import watchdog as _watchdog
         self._watchdog = _watchdog
@@ -1083,70 +1060,6 @@ class Engine:
 
         return body
 
-    def _get_multi_fn(self, k: int):
-        """The fused k-tick greedy decode executable — ``k`` decode
-        steps as ONE ``lax.scan`` program (speculative.py's draft loop
-        is the template), dispatched when every live slot is in a
-        pure-greedy stretch. One compile per k bucket (powers of two
-        up to ``multi_tick``, plus ``multi_tick`` itself), so mixed
-        clamp traces bounce between a handful of warm executables with
-        zero steady-state recompiles."""
-        fn = self._multi_fns.get(k)
-        if fn is not None:
-            return fn
-        fn = jax.jit(_named(self._multi_body(k), f"serve_multi_{k}"),
-                     donate_argnums=(1, 3))
-        self._multi_fns[k] = fn
-        self._note_compile()
-        return fn
-
-    def _multi_body(self, k: int):
-        """Traceable body of the k-tick fused decode. Scan step j:
-        rows still ALIVE (live slot, budget > 0) feed their newest
-        token at position ``pos``; frozen rows ride the dead-slot
-        convention (cache_index -1: no page DMA, no compute, scratch-
-        page write) — an in-scan eos zeroes the row's budget so it
-        writes nothing and consumes nothing for the rest of the scan,
-        and a row whose max_new_tokens budget runs out freezes the
-        same way. Greedy only: argmax consumes no rng, keys pass
-        through untouched, so the emitted stream is bit-identical to
-        k single-tick greedy steps. Poison (the decode.nan fault
-        vector) rides into every step's sampling logits; the per-step
-        ``ok`` matrix lets the host quarantine the offending slot at
-        the exact step the NaN appeared."""
-        model = self.model
-
-        def body(st, caches, bt, state, poison):
-            last, pos, temps, topks, topps, keys, live, eosv, bud = state
-
-            def step(carry, _):
-                tok, kv, p, b = carry
-                alive = (live > 0) & (b > 0)
-                idx = jnp.where(alive, p, -jnp.ones_like(p))
-                kvb = self._inject_bt(kv, bt)
-                logits, new_kv = _model_forward(model, st, tok[:, None],
-                                                kvb, idx)
-                cur = logits[:, -1].astype(jnp.float32) + poison[:, None]
-                okr = jnp.isfinite(cur).all(axis=-1)
-                sampled = jnp.argmax(cur, axis=-1).astype(jnp.int32)
-                nxt = jnp.where(alive, sampled, tok)
-                b2 = jnp.where(alive,
-                               jnp.where(sampled == eosv,
-                                         jnp.zeros_like(b), b - 1),
-                               b)
-                return (nxt, self._strip_bt(new_kv), p + alive.astype(
-                    p.dtype), b2), (sampled, okr)
-
-            (tok_f, caches, pos_f, bud_f), (toks, oks) = jax.lax.scan(
-                step, (last, caches, pos, bud), None, length=k)
-            state2 = (tok_f, pos_f, temps, topks, topps, keys, live,
-                      eosv, bud_f)
-            # [S, k] per-step tokens + ok flags: the ONLY fetches
-            return (jnp.swapaxes(toks, 0, 1), jnp.swapaxes(oks, 0, 1),
-                    state2, caches)
-
-        return body
-
     def _get_verify_fn(self, variant: str):
         """The speculative verify executable — ONE fixed-shape
         ``[max_slots, k+1]`` target forward per static sampler variant
@@ -1269,13 +1182,6 @@ class Engine:
                 name=f"decode[{v}]", body=self._decode_body(v),
                 args=(st, pools, bt, state, poison),
                 donate=(1, 3), fetched=(0, 1)))
-        mks = tuple(sorted(self._multi_fns)) \
-            or ((self.multi_tick,) if self.multi_tick > 1 else ())
-        for mk in mks:
-            specs.append(hp.ExecutableSpec(
-                name=f"decode-multi[k={mk}]", body=self._multi_body(mk),
-                args=(st, pools, bt, state, poison),
-                donate=(1, 3), fetched=(0, 1)))
         if self._spec is not None:
             k = self._spec.k
             for v in tuple(self._verify_fns) or variants:
@@ -1297,27 +1203,23 @@ class Engine:
                 donate=(1,), fetched=(0, 1, 2), per_tick=False))
         cache_keys = {"_decode_fns": list(self._decode_fns),
                       "_verify_fns": list(self._verify_fns),
-                      "_prefill_fns": list(self._prefill_fns),
-                      "_multi_fns": list(self._multi_fns)}
+                      "_prefill_fns": list(self._prefill_fns)}
         if self._spec is not None:
             cache_keys["_spec._prefill_fns"] = \
                 list(self._spec._prefill_fns)
         tick = [self.step, self._admit, self._expire,
                 self._run_prefills, self._safe_prefill, self._prefill,
                 self._ensure_pages, self._safe_decode,
-                self._decode_dispatch, self._dispatch_multi,
-                self._dispatch_spec, self._multi_k,
-                self._deadline_ticks, self._lanes, self._drain,
-                self._decode_harvest,
-                self._harvest_single, self._harvest_multi,
-                self._harvest_spec, self._flush_state,
-                self._poison_slot, self._unpoison]
+                self._decode_dispatch, self._dispatch_spec,
+                self._lanes, self._drain, self._decode_harvest,
+                self._harvest_single, self._harvest_spec,
+                self._flush_state, self._poison_slot, self._unpoison]
         return hp.HotpathInventory(
             subject=f"{type(self).__name__}[{self.label}]",
             executables=specs, tick_functions=tick,
-            steady_functions=("_decode_dispatch", "_dispatch_multi",
-                              "_dispatch_spec", "_flush_state",
-                              "_poison_slot", "_unpoison"),
+            steady_functions=("_decode_dispatch", "_dispatch_spec",
+                              "_flush_state", "_poison_slot",
+                              "_unpoison"),
             cache_keys=cache_keys, file=__file__)
 
     def inspect_hotpath(self):
@@ -1348,18 +1250,18 @@ class Engine:
                 lanes.append((i, req, ahead))
         return lanes
 
-    def _dispatch_span_args(self, lanes, variant: str,
-                            ticks: int) -> dict:
+    def _dispatch_span_args(self, lanes, variant: str) -> dict:
         """`engine.decode.dispatch` arguments, for the positions the
         dispatched program READS (the host mirrors plus the tick in
         flight): its lanes, their context, and for a spec with a
         sparse selection or a window the tokens its attention has to
         read, a slot's context counted up to `index_topk` on the
         layers that select (sel_tokens) and up to `window` on the
-        layers that slide (win_tokens)."""
+        layers that slide (win_tokens). `ticks` is the device ticks a
+        dispatch covers, always 1 (the benchmark's readers name it)."""
         pos = [int(self._pos[i]) + ahead for i, _, ahead in lanes]
         args = dict(variant=variant, slots=len(lanes),
-                    ctx_tokens=sum(pos), ticks=ticks,
+                    ctx_tokens=sum(pos), ticks=1,
                     inflight=int(self._inflight is not None))
         spec = self.serving_spec
         for name, cap in (("sel_tokens", spec.get("index_topk")),
@@ -1369,15 +1271,14 @@ class Engine:
         return args
 
     def _pending(self, kind: str, data: tuple, lanes, t0: float,
-                 mark: float, variant: str, ticks: int = 1,
-                 **more) -> _PendingTick:
+                 mark: float, variant: str, extras: tuple = ()
+                 ) -> _PendingTick:
         """The handoff record of the dispatch just made over `lanes`."""
         return _PendingTick(
             kind=kind, data=data,
-            active=[(i, req) for i, req, _ in lanes], ticks=ticks,
-            t_dispatch=t0, dev_mark=mark, variant=variant,
-            span_args=self._dispatch_span_args(lanes, variant, ticks),
-            **more)
+            active=[(i, req) for i, req, _ in lanes],
+            t_dispatch=t0, dev_mark=mark, extras=extras,
+            span_args=self._dispatch_span_args(lanes, variant))
 
     def _dispatch_steady(self, steady, fn, *args):
         """Dispatch one tick executable. On a STEADY tick (warm
@@ -1470,14 +1371,11 @@ class Engine:
         logits, prefill error) retires that request and never raises
         out of here.
 
-        A speculative or fused (``multi_tick=k > 1``, pure-greedy
-        steady stretch, up to k device ticks as ONE scan) dispatch is
-        sized from what the last harvest left, so it is dispatched,
-        waited for and harvested inside one step, and a tick in
-        flight is drained before it. Deadline / queue-timeout
-        enforcement lands on dispatch boundaries, so a request can
-        overrun its deadline_ms by at most one dispatch (k ticks)
-        before _expire retires it."""
+        A speculative dispatch is sized from what the last harvest
+        left, so it is dispatched, waited for and harvested inside one
+        step. Deadline / queue-timeout enforcement lands on step
+        boundaries, so a request can overrun its deadline_ms by at
+        most one step before _expire retires it."""
         with RecordEvent("engine.step", step=self._steps,
                          active=self.num_active,
                          waiting=self.num_waiting,
@@ -1486,7 +1384,6 @@ class Engine:
             # first; a drain inside this step appends here too
             outputs = self._held
             wall0 = time.perf_counter()
-            clk0 = self._clock()
             self._device_s = 0.0
             self._overlap_s = 0.0
             if self._inflight is not None:
@@ -1519,8 +1416,8 @@ class Engine:
                 # (b) a single-tick dispatch stays in flight; the tick the
                 # last step left in flight is waited for and harvested now.
                 # (Nothing dispatched: the tick in flight, if any, is
-                # simply harvested. A spec / fused dispatch found none:
-                # _decode_dispatch drained it.)
+                # simply harvested. A speculative engine never leaves
+                # one.)
                 ahead = pending is not None and pending.kind == "single"
                 carried, self._inflight = \
                     self._inflight, pending if ahead else None
@@ -1539,14 +1436,13 @@ class Engine:
                 outputs.extend(self._run_prefills())
                 self._watchdog.maybe_start_and_tick()
                 if not ahead:
-                    # spec / fused: block on THIS step's dispatch
+                    # spec: block on THIS step's dispatch
                     # (attributed — host work above that hid under device
                     # execution lands in the overlap share)
                     outputs.extend(self._decode_harvest(pending))
                 # (d) page growth for the NEXT dispatch, counting the tick
-                # in flight (multi-tick horizon pre-allocates k ticks of
-                # headroom when free pages allow; a preemption drains the
-                # tick in flight before it reads the victim's key)
+                # in flight (a preemption drains the tick in flight before
+                # it reads the victim's key)
                 self._ensure_pages()
             if self._injector is not None and \
                     self._injector.fire("alloc.refcount_skew",
@@ -1592,10 +1488,7 @@ class Engine:
                 # overlap share is also published on its own so the gate
                 # measures real EXPOSED host cost, never double-counted).
                 # Wall clock, never the injectable clock — timelines stay
-                # deterministic, attribution stays honest. One step = one
-                # dispatch: under multi_tick these are per-DISPATCH values
-                # covering `ticks` device ticks (the sums the bench host-share
-                # gate aggregates stay true trace totals).
+                # deterministic, attribution stays honest.
                 wall_ms = (time.perf_counter() - wall0) * 1e3
                 dev_ms = min(self._device_s * 1e3, wall_ms)
                 host_ms = wall_ms - dev_ms
@@ -1610,16 +1503,6 @@ class Engine:
                 self._mon.histogram("serving.hist.overlap_ms_per_tick").record(
                     ov_ms)
                 self._mon.histogram("serving.hist.tick_ms").record(wall_ms)
-                if pending is not None:
-                    if self.multi_tick > 1:
-                        self._mon.gauge(
-                            "serving.multi_tick.ticks_per_dispatch").set(
-                                pending.ticks)
-                    # per-device-tick duration EWMA on the INJECTABLE clock —
-                    # the deadline clamp's horizon unit (_deadline_ticks)
-                    d_ms = (self._clock() - clk0) * 1e3 / max(1, pending.ticks)
-                    self._tick_est_ms = d_ms if self._tick_est_ms <= 0.0 \
-                        else 0.7 * self._tick_est_ms + 0.3 * d_ms
                 self._steps += 1
                 self._held = []
                 return outputs
@@ -1636,12 +1519,9 @@ class Engine:
         here).
 
         ``heartbeat_timeout=T`` attaches an in-process
-        ``distributed.watchdog.Heartbeat``: every completed step —
-        one DISPATCH, which under ``multi_tick=k`` covers up to k
-        device ticks, so T must exceed the worst-case fused dispatch,
-        not the worst single tick — ticks it, and a loop that makes
-        no progress for T seconds triggers ``_stall_report`` — a
-        per-thread stack dump plus a best-effort host-state snapshot
+        ``distributed.watchdog.Heartbeat``: every completed step ticks
+        it, and a loop that makes no progress for T seconds triggers
+        ``_stall_report`` — a per-thread stack dump plus a best-effort host-state snapshot
         (to ``snapshot_path`` when given, always kept on
         ``last_stall_snapshot``) so a wedged serving process leaves a
         recoverable trail before the pod is killed."""
@@ -1953,16 +1833,10 @@ class Engine:
         wall deadline (waiting OR mid-decode — its pages free this
         tick) and every waiting request past its queue-step budget.
 
-        Enforcement granularity is one DISPATCH, not one device tick:
-        under ``multi_tick=k`` a fused dispatch covers up to k device
-        ticks, so a deadline can be overrun by at most one dispatch
-        before this sweep retires the request (the _deadline_ticks
-        clamp shrinks the fused k toward the nearest deadline, and an
-        expired request's in-flight tokens are discarded at harvest).
-        ``max_queue_steps`` counts step() calls — dispatches — so its
-        wall meaning stretches by up to k during fused stretches; it
-        only ever governs WAITING/PREEMPTED requests, which block
-        fusion anyway (_multi_k admission rung)."""
+        Enforcement granularity is one step(): a deadline can be
+        overrun by at most one step before this sweep retires the
+        request, and an expired request's in-flight token is discarded
+        at harvest. ``max_queue_steps`` counts step() calls."""
         outs: List[Output] = []
         now = self._clock()
         for req in list(self._waiting) + [r for r in self._slots
@@ -2423,11 +2297,7 @@ class Engine:
         flight for the plain decode step, k+1 for a speculative
         draft/verify tick; allocate lazily, preempting the YOUNGEST
         sequence when the pool runs dry (after reclaiming idle
-        prefix-cache pages; a tick in flight is drained first). With multi-tick
-        enabled the horizon stretches toward ``multi_tick`` positions
-        — but only from FREE pages (no eviction, no preemption): a
-        short coverage just clamps the fused k, it never costs another
-        request its cache."""
+        prefix-cache pages; a tick in flight is drained first)."""
         with RecordEvent("engine.ensure_pages") as span:
             allocated = 0
             # a preemption is the only thing here that queues a request
@@ -2446,24 +2316,6 @@ class Engine:
                     allocated += len(page)
                     self._bt[i, :len(req.pages)] = req.pages
                     self._bt_dirty = True
-            if self.multi_tick > 1 and self._spec is None:
-                for i in range(self.max_slots):
-                    req = self._slots[i]
-                    if req is None or req.state != DECODE:
-                        continue
-                    rem = int(req.params.max_new_tokens) \
-                        - len(req.generated)
-                    want = _ceil_div(
-                        req.written + min(max(rem, 1), self.multi_tick),
-                        self.page_size)
-                    while len(req.pages) < want and \
-                            self._alloc.can_alloc(
-                                1, self.watermark_pages):
-                        req.pages.extend(
-                            self._alloc.alloc(1, seq=req.req_id))
-                        allocated += 1
-                        self._bt[i, :len(req.pages)] = req.pages
-                        self._bt_dirty = True
             span.set(allocated=allocated,
                      preempted=len(self._waiting) - waiting0)
 
@@ -2562,9 +2414,9 @@ class Engine:
         come from the host mirrors plus what the host knows of the
         tick in flight (_lanes) — exactly the rows the dispatched
         executable computes, but for a lane that tick ends by its eos.
-        A speculative or fused dispatch is sized from host decisions
-        made on the last harvest (drafts, the clamp on k): it finds no
-        tick in flight, or drains it first."""
+        A speculative dispatch is sized from host decisions made on
+        the last harvest (the drafts) and is harvested inside its own
+        step, so it never finds a tick in flight."""
         lanes = self._lanes()
         if not lanes:
             return None
@@ -2582,25 +2434,7 @@ class Engine:
         self._fault_raise("decode.device_error")
         self._poison_slot(active)
         if self._spec is not None:
-            if self.multi_tick > 1:
-                # spec decode owns the draft/verify horizon: fused
-                # multi-tick never composes with it, every dispatch
-                # in a multi_tick>1 config is an exclusion, not a
-                # silent downgrade
-                self._mon.counter(
-                    "serving.multi_tick.clamp.spec").increase()
             return self._dispatch_spec(lanes, variant)
-        if self._inflight is not None and self._multi_eligible(variant):
-            # single -> fused: the clamp on k reads budgets, pages and
-            # deadlines as the last harvest left them
-            self._drain("kind_switch")
-            lanes = self._lanes()
-            if not lanes:
-                return None
-            active = [i for i, _, _ in lanes]
-        mk = self._multi_k(active, variant)
-        if mk > 1:
-            return self._dispatch_multi(lanes, mk)
         # steady = the dirty-row-merge discipline says this tick
         # uploads nothing and dispatches a warm executable — the
         # PADDLE_TPU_LINT transfer guard may wrap the dispatch
@@ -2626,8 +2460,8 @@ class Engine:
     def _drain(self, cause: str) -> None:
         """Wait for and harvest the tick in flight, NOW: what reads
         the device-resident state or the host's view of a slot as of
-        the newest token (a preemption's key fetch, a fused dispatch's
-        clamp, the public entries that move requests) calls this
+        the newest token (a preemption's key fetch, the public entries
+        that move requests) calls this
         first. The Outputs it retires come out of the step() that is
         running, or of the next one. Counted by cause:
         ``serving.runahead.drains.<cause>``."""
@@ -2636,118 +2470,6 @@ class Engine:
             return
         self._mon.counter("serving.runahead.drains." + cause).increase()
         self._held.extend(self._decode_harvest(pend))
-
-    def _multi_eligible(self, variant: str) -> bool:
-        """The static rungs of the fused decode's ladder (_multi_k):
-        every live slot in a pure-greedy stretch, nothing pending
-        host-side."""
-        return not (self.multi_tick <= 1 or self._spec is not None
-                    or variant != "greedy" or self._waiting
-                    or self._poisoned or self.num_prefilling)
-
-    def _multi_k(self, active: List[int], variant: str) -> int:
-        """Eligibility ladder + per-dispatch clamp for the fused
-        multi-tick decode (docs/SERVING.md "Dispatch pipelining &
-        multi-tick decode"). Eligible only when EVERY live slot is in
-        a pure-greedy stretch with nothing pending host-side: greedy
-        variant (no sampler rng), no waiting admissions, no
-        mid-prefill slot, no speculative decoder, no armed poison
-        tick (quarantine timing must match single-tick). The fused
-        length is then clamped so no slot can overrun its allocated
-        page coverage at all, or its max_new_tokens / deadline_ms by
-        more than one dispatch, and rounded DOWN to a compiled k
-        bucket (the in-scan budget freeze makes running FEWER ticks
-        than a row needs always exact)."""
-        K = self.multi_tick
-        if not self._multi_eligible(variant):
-            return 1
-        horizon = 0      # longest remaining budget over live rows
-        cov = None       # tightest allocated-page coverage
-        for i in active:
-            req = self._slots[i]
-            horizon = max(horizon, int(req.params.max_new_tokens)
-                          - len(req.generated))
-            c = len(req.pages) * self.page_size - req.written
-            cov = c if cov is None else min(cov, c)
-        k = K
-        if horizon < k:
-            # no point scanning past the longest remaining budget —
-            # every row would be frozen (shorter rows freeze in-graph;
-            # this clamp only drops dead trailing ticks)
-            k = horizon
-            self._mon.counter(
-                "serving.multi_tick.clamp.max_new").increase()
-        if cov is not None and cov < k:
-            # page-boundary horizon: the scan writes up to k positions
-            # with no host allocator in the loop, so k is HARD-capped
-            # by the tightest slot's allocated coverage (_ensure_pages
-            # pre-extends toward multi_tick when free pages allow)
-            k = cov
-            self._mon.counter(
-                "serving.multi_tick.clamp.pages").increase()
-        dl = self._deadline_ticks(active)
-        if dl < k:
-            k = dl
-            self._mon.counter(
-                "serving.multi_tick.clamp.deadline").increase()
-        if k < 2:
-            return 1
-        return self._multi_bucket(k)
-
-    def _multi_bucket(self, k: int) -> int:
-        """Largest compiled k bucket <= k: powers of two, plus
-        ``multi_tick`` itself (so the configured maximum is one warm
-        executable, not two) — a bounded executable set whatever the
-        clamp trace does, keeping steady_state_recompiles()==0."""
-        best = 2
-        b = 2
-        while b * 2 <= k:
-            b *= 2
-            best = b
-        if self.multi_tick <= k:
-            best = max(best, self.multi_tick)
-        return best
-
-    def _deadline_ticks(self, active: List[int]) -> int:
-        """Ticks until the nearest active deadline, in units of the
-        per-device-tick EWMA on the injectable clock — the deadline
-        leg of the multi-tick clamp. Unbounded (multi_tick) when no
-        slot has a deadline or no tick estimate exists yet; a slot
-        that still overshoots (estimate drift) is bounded by the
-        at-most-one-dispatch guarantee and expired by _expire on the
-        next step."""
-        est = self._tick_est_ms
-        if est <= 0.0:
-            return self.multi_tick
-        ticks = self.multi_tick
-        now = self._clock()
-        for i in active:
-            req = self._slots[i]
-            dl = req.params.deadline_ms
-            if dl is None:
-                continue
-            left = float(dl) - (now - req.arrival_t) * 1e3
-            ticks = min(ticks, int(left // est))
-        return max(1, ticks)
-
-    def _dispatch_multi(self, lanes, k: int) -> _PendingTick:
-        """Dispatch ONE fused k-tick greedy scan. Each slot's eos id
-        and remaining-token budget are part of the device-resident
-        state every decode program advances in-graph, so back-to-back
-        fused dispatches ship nothing host-to-device."""
-        steady = (k in self._multi_fns
-                  and not self._dirty and not self._bt_dirty)
-        fn = self._get_multi_fn(k)
-        self._flush_state()
-        mark = self._device_s
-        t0 = time.perf_counter()
-        toks, oks, self._dev, self._pools = self._dispatch_steady(
-            steady, fn, self._st, self._pools, self._bt_dev,
-            self._dev, self._poison_dev)
-        self._mon.counter("serving.multi_tick.dispatches").increase()
-        self._mon.counter("serving.multi_tick.ticks").increase(k)
-        return self._pending("multi", (toks, oks), lanes, t0, mark,
-                             "greedy", ticks=k, k=k)
 
     def _decode_harvest(self, pend: Optional[_PendingTick]
                         ) -> List[Output]:
@@ -2762,9 +2484,8 @@ class Engine:
         with RecordEvent("engine.decode.wait"):
             self._sync_timed(pend.data, dispatch_t=pend.t_dispatch,
                              dev_mark=pend.dev_mark)
-        harvest = {"multi": self._harvest_multi,
-                   "spec": self._harvest_spec}.get(
-                       pend.kind, self._harvest_single)
+        harvest = self._harvest_spec if pend.kind == "spec" \
+            else self._harvest_single
         with RecordEvent("engine.harvest") as span:
             emitted = _Emitted(self._clock())
             outs = harvest(pend, emitted)
@@ -2819,59 +2540,6 @@ class Engine:
                 "serving.runahead.dead_lane_ticks").increase(dead)
         return outs
 
-    def _harvest_multi(self, pend: _PendingTick,
-                       emitted: _Emitted) -> List[Output]:
-        """Walk the fused dispatch's [S, k] token/ok matrices exactly
-        as k single-tick harvests would: append until the row's eos or
-        length exit (the same condition that froze it in-graph — the
-        walk never reads past the freeze point), fail the slot at the
-        first not-ok step keeping its earlier tokens, and discard the
-        post-finish garbage columns."""
-        toks = np.asarray(pend.data[0])
-        oks = np.asarray(pend.data[1])
-        outs: List[Output] = []
-        exited = False
-        for i, req in pend.active:
-            if self._slots[i] is not req or req.state != DECODE:
-                continue          # retired in the overlap window
-            done = False
-            for j in range(pend.k):
-                if not bool(oks[i, j]):
-                    # NaN/inf logits at scan step j: quarantine the
-                    # slot; tokens 0..j-1 were clean and are kept
-                    self._mon.counter(
-                        "serving.nan_quarantines").increase()
-                    self._mon.counter(
-                        "serving.multi_tick.scan_exit.nan_logits"
-                    ).increase()
-                    outs.append(self._fail(req, "nan_logits"))
-                    done = True
-                    break
-                tok = int(toks[i, j])
-                req.written += 1
-                self._pos[i] = req.written
-                emitted.append(req, tok)
-                self._last[i] = tok
-                reason = self._finish_reason(req, tok)
-                if reason:
-                    self._mon.counter(
-                        "serving.multi_tick.scan_exit." + reason
-                    ).increase()
-                    outs.append(self._finish(req, reason))
-                    done = True
-                    break
-            if done:
-                exited = True
-            else:
-                # stamp the open DECODE stint with its fused progress
-                tracing.bump_open(req.spans, tracing.DECODE,
-                                  multi_ticks=pend.k,
-                                  multi_dispatches=1)
-        if not exited:
-            self._mon.counter(
-                "serving.multi_tick.scan_exit.horizon").increase()
-        return outs
-
     def _poison_slot(self, active: List[int]) -> None:
         """decode.nan fault point: pick one active slot (seeded rng)
         and ride a NaN into its sampling logits this tick — the
@@ -2904,7 +2572,6 @@ class Engine:
                   and not self._dirty and not self._bt_dirty
                   and not self._poisoned)
         self._flush_state()
-        k = self._spec.k
         mark = self._device_s
         t0 = time.perf_counter()
         drafts = self._spec.draft(self._bt_dev, self._dev[0],
@@ -2921,14 +2588,14 @@ class Engine:
             drafts, self._poison_dev)
         self._unpoison()
         return self._pending("spec", (toks, acc, okv), lanes, t0, mark,
-                             variant, k=k)
+                             variant)
 
     def _harvest_spec(self, pend: _PendingTick,
                       emitted: _Emitted) -> List[Output]:
         toks = np.asarray(pend.data[0])
         acc = np.asarray(pend.data[1])
         okv = np.asarray(pend.data[2])
-        k = pend.k
+        k = self._spec.k
         outs: List[Output] = []
         for i, req in pend.active:
             if self._slots[i] is not req or req.state != DECODE:

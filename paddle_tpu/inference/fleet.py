@@ -175,7 +175,7 @@ class ServingFleet:
                  max_prefill_tokens_per_step: Optional[int] = None,
                  router: str = "session",
                  heartbeat_timeout: Optional[float] = None,
-                 autoscale=None, multi_tick: int = 1):
+                 autoscale=None):
         if int(replicas) < 1:
             raise ValueError(f"need at least one replica, got {replicas}")
         if router not in ROUTERS:
@@ -206,8 +206,7 @@ class ServingFleet:
             clock=self._clock,
             fault_injector=(self._injector
                             if self._injector is not None else False),
-            max_prefill_tokens_per_step=max_prefill_tokens_per_step,
-            multi_tick=int(multi_tick))
+            max_prefill_tokens_per_step=max_prefill_tokens_per_step)
         if autoscale is True:
             autoscale = AutoscalePolicy()
         self._policy: Optional[AutoscalePolicy] = autoscale

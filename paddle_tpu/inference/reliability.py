@@ -313,10 +313,6 @@ def _fingerprint(eng) -> Dict[str, object]:
             "prefix_cache": eng._prefix is not None,
             "max_prefill_tokens_per_step":
                 eng.max_prefill_tokens_per_step,
-            # fused-dispatch width is pure scheduling: k single-tick
-            # greedy steps and one fused k-tick scan emit identical
-            # tokens, so restoring across multi_tick widths is safe
-            "multi_tick": getattr(eng, "multi_tick", 1),
         },
     }
 

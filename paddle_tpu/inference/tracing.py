@@ -102,21 +102,6 @@ def seal(spans: List[dict], phase: str, t_ms: float, origin: str,
     spans.append(sp)
 
 
-def bump_open(spans: List[dict], phase: str, **counts) -> None:
-    """Accumulate numeric detail onto the trailing OPEN span when its
-    phase matches — the multi-tick decode path stamps each fused
-    dispatch's tick count onto the request's single tick-aggregated
-    DECODE stint (spans stay O(lifecycle transitions), not
-    O(dispatches)). No-op when nothing matching is open (a harvest
-    that just sealed the span, a restore mid-stretch)."""
-    if not spans or spans[-1].get("t1_ms") is not None \
-            or spans[-1].get("phase") != phase:
-        return
-    det = spans[-1].setdefault("detail", {})
-    for k, v in counts.items():
-        det[k] = det.get(k, 0) + v
-
-
 def current_phase(spans: List[dict]) -> Optional[str]:
     """Phase of the trailing OPEN span (None when nothing is open)."""
     if spans and spans[-1].get("t1_ms") is None:

@@ -86,8 +86,6 @@ def test_headline_prints_first_and_extras_append(stubbed, capsys,
                 "llama_1b_decode_paged_vs_dense_ratio",
                 "llama_1b_serving_tokens_per_sec",
                 "llama_1b_serving_host_share_per_tick",
-                "llama_1b_serving_multi_tick_tokens_per_sec",
-                "llama_1b_serving_multi_tick_host_share",
                 "llama_1b_serving_int8kv_tokens_per_sec",
                 "llama_1b_serving_prefix_tokens_per_sec",
                 "llama_1b_serving_spec_tokens_per_sec",
@@ -129,7 +127,6 @@ def test_budget_skips_extras_but_headline_survives(stubbed, capsys,
         "llama_decode_int8kv", "llama_decode_int8",
         "llama_decode_paged", "llama_decode_paged_int8",
         "llama_decode_rolling", "llama_serving",
-        "llama_serving_multi_tick",
         "llama_serving_int8kv", "llama_serving_prefix",
         "llama_serving_spec", "llama_serving_longctx",
         "llama_serving_chaos", "llama_serving_disagg",

@@ -102,7 +102,6 @@ def test_return_logits_needs_keep_logits(tiny):
 
 @pytest.mark.parametrize("option,kwargs", [
     ("prefix_cache", dict(prefix_cache=True)),
-    ("multi_tick", dict(multi_tick=4)),
     ("cache_dtype='int8'", dict(cache_dtype="int8")),
     ("draft_model", dict(draft_model="any")),
 ])
@@ -307,7 +306,7 @@ def test_decode_span_arguments_and_window_gauge(tiny):
         # lane 1 rides the tick in flight: the program reads it one
         # position past the host's mirror
         args = eng._dispatch_span_args([(0, None, 0), (1, None, 1)],
-                                       "greedy", 1)
+                                       "greedy")
         assert args == {"variant": "greedy", "slots": 2, "ticks": 1,
                         "inflight": 0, "ctx_tokens": 20 + 4,
                         "sel_tokens": 8 + 5, "win_tokens": 5 + 5}

@@ -198,7 +198,7 @@ def test_dispatch_args_are_what_the_program_reads():
 
 def test_runahead_counters_count_what_the_case_did():
     names = ("dispatches", "drains.api", "drains.preempt",
-             "drains.kind_switch", "dead_lane_ticks")
+             "dead_lane_ticks")
 
     def read():
         snap = monitor.snapshot()
@@ -211,11 +211,11 @@ def test_runahead_counters_count_what_the_case_did():
     for _ in range(4):                      # prefill, ticks 1..3
         outs += eng.step()
     # ticks 2 and 3 were dispatched behind a tick in flight
-    assert [a - b for a, b in zip(read(), c0)] == [2, 0, 0, 0, 0]
+    assert [a - b for a, b in zip(read(), c0)] == [2, 0, 0, 0]
     held = len(eng.requests[rid].generated)
     out = eng.cancel(rid)                   # drains tick 3 first
     assert len(out.token_ids) == held + 1
-    assert [a - b for a, b in zip(read(), c0)] == [2, 1, 0, 0, 0]
+    assert [a - b for a, b in zip(read(), c0)] == [2, 1, 0, 0]
     # an eos the host cannot foresee: its lane rides one dead tick
     ref = eng.run([(_prompt(5), SamplingParams(max_new_tokens=6))])[0]
     eos = ref.token_ids[2]
@@ -226,7 +226,7 @@ def test_runahead_counters_count_what_the_case_did():
     assert out.token_ids == ref.token_ids[:3]
     # tick 1 alone, ticks 2 and the dead tick 3 behind one in flight;
     # run() ends by harvesting the dead tick
-    assert [a - b for a, b in zip(read(), c1)] == [2, 1, 0, 0, 1]
+    assert [a - b for a, b in zip(read(), c1)] == [2, 1, 0, 1]
     assert monitor.counter("serving.steps").get() > s0
     assert eng.idle and eng.leaked_pages() == 0
 
@@ -260,13 +260,12 @@ def test_recording_changes_no_token_and_no_timeline():
     assert _run_virtual(record=False) == _run_virtual(record=True)
 
 
-@pytest.mark.parametrize("multi_tick", [1, 4])
-def test_itl_histogram_counts_tokens_minus_requests(multi_tick):
+def test_itl_histogram_counts_tokens_minus_requests():
     hist = monitor.histogram("serving.hist.itl_ms")
     tokens = monitor.counter("serving.tokens")
     h0, z0, t0 = hist.count, hist.to_dict()["zeros"], tokens.get()
     vt = [1.0]
-    eng = _engine(clock=lambda: vt[0], multi_tick=multi_tick)
+    eng = _engine(clock=lambda: vt[0])
     news = (6, 9, 4)
     for i, new in enumerate(news):
         eng.add_request(_prompt(4 + i, i + 1),
@@ -278,12 +277,8 @@ def test_itl_histogram_counts_tokens_minus_requests(multi_tick):
     assert sorted(len(o.token_ids) for o in outs) == sorted(news)
     assert tokens.get() - t0 == sum(news)
     assert hist.count - h0 == sum(news) - len(news)
-    if multi_tick == 1:
-        # one token a step, the clock moves between steps: no 0 gap
-        assert hist.to_dict()["zeros"] == z0
-    else:
-        # the k tokens of a fused dispatch arrive together
-        assert hist.to_dict()["zeros"] > z0
+    # one token a step, the clock moves between steps: no 0 gap
+    assert hist.to_dict()["zeros"] == z0
 
 
 def test_extract_request_resets_the_gap_stamp():
@@ -339,10 +334,9 @@ def test_jax_trace_shows_span_args_and_program_names(tmp_path):
 
 
 def test_program_names_of_every_executable_family():
-    eng = _engine(multi_tick=4)
+    eng = _engine()
     assert eng._get_decode_fn("plain").__name__ == "serve_decode_plain"
     assert eng._get_prefill_fn(32).__name__ == "serve_prefill_32"
-    assert eng._get_multi_fn(4).__name__ == "serve_multi_4"
     assert eng._get_verify_fn("greedy").__name__ == "serve_verify_greedy"
 
 

@@ -21,7 +21,7 @@ from paddle_tpu.text.models import LlamaConfig, LlamaForCausalLM
 
 PAGE = 8
 COUNTERS = ("dispatches", "dead_lane_ticks", "drains.api",
-            "drains.preempt", "drains.kind_switch")
+            "drains.preempt")
 
 
 def _net(seed=0):
@@ -222,7 +222,7 @@ def test_greedy_sampled_transitions_run_ahead(net):
     _exact(net, done, ids, reqs)
     assert set(eng._decode_fns) == {"greedy", "plain", "filtered"}
     d = _delta(before)
-    assert d["drains.kind_switch"] == d["drains.api"] == 0
+    assert d["drains.preempt"] == d["drains.api"] == 0
     eng.close()
 
 
@@ -407,22 +407,81 @@ def test_speculative_engine_keeps_no_tick_in_flight(net):
     eng.close()
 
 
-def test_multi_tick_engine_drains_on_a_switch_of_kinds(net):
-    """A greedy stretch fuses; a sampled arrival makes the dispatches
-    single (run-ahead); when it leaves, the switch back to the fused
-    scan drains the tick in flight."""
-    reqs = [(_prompt(5), SamplingParams(max_new_tokens=40)),
-            (_prompt(7, 3), SamplingParams(max_new_tokens=6,
-                                           temperature=0.9, seed=3))]
+def test_pure_greedy_stretch_never_drains(net):
+    """All-greedy lanes with nothing waiting or prefilling: every decode
+    dispatch but the first queues behind a tick in flight, and nothing
+    forces a drain before the run's end."""
+    reqs = [(_prompt(5), SamplingParams(max_new_tokens=24)),
+            (_prompt(7, 3), SamplingParams(max_new_tokens=24))]
     before = _counts()
-    m0 = monitor.counter("serving.multi_tick.dispatches").get()
-    eng = _engine(net, multi_tick=4)
-    done, ids = _drive(eng, {0: reqs[:1], 4: reqs[1:]})
+    eng = _engine(net)
+    ids = [eng.add_request(p, sp) for p, sp in reqs]
+    done, steps = {}, 0
+    while not eng.idle:
+        for o in eng.step():
+            done[o.req_id] = o
+        steps += 1
+        # step 1 holds the two prefills, the last step only a harvest;
+        # every step between leaves its tick in flight
+        assert (eng._inflight is not None) == (1 < steps < 25)
     _exact(net, done, ids, reqs)
+    assert steps == 1 + 23 + 1
     d = _delta(before)
-    assert d["dispatches"] > 0 and d["drains.kind_switch"] > 0
-    assert monitor.counter("serving.multi_tick.dispatches").get() > m0
+    # 23 decode dispatches, all but the first behind a tick in flight
+    assert d.pop("dispatches") == 23 - 1
+    assert d == dict.fromkeys(d, 0)
     eng.close()
+
+
+@pytest.mark.parametrize("door", ["Engine", "DisaggEngine", "ServingFleet"])
+def test_front_doors_take_no_multi_tick(net, door):
+    from paddle_tpu import inference
+    with pytest.raises(TypeError, match="multi_tick"):
+        getattr(inference, door)(net, multi_tick=4)
+
+
+def test_snapshot_with_a_multi_tick_field_restores(net):
+    """Builds up to PR 30 wrote "multi_tick" into the soft fingerprint;
+    such a snapshot restores strictly, silently and token-exact."""
+    import warnings
+    reqs = [(_prompt(5), SamplingParams(max_new_tokens=12)),
+            (_prompt(9, 7), SamplingParams(max_new_tokens=9,
+                                           temperature=0.8, seed=5))]
+    eng = _engine(net)
+    ids = [eng.add_request(p, sp) for p, sp in reqs]
+    for _ in range(4):
+        eng.step()
+    snap = eng.snapshot()
+    eng.close()
+    assert "multi_tick" not in snap["fingerprint"]["soft"]
+    snap["fingerprint"]["soft"]["multi_tick"] = 4
+    eng2 = _engine(net)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        assert eng2.restore(snap, strict=True) == len(ids)
+    done, _ = _drive(eng2, {0: []})
+    _exact(net, done, ids, reqs)
+    eng2.close()
+
+
+def test_hotpath_inventory_names_the_families_that_exist(net):
+    eng = _engine(net, draft_model=_net(seed=1), spec_k=3)
+    inv = eng._hotpath_inventory()
+    families = {e.name.split("[")[0] for e in inv.executables}
+    assert families == {"decode", "verify", "prefill", "draft-loop",
+                        "draft-prefill"}
+    assert set(inv.cache_keys) == {"_decode_fns", "_verify_fns",
+                                   "_prefill_fns", "_spec._prefill_fns"}
+    ticks = {f.__name__ for f in inv.tick_functions}
+    assert {"_decode_dispatch", "_dispatch_spec", "_harvest_single",
+            "_harvest_spec", "_flush_state", "_drain"} <= ticks
+    assert all(hasattr(eng, n) for n in ticks | set(inv.steady_functions))
+    eng.close()
+    plain = _engine(net)
+    assert {e.name.split("[")[0]
+            for e in plain._hotpath_inventory().executables} == \
+        {"decode", "prefill"}
+    plain.close()
 
 
 def test_close_and_run_leave_nothing_in_flight(net):

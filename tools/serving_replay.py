@@ -19,22 +19,6 @@ Run: python tools/serving_replay.py trace.jsonl [--max-slots 4]
          [--replicas N --route session] [--kill-replica 1:40]
          [--trace-out spans.json] [--expect-complete-timelines]
          [--expect-hotpath-clean]
-         [--multi-tick K] [--expect-host-share PCT]
-
-``--multi-tick K`` replays with multi-tick fused decode enabled
-(docs/SERVING.md "Dispatch pipelining & multi-tick decode"): when
-every live slot is pure-greedy the engine runs up to K device ticks
-per host round-trip as one fused scan executable. Works under
-``--disagg`` / ``--replicas`` (decode workers / every replica inherit
-K). The report's ``host_device`` block grows ``overlap_ms_per_tick``
-(host work hidden inside the dispatch window), the measured-run
-``host_share`` and a ``multi_tick`` sub-block (fused dispatches /
-ticks / mean ticks_per_dispatch), and the ``serving.multi_tick.*``
-counter deltas land next to the rest. ``--expect-host-share PCT``
-(exit 14) fails the replay when host time exceeds PCT percent of
-(host+device) tick time over the measured run — the raw-speed CI
-gate (docs/PERF.md "Host share"); pair it with ``--multi-tick`` on
-greedy traces.
 
 ``--expect-hotpath-clean`` (exit 13) lints the DRAINED serving
 surface through ``inspect_hotpath()`` (analysis/hotpath_lint.py):
@@ -455,20 +439,6 @@ def main(argv=None) -> int:
                     help="fail (exit 11) when steady_state_recompiles "
                          "ends nonzero — the bucket/trace-churn CI "
                          "guard (either mode)")
-    ap.add_argument("--multi-tick", type=int, default=1, metavar="K",
-                    help="fuse up to K greedy decode ticks per host "
-                         "round-trip (Engine(multi_tick=K), one "
-                         "lax.scan executable per k bucket) — "
-                         "token-exact vs K=1 by construction; "
-                         "docs/SERVING.md 'Dispatch pipelining & "
-                         "multi-tick decode'")
-    ap.add_argument("--expect-host-share", type=float, default=None,
-                    metavar="PCT",
-                    help="exit 14 when host_ms/(host_ms+device_ms) "
-                         "over the measured ticks exceeds PCT "
-                         "(fraction, e.g. 0.10) — the ROADMAP item 5 "
-                         "host-share gate on a replayed trace (wall "
-                         "clock: gate on a quiet machine)")
     ap.add_argument("--max-prefill-tokens", type=int, default=None,
                     help="chunked prefill: at most this many prompt "
                          "tokens are prefilled per engine step, "
@@ -615,9 +585,6 @@ def main(argv=None) -> int:
              args.expect_prefix_hit_rate is not None),
             ("--expect-p99-ttft-ms",
              args.expect_p99_ttft_ms is not None),
-            ("--multi-tick", args.multi_tick != 1),
-            ("--expect-host-share",
-             args.expect_host_share is not None),
             ("--model ernie_moe", args.model == "ernie_moe"),
             ("--trace-out", args.trace_out is not None),
             ("--expect-complete-timelines",
@@ -785,8 +752,7 @@ def main(argv=None) -> int:
                   draft_model=draft, spec_k=max(args.spec_k, 1),
                   clock=lambda: vt_box["vt"] / 1e3,
                   fault_injector=injector,
-                  max_prefill_tokens_per_step=args.max_prefill_tokens,
-                  multi_tick=args.multi_tick)
+                  max_prefill_tokens_per_step=args.max_prefill_tokens)
         if args.disagg:
             return DisaggEngine(net,
                                 prefill_workers=args.prefill_workers,
@@ -1020,19 +986,11 @@ def main(argv=None) -> int:
                                "serving.prefix_", "serving.spec_",
                                "serving.timeouts", "serving.cancelled",
                                "serving.failed",
-                               # multi-tick COUNTERS only — the namespace
-                               # also holds the ticks_per_dispatch gauge
-                               "serving.multi_tick.dispatches",
-                               "serving.multi_tick.ticks",
-                               "serving.multi_tick.clamp.",
-                               "serving.multi_tick.scan_exit.",
                                "serving.nan_quarantines",
                                "serving.step_errors",
                                "serving.invariant_repairs",
                                "serving.fault_injected.",
                                "lint.hotpath.", "xla.compiles"))
-              # prefix-collides with the .ticks counter above
-              and k != "serving.multi_tick.ticks_per_dispatch"
               and int(after.get(k, 0)) - int(before.get(k, 0))}
     # the per-replay decode-path breakdown: which attention path the
     # compiled loops actually baked in (trace-time counters,
@@ -1088,8 +1046,6 @@ def main(argv=None) -> int:
     _dev_sum = float(_dh.get("mean", 0.0)) * int(_dh.get("count", 0))
     host_share = (_host_sum / (_host_sum + _dev_sum)
                   if _host_sum + _dev_sum > 0 else 0.0)
-    _fused_d = deltas.get("serving.multi_tick.dispatches", 0)
-    _fused_t = deltas.get("serving.multi_tick.ticks", 0)
     report["host_device"] = {
         "host_ms_per_tick": detail.get("serving.host_ms_per_tick",
                                        {"last": 0.0, "mean": 0.0}),
@@ -1100,15 +1056,6 @@ def main(argv=None) -> int:
         "overlap_ms_per_tick": detail.get("serving.overlap_ms_per_tick",
                                           {"last": 0.0, "mean": 0.0}),
         "host_share": round(host_share, 4),
-        "multi_tick": {
-            "k": int(args.multi_tick),
-            "fused_dispatches": _fused_d,
-            "fused_ticks": _fused_t,
-            # mean fused width across multi-tick dispatches (1.0 when
-            # fusion never engaged: mixed sampling, spec, or K=1)
-            "ticks_per_dispatch": round(_fused_t / _fused_d, 2)
-            if _fused_d else 1.0,
-        },
     }
     # stitched per-request timelines (span logs ride the Outputs)
     timelines = {rid: out.spans for rid, (out, _) in finish.items()
@@ -1267,11 +1214,7 @@ def main(argv=None) -> int:
               f"overlap_ms_per_tick "
               f"{hd['overlap_ms_per_tick'].get('mean', 0.0):.3f}   "
               f"(wall clock, mean/tick)")
-        print(f"  host_share {hd['host_share']:.4f}  "
-              f"ticks_per_dispatch "
-              f"{hd['multi_tick']['ticks_per_dispatch']:.2f}  "
-              f"(k={hd['multi_tick']['k']}, "
-              f"{hd['multi_tick']['fused_dispatches']} fused dispatches)")
+        print(f"  host_share {hd['host_share']:.4f}")
         for name, st in report["histograms"].items():
             print(f"  {name:32s} n {st['count']:5d}  "
                   f"p50 {st['p50']:8.2f}  p90 {st['p90']:8.2f}  "
@@ -1485,20 +1428,6 @@ def main(argv=None) -> int:
               f"the drained serving surface:\n{hotpath_report.format()}"
               f"\n(docs/ANALYSIS.md 'Hot-path rules')", file=sys.stderr)
         return 13
-    if args.expect_host_share is not None:
-        hd = report["host_device"]
-        if hd["host_share"] * 100.0 > args.expect_host_share:
-            print(f"serving_replay: --expect-host-share FAILED — "
-                  f"host share {hd['host_share'] * 100.0:.2f}% of "
-                  f"(host+device) tick time exceeds the "
-                  f"{args.expect_host_share:.2f}% budget "
-                  f"(host {hd['host_ms_per_tick'].get('mean', 0.0):.3f} "
-                  f"ms/tick, device "
-                  f"{hd['device_ms_per_tick'].get('mean', 0.0):.3f} "
-                  f"ms/tick, ticks_per_dispatch "
-                  f"{hd['multi_tick']['ticks_per_dispatch']:.2f}; "
-                  f"docs/PERF.md 'Host share')", file=sys.stderr)
-            return 14
     return 0
 
 
