@@ -35,8 +35,9 @@ heads: ``[c_kv ; k_rope]`` padded to whole 128-lane tiles (576 -> 640 on
 a full layer, 1088 -> 1152 on a sliding one), plus the indexer's key
 (128) in a pool of its own on full layers. A chunk of more than one
 token (prefill) gathers the rows it may attend and EXPANDS them to
-per-head keys and values, in query blocks; a one-token step (decode)
-ABSORBS ``W_kvb`` into the query and the output and runs
+per-head keys and values, in query blocks (on a sliding layer a block
+scores only the band of rows its window can keep); a one-token step
+(decode) ABSORBS ``W_kvb`` into the query and the output and runs
 ``kernels.paged_attention.paged_mla_decode`` on the latent rows
 themselves: full layers under the indexer's mask, sliding layers over
 the pages that hold the last ``sliding_window_size`` positions and no
@@ -332,13 +333,19 @@ class Dots3LatentAttention(Layer):
     # -- attention over gathered rows (prefill, and the cache-less pass) ----
 
     def _attend_expanded(self, q_nope, q_rope, gate, idx, rows, kI_rows,
-                         q_pos, k_pos):
+                         q_pos, k_pos, align):
         """Expanded attention of s queries over L gathered latent rows:
         K and V are computed from the rows for every head, the scores
         of `q_block` queries at a time. rows [b, L, row], kI_rows
-        [b, L, dI] | None, q_pos [b, s], k_pos [b, L] (absolute).
-        Returns [b, s, H * d_v] gated."""
+        [b, L, dI] | None, q_pos [b, s], k_pos [b, L] (absolute and
+        consecutive along L). Returns [b, s, H * d_v] gated.
+
+        On a sliding layer a block of queries scores only the `band`
+        rows its window can keep: a slice of static length that starts
+        at a multiple of `align` (the page size, where there are pages)
+        at or below the first key its first query keeps."""
         b, s, H = q_nope.shape[:3]
+        L = rows.shape[1]
         cdt = rows.dtype
         w_k, w_v = self._w_kvb()
         c_kv = rows[..., :self.kv_rank]
@@ -352,6 +359,13 @@ class Dots3LatentAttention(Layer):
         v = jnp.einsum("bLc,chd->bLhd", c_kv, w_v.astype(cdt))
         qb = self.q_block if s % self.q_block == 0 else s
         nblk = s // qb
+        band = L
+        if self.window is not None:
+            # qb + window - 1 rows from the first kept key, up to
+            # align - 1 rows between it and the slice's start
+            band = min(L, -(-(qb + self.window - 1) // align) * align + align)
+            monitor.counter("kernels.prefill.swa_band" if band < L else
+                            "kernels.prefill.swa_whole").increase()
 
         def split(a):                 # [b, s, ...] -> [nblk, b, qb, ...]
             return jnp.moveaxis(
@@ -359,9 +373,16 @@ class Dots3LatentAttention(Layer):
 
         def block(args):
             q, qp, sel = args
-            keep = k_pos[:, None, :] <= qp[:, :, None]        # [b, qb, L]
+            k_blk, v_blk, k_pos_blk = k, v, k_pos
+            if band < L:
+                first = qp[:, 0] - (self.window - 1) - k_pos[:, 0]
+                start = jnp.clip(first // align * align, 0, L - band)
+                k_blk, v_blk, k_pos_blk = (
+                    jax.vmap(lambda a, i: jax.lax.dynamic_slice_in_dim(
+                        a, i, band))(a, start) for a in (k, v, k_pos))
+            keep = k_pos_blk[:, None, :] <= qp[:, :, None]    # [b, qb, band]
             if self.window is not None:
-                keep &= qp[:, :, None] - k_pos[:, None, :] < self.window
+                keep &= qp[:, :, None] - k_pos_blk[:, None, :] < self.window
             if sel is not None:
                 qI, wI = sel
                 I = jnp.einsum(
@@ -369,17 +390,16 @@ class Dots3LatentAttention(Layer):
                         "bqjd,bLd->bqjL", qI, kI_rows,
                         preferred_element_type=jnp.float32)))
                 I = jnp.where(keep, I, -jnp.inf)
-                L = I.shape[-1]
                 keep &= topk_mask(I.reshape(b * qb, L),
                                   self.topk).reshape(b, qb, L)
-            sc = jnp.einsum("bqhd,bLhd->bhqL", q, k,
+            sc = jnp.einsum("bqhd,bLhd->bhqL", q, k_blk,
                             preferred_element_type=jnp.float32)
             sc = jnp.where(keep[:, None], sc * jnp.float32(self.scale),
                            NEG_INF)
             # softmax with the division moved behind the value matmul:
             # every row keeps at least one key, so exp(NEG_INF - max) = 0
             p = jnp.exp(sc - jnp.max(sc, axis=-1, keepdims=True))
-            out = jnp.einsum("bhqL,bLhd->bqhd", p.astype(cdt), v,
+            out = jnp.einsum("bhqL,bLhd->bqhd", p.astype(cdt), v_blk,
                              preferred_element_type=jnp.float32)
             return out / jnp.moveaxis(jnp.sum(p, axis=-1), 1, 2)[..., None]
 
@@ -438,7 +458,7 @@ class Dots3LatentAttention(Layer):
             q_nope, q_rope, row, gate, idx = self._project(u, pos)
             out = self._attend_expanded(
                 q_nope, q_rope, gate, idx, row,
-                None if idx is None else idx[2], pos, pos)
+                None if idx is None else idx[2], pos, pos, self.q_block)
             return self.o_proj(wrap(out))
         pools, bt = kv_cache[:-1], kv_cache[-1]
         pool = pools[0]
@@ -494,7 +514,8 @@ class Dots3LatentAttention(Layer):
                     sel = (qI.astype(kI_rows.dtype), wI, None)
                 return self._attend_expanded(
                     q_nope, q_rope, gate, sel,
-                    paged.gather_rows(pool, cols), kI_rows, pos, k_pos)
+                    paged.gather_rows(pool, cols), kI_rows, pos, k_pos,
+                    bs_)
 
             qI, wI = (None, None) if idx is None else idx[:2]
             ops = (q_nope, q_rope, gate, qI, wI, pool,

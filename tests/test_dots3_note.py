@@ -184,6 +184,57 @@ def test_absorbed_decode_equals_expanded_attention(tiny):
         np.testing.assert_allclose(unwrap(got)[:, 0], want, atol=TOL)
 
 
+@pytest.mark.parametrize("starts,s,width", [
+    ([0], 16, 6), ([5], 16, 6), ([16], 16, 6), ([19], 16, 6),
+    ([7], 32, 5), ([3, 22], 16, 6)],
+    ids=["before-the-window", "at-the-window", "page-aligned",
+         "past-the-window-unaligned", "last-block-clamped", "two-rows"])
+def test_a_sliding_block_scores_the_band_its_window_can_keep(
+        tiny, monkeypatch, starts, s, width):
+    """A sliding layer's chunk of `s` tokens at `starts` through the paged
+    pool, in blocks of 8 queries that each score a band of 24 rows (window
+    5, pages of 8) from an aligned start, against the cache-less pass over
+    the same tokens in ONE block, which scores every row. The last block
+    of a table `width` = 5 columns wide starts where the clamp puts it;
+    block-table columns past a row's context point at a page of large
+    numbers."""
+    from paddle_tpu import monitor
+    cfg, net, _, _ = tiny
+    attn = net.layers[2].self_attn
+    b, bs_ = len(starts), 8
+    band, whole = (monitor.counter(f"kernels.prefill.swa_{n}")
+                   for n in ("band", "whole"))
+    x = jnp.asarray(np.random.default_rng(5).normal(
+        size=(b, max(starts) + s, cfg.hidden_size)), jnp.float32)
+    monkeypatch.setattr(attn, "q_block", 1 << 20)
+    n_whole, n_band = whole.get(), band.get()
+    want = unwrap(attn(paddle.to_tensor(x)))
+    assert (whole.get(), band.get()) == (n_whole + 1, n_band)
+    junk = b * width + 1
+    pools = tuple(jnp.zeros((junk + 1, bs_, w), jnp.float32).at[junk].set(1e3)
+                  for w in attn.cache_rows())
+    bt = np.full((b, width), junk, np.int32)
+    for r, p0 in enumerate(starts):
+        n_pages = -(-(p0 + s) // bs_)
+        bt[r, :n_pages] = 1 + r * width + np.arange(n_pages)
+    bt = jnp.asarray(bt)
+    for r, p0 in enumerate(starts):
+        if p0:      # what came before the chunk, written a row at a time
+            _, cache = attn(paddle.to_tensor(x[r:r + 1, :p0]),
+                            kv_cache=pools + (bt[r:r + 1],),
+                            cache_index=jnp.asarray([0], jnp.int32))
+            pools = cache[:-1]
+    monkeypatch.setattr(attn, "q_block", 8)
+    n_band = band.get()
+    chunk = jnp.stack([x[r, p0:p0 + s] for r, p0 in enumerate(starts)])
+    got, _ = attn(paddle.to_tensor(chunk), kv_cache=pools + (bt,),
+                  cache_index=jnp.asarray(starts, jnp.int32))
+    assert band.get() == n_band + 1
+    for r, p0 in enumerate(starts):
+        np.testing.assert_allclose(unwrap(got)[r], want[r, p0:p0 + s],
+                                   rtol=1e-5, atol=1e-6)
+
+
 def test_the_shares_add_up_to_the_uncut_layer(tiny):
     """The guide's share test: the routed parts the 4 shares give, with
     the shared expert counted once, add up to what the reference gives

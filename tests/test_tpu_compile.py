@@ -267,6 +267,51 @@ def test_latent_layer_cache_step_compiles_in_place(one_chip, kind, tokens):
     assert mem.alias_size_in_bytes >= all_pools
 
 
+@pytest.mark.parametrize("tokens,keys,counter", [
+    (2048, 2688, "band"), (256, 896, "whole")],
+    ids=["chunk-2048-banded", "chunk-256-whole"])
+def test_sliding_layer_chunk_scores_a_band_of_its_keys(one_chip, tokens,
+                                                       keys, counter):
+    """A sliding layer's prefill chunk at the published widths (64 heads,
+    blocks of 256 queries, window 513, pages of 128): the chunk gathers
+    `keys` rows, and a block's float32 scores span 896 of them, the band
+    its window can keep, never the 2,688 a 2,048-token chunk gathers.
+    (Scoring all of them made the sliding layers' loops 18.7% of that
+    chunk's program: PERF.md section 6, PR 32.)"""
+    from paddle_tpu import monitor
+    from paddle_tpu.jit.functional import functional_call, get_params
+    from paddle_tpu.nn.initializer.lazy_init import LazyGuard
+    from paddle_tpu.nn.layer.layers import param_dtype
+    from paddle_tpu.text.models.dots3_note import (SLIDING,
+                                                   Dots3LatentAttention,
+                                                   Dots3NoteConfig)
+    with LazyGuard(), param_dtype("bfloat16"):
+        attn = Dots3LatentAttention(Dots3NoteConfig(), SLIDING)
+
+    def chunk_step(params, u, pos0, bt, pool):
+        (out, cache), _ = functional_call(
+            attn, params, {}, (u,),
+            dict(kv_cache=(pool, bt), cache_index=pos0))
+        return out, cache[0]
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = ({k: struct(v.shape, v.dtype)
+             for k, v in get_params(attn).items()},
+            struct((1, tokens, 5120), jnp.bfloat16), struct((1,), jnp.int32),
+            struct((1, LATENT_BT), jnp.int32),
+            struct((LATENT_PAGES, 128, 1152), jnp.bfloat16))
+    count = monitor.counter(f"kernels.prefill.swa_{counter}")
+    before = count.get()
+    text = jax.jit(chunk_step, donate_argnums=(4,)).lower(
+        *args).compile().as_text()
+    assert count.get() == before + 1
+    # the compiler drops the size-1 batch dimension of the score block
+    scores = set(re.findall(r"f32\[(?:1,)?64,256,(\d+)\]", text))
+    assert scores == {"896"}, scores
+    assert f"bf16[1,{keys},64,256]" in text      # K, expanded once a chunk
+
+
 def _moe_shapes(e=8, cap=8192, h=768, dff=3072):
     return [((e, cap, h), jnp.bfloat16), ((e, h, dff), jnp.bfloat16),
             ((e, 1, dff), jnp.float32), ((e, dff, h), jnp.bfloat16),
