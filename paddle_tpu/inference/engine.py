@@ -326,8 +326,8 @@ def serving_model_spec(model) -> dict:
         spec = dict(fn())
         if spec.get("kind") == "decoder":
             # a spec that gives its cache PER LAYER ("cache_layers": one
-            # {"kind": "latent", "rows": (width, ...)} a layer) has no
-            # one kv_heads x head_dim to name
+            # entry a layer, see _make_spec_pools) has no one
+            # kv_heads x head_dim to name
             geometry = ("num_layers", "max_context") \
                 if spec.get("cache_layers") is not None else \
                 ("num_layers", "kv_heads", "head_dim", "max_context")
@@ -414,28 +414,60 @@ def _make_paged_pools(layers, rows, hkv, page_size, hd, dtype, quant):
         for _ in range(layers)]
 
 
-def _make_spec_pools(spec, rows, page_size, dtype, quant):
-    """The page pools a serving spec asks for, one tuple a layer. A
+def _cache_kinds(spec) -> List[str]:
+    """The kind of each layer's cache: a spec with ONE geometry is "kv"
+    on every layer; a ``cache_layers`` spec says, layer by layer."""
+    layers = spec.get("cache_layers")
+    if layers is None:
+        return ["kv"] * int(spec["num_layers"])
+    return [str(layer.get("kind")) for layer in layers]
+
+
+def _make_spec_pools(spec, rows, page_size, dtype, quant, slots=0):
+    """What a serving spec asks the engine to hold, one tuple a layer. A
     spec with ONE geometry (``kv_heads`` x ``head_dim``: LLaMA, Mistral,
     ERNIE-MoE) gets _make_paged_pools' (k, v[, ks, vs]) exactly. A spec
-    with ``cache_layers`` gets, for each layer, one pool
-    [rows, page_size, width] per entry of its ``rows``: one vector a
-    token and no head dimension (a latent-attention layer's
-    [c_kv ; k_rope] row, its indexer's key). The same block tables, the
-    same in-place write (_scatter_tokens) and the same page walk serve
-    both kinds."""
+    with ``cache_layers`` gets what each entry's ``kind`` says:
+
+    * ``"kv"`` (``kv_heads``, ``head_dim``): that same paged (k, v) pair
+      with heads, for one layer.
+    * ``"latent"`` (``rows``): one pool [rows, page_size, width] per
+      entry of its ``rows``: one vector a token and no head dimension
+      (a latent-attention layer's [c_kv ; k_rope] row, its indexer's
+      key).
+    * ``"state"`` (``arrays``: name -> (shape, dtype)): one array
+      [slots, *shape] per entry, in order: what a layer keeps by SLOT
+      and not by page, whatever the context's length (a linear-attention
+      layer's recurrent state, its convolution's tail).
+
+    The same block tables, the same in-place write (_scatter_tokens) and
+    the same page walk serve the two paged kinds."""
     layers = spec.get("cache_layers")
     if layers is None:
         return _make_paged_pools(
             int(spec["num_layers"]), rows, int(spec["kv_heads"]),
             page_size, int(spec["head_dim"]), dtype, quant)
+    pools = []
     for layer in layers:
-        if layer.get("kind") != "latent":
+        kind = layer.get("kind")
+        if kind == "kv":
+            pools += _make_paged_pools(1, rows, int(layer["kv_heads"]),
+                                       page_size, int(layer["head_dim"]),
+                                       dtype, quant)
+        elif kind == "latent":
+            pools.append(tuple(jnp.zeros((rows, page_size, int(w)), dtype)
+                               for w in layer["rows"]))
+        elif kind == "state":
+            pools.append(tuple(
+                jnp.zeros((int(slots),) + tuple(int(n) for n in shape),
+                          jnp.dtype(dt))
+                for shape, dt in layer["arrays"].values()))
+        else:
             raise ValueError(
-                f"cache_layers entry {layer!r}: only kind 'latent' (one "
-                f"row a token per pool) is known")
-    return [tuple(jnp.zeros((rows, page_size, int(w)), dtype)
-                  for w in layer["rows"]) for layer in layers]
+                f"cache_layers entry {layer!r}: the kinds known are 'kv' "
+                f"(paged keys and values with heads), 'latent' (one row a "
+                f"token per pool) and 'state' (arrays by slot)")
+    return pools
 
 
 @dataclass
@@ -493,18 +525,28 @@ class _Emitted:
 
 
 @jax.jit
-def _merge_rows(dev, host, mask):
+def _merge_rows(dev, packed):
     """Fold host-updated slot rows (admissions, preemptions, finishes)
-    into the device-resident decode state: row i comes from ``host``
-    where ``mask[i]`` (the scheduler touched the slot since the last
-    decode step), else from the state the last decode executable
-    produced. ONE fixed-shape executable whatever the number of dirty
-    slots — a per-index scatter would compile a fresh tiny program per
-    dirty-set shape and show up as steady-state recompiles."""
-    def pick(d, h):
-        m = mask.reshape((-1,) + (1,) * (d.ndim - 1))
-        return jnp.where(m, h.astype(d.dtype), d)
-    return jax.tree_util.tree_map(pick, dev, host)
+    into the device-resident decode state: row i comes from the host's
+    mirrors where the scheduler touched the slot since the last decode
+    step, else from the state the last decode executable produced.
+    ``packed`` is Engine._pack_rows' ONE int32 array [slots, 11]: the
+    nine mirrors column by column (a float or uint32 column by its
+    bits, the keys two columns) and the dirty mask last, so a flush is
+    one upload and not ten. ONE fixed-shape executable whatever the
+    number of dirty slots — a per-index scatter would compile a fresh
+    tiny program per dirty-set shape and show up as steady-state
+    recompiles."""
+    mask = packed[:, -1] > 0
+    out, col = [], 0
+    for d in dev:
+        width = d.size // d.shape[0]
+        h = jax.lax.bitcast_convert_type(packed[:, col:col + width],
+                                         d.dtype).reshape(d.shape)
+        out.append(jnp.where(
+            mask.reshape((-1,) + (1,) * (d.ndim - 1)), h, d))
+        col += width
+    return tuple(out)
 
 
 def _named(body, name: str):
@@ -580,10 +622,15 @@ class Engine:
                 "generation instead")
         self.serving_spec = spec
         self.model = model
-        # a per-layer (latent) cache spec: what this engine does not yet
-        # do for it is refused by name, not run wrong
-        self._latent = spec.get("cache_layers") is not None
-        if self._latent:
+        # what the spec keeps, layer by layer; what this engine does not
+        # yet do for a per-layer spec is refused by name, not run wrong
+        self._cache_kinds = _cache_kinds(spec)
+        self._per_layer = spec.get("cache_layers") is not None
+        self._has_state = "state" in self._cache_kinds
+        self._spec_name = (
+            f"{type(model).__name__}'s per-layer cache spec (kinds "
+            f"{', '.join(sorted(set(self._cache_kinds)))})")
+        if self._per_layer:
             for option, on in (("prefix_cache", bool(prefix_cache)),
                                ("draft_model", draft_model is not None),
                                ("cache_dtype='int8'",
@@ -591,8 +638,7 @@ class Engine:
                 if on:
                     raise ValueError(
                         f"{option} is not supported for "
-                        f"{type(model).__name__}'s per-layer latent "
-                        f"cache spec (docs/SERVING.md 'Model "
+                        f"{self._spec_name} (docs/SERVING.md 'Model "
                         f"polymorphism')")
         # keep_logits: the decode and prefill programs also return the
         # float32 logits they sampled from (left on the device; a row is
@@ -640,8 +686,12 @@ class Engine:
                     get_frozen(model))
         self.cache_dtype = _resolve_cache_dtype(cache_dtype, self._st[0])
         self._quant = self.cache_dtype == jnp.dtype(jnp.int8)
-        hkv = 1 if self._latent else int(spec["kv_heads"])
-        hd = None if self._latent else int(spec["head_dim"])
+        # the paged layers with heads (all of a one-geometry spec's)
+        heads = [(int(e["kv_heads"]), int(e["head_dim"]))
+                 for e in (spec["cache_layers"] if self._per_layer
+                           else [dict(spec, kind="kv")])
+                 if e.get("kind") == "kv"]
+        hkv = heads[0][0] if heads else 1
         # pool row 0 is the scratch page (inactive lanes) — the
         # allocator hands out ids [1, pool_pages]
         rows = self.pool_pages + 1
@@ -699,13 +749,13 @@ class Engine:
                     self._mp_rep = NamedSharding(sh.mesh,
                                                  PartitionSpec())
                     break
-        if self._latent and self._mp_degree > 1:
+        if self._per_layer and self._mp_degree > 1:
             raise ValueError(
                 f"an mp={self._mp_degree} mesh is not supported for "
-                f"{type(model).__name__}'s per-layer latent cache spec")
+                f"{self._spec_name}")
         self._pools = self._commit_pools(_make_spec_pools(
-            spec, rows, self.page_size, self.cache_dtype, self._quant),
-            hkv)
+            spec, rows, self.page_size, self.cache_dtype, self._quant,
+            self.max_slots), hkv)
         S, MB = self.max_slots, self.max_blocks
         self._bt = np.zeros((S, MB), np.int32)
         self._pos = np.zeros((S,), np.int32)
@@ -774,6 +824,12 @@ class Engine:
         # serving.<label>.… twin; a plain engine stays unlabeled.
         self.label = str(label) if label is not None else "engine"
         self._mon = monitor.scope(label)
+        if self._has_state:
+            # what the engine holds by slot rather than by page
+            self._mon.gauge("serving.state.bytes").set(sum(
+                a.nbytes for kind, layer in zip(self._cache_kinds,
+                                                self._pools)
+                if kind == "state" for a in layer))
         # host/device tick attribution: wall seconds this tick spent
         # blocked on device results (block_until_ready around the
         # tick's dispatch outputs); step() publishes the split
@@ -820,11 +876,11 @@ class Engine:
         # instead of letting every decode step silently gather: an
         # ineligible geometry on a TPU backend costs a full-cache copy
         # per token and previously only showed up as slow numbers.
-        # (a per-layer latent spec's kernel is the model's own matter:
-        # on a TPU it raises at trace time for a pool it cannot take)
-        self.decode_fallback_reason = None if self._latent else \
-            paged_pallas_requirements(hd, self.page_size,
-                                      self.cache_dtype)
+        # (a latent or a state layer's kernel is the model's own matter:
+        # on a TPU it raises at trace time for what it cannot take)
+        self.decode_fallback_reason = next(filter(None, (
+            paged_pallas_requirements(hd, self.page_size, self.cache_dtype)
+            for hd in sorted({hd for _, hd in heads}))), None)
         self.pallas_eligible = self.decode_fallback_reason is None
         if not self.pallas_eligible:
             monitor.counter("serving.decode_fallback").increase()
@@ -885,6 +941,16 @@ class Engine:
                 self._topps, self._keys, self._live, self._eos,
                 self._bud)
 
+    def _pack_rows(self) -> np.ndarray:
+        """The host mirrors and the dirty mask as ONE int32 array
+        [slots, 11] (_merge_rows unpacks it): every mirror is 32 bits a
+        value, so a column carries a float or a key word by its bits."""
+        mask = np.zeros((self.max_slots, 1), np.int32)
+        mask[list(self._dirty)] = 1
+        return np.concatenate(
+            [m.view(np.int32).reshape(self.max_slots, -1)
+             for m in self._mirrors()] + [mask], axis=1)
+
     def _up(self, x):
         """Host→device upload of engine state, committed to the
         replicated sharding under an mp>1 mesh (see __init__) — plain
@@ -936,19 +1002,28 @@ class Engine:
         # resume prefix is at most need - 2 tokens.
         return _ceil_div(need - 1 + self._lookahead, self.page_size)
 
-    def _inject_bt(self, caches, bt):
-        """Pool tuples -> the model's per-layer paged cache tuples:
-        (k, v, bt[, ks, vs]) — the block table is engine state, shared
-        by every layer, injected at call time. A latent layer's tuple
-        is its pools with the block table last."""
-        if self._latent:
-            return [tuple(c) + (bt,) for c in caches]
-        return [(c[0], c[1], bt) + tuple(c[2:]) for c in caches]
+    def _inject_bt(self, caches, bt, slots=None, n_valid=None):
+        """Engine state -> the model's per-layer cache tuples. A paged
+        layer takes its pools and the block table, engine state shared
+        by every layer and injected at call time: (k, v, bt[, ks, vs])
+        with heads, its pools with the block table last when latent. A
+        state layer takes its arrays, then `slots` (the rows of the
+        batch's sequences; None in the decode program, where row i is
+        slot i) and `n_valid` (how many of each sequence's tokens are
+        real: 0 for a lane that is not decoding, a chunk's real
+        length)."""
+        return [tuple(c) + (slots, n_valid) if kind == "state"
+                else tuple(c) + (bt,) if kind == "latent"
+                else (c[0], c[1], bt) + tuple(c[2:])
+                for kind, c in zip(self._cache_kinds, caches)]
 
     def _strip_bt(self, kv):
-        if self._latent:
-            return [tuple(t[:-1]) for t in kv]
-        return [(t[0], t[1]) + tuple(t[3:]) for t in kv]
+        """The model's new cache tuples -> what the engine holds (a
+        state layer hands back its arrays alone)."""
+        return [tuple(t) if kind == "state"
+                else tuple(t[:-1]) if kind == "latent"
+                else (t[0], t[1]) + tuple(t[3:])
+                for kind, t in zip(self._cache_kinds, kv)]
 
     def _tick_extras(self, cur, stats=True):
         """What a decode or prefill program returns beyond today's
@@ -1019,7 +1094,6 @@ class Engine:
 
         def body(st, caches, bt, state, poison):
             last, pos, temps, topks, topps, keys, live, eosv, bud = state
-            kv = self._inject_bt(caches, bt)
             # idle lanes ride at cache_index -1: their context_lens
             # (pos + 1) is then 0, so the multi-sequence decode kernel
             # treats them as DEAD slots — no page DMA, no compute —
@@ -1033,6 +1107,10 @@ class Engine:
             # tick was dispatched with the lane still marked live.
             alive = (live > 0) & (bud > 0)
             idx = jnp.where(alive, pos, -jnp.ones_like(pos))
+            # a state layer updates the rows of the lanes that are alive
+            # and leaves every other slot's rows as they are: a slot
+            # between two prefill chunks, a free one, a dead lane
+            kv = self._inject_bt(caches, bt, None, alive.astype(jnp.int32))
             logits, new_kv = _model_forward(model, st, last[:, None],
                                             kv, idx)
             # poison (normally all zeros, NaN at a fault-injected
@@ -1127,8 +1205,11 @@ class Engine:
         model = self.model
 
         def body(st, caches, bt_row, prompt, plen, start, temps, topks,
-                 topps, keys, poison):
-            kv = self._inject_bt(caches, bt_row)
+                 topps, keys, poison, slot):
+            # a state layer starts from zeros where `start` is 0 and from
+            # the rows of `slot` otherwise, stops at the chunk's `plen`
+            # real tokens, and writes the rows back
+            kv = self._inject_bt(caches, bt_row, slot, plen)
             # `start` is the page-aligned token offset the chunk begins
             # at — 0 for a cold prefill, the cached-prefix length on a
             # prefix-cache hit (the chunk attends the shared pages
@@ -1199,7 +1280,8 @@ class Engine:
                       s((1, pb), np.int32), s((1,), np.int32),
                       s((1,), np.int32), s((1,), np.float32),
                       s((1,), np.int32), s((1,), np.float32),
-                      s((1, 2), np.uint32), s((1,), np.float32)),
+                      s((1, 2), np.uint32), s((1,), np.float32),
+                      s((1,), np.int32)),
                 donate=(1,), fetched=(0, 1, 2), per_tick=False))
         cache_keys = {"_decode_fns": list(self._decode_fns),
                       "_verify_fns": list(self._verify_fns),
@@ -1268,6 +1350,9 @@ class Engine:
                           ("win_tokens", spec.get("window"))):
             if cap is not None:
                 args[name] = sum(min(p + 1, int(cap)) for p in pos)
+        if self._has_state:
+            # lanes whose per-slot state the dispatched program updates
+            args["state_slots"] = len(lanes)
         return args
 
     def _pending(self, kind: str, data: tuple, lanes, t0: float,
@@ -1597,6 +1682,7 @@ class Engine:
         host holds and that tick's token for its lane is discarded,
         to be produced again where the request resumes). Returns None
         for unknown or already-retired ids."""
+        self._refuse_for_state("extract_request")
         if device_key:
             self._drain("api")
         req = self.requests.get(int(req_id))
@@ -1629,6 +1715,16 @@ class Engine:
                           self._clock() * 1e3, self.label)
         return req
 
+    def _refuse_for_state(self, what: str) -> None:
+        """The entries that move a request between engines carry its
+        tokens and no per-slot state: for a spec with state they are
+        refused by name, not run with the state dropped in silence."""
+        if self._has_state:
+            raise ValueError(
+                f"{what} is not supported for {self._spec_name}: a "
+                f"slot's state is not part of what it moves "
+                f"(docs/SERVING.md 'Model polymorphism')")
+
     def snapshot(self, sync: bool = True) -> dict:
         """Crash-exact host-state snapshot (reliability.py has the
         format): queued + live request tokens, rng chains, sampling
@@ -1637,6 +1733,7 @@ class Engine:
         stall-dump path, where the device may be wedged) at the cost
         of exactness for mid-flight SAMPLING requests."""
         from .reliability import snapshot_engine
+        self._refuse_for_state("snapshot")
         if sync:
             # the rng rows fetched belong to the newest token; without
             # the sync the snapshot is the host's view, one tick behind
@@ -1649,6 +1746,7 @@ class Engine:
         restored run's outputs are bit-identical to the uninterrupted
         one. Returns the number of requests re-admitted."""
         from .reliability import restore_engine
+        self._refuse_for_state("restore")
         self._drain("api")
         return restore_engine(self, snap, strict=strict)
 
@@ -2164,6 +2262,9 @@ class Engine:
             pb = min(self._pbucket(T),
                      self.max_blocks * self.page_size - start)
             span.set(bucket=pb, tokens=T, start=start, final=int(final))
+            if self._has_state:
+                # 1: the chunk starts from the slot's rows, not from zeros
+                span.set(state_carry=int(start > 0))
             # allocate pages for REAL tokens only: block-table rows beyond
             # them stay 0, so the chunk's bucket-padding writes land in the
             # shared scratch page (the masked-lane convention) instead of
@@ -2208,9 +2309,13 @@ class Engine:
             prompt_dev = jnp.asarray(prompt)
             start_dev = jnp.asarray([start], jnp.int32)
             self._fault_raise("prefill.device_error")
-            poison = jnp.asarray(
+            # the one-value arguments go to the program as host arrays:
+            # its call uploads them together, where a jnp.asarray each
+            # is a dispatch of its own (and, from a Python float, a
+            # device program to convert it) with the chip often idle
+            poison = np.asarray(
                 [float("nan") if self._fault("prefill.nan") else 0.0],
-                jnp.float32)
+                np.float32)
             # windowed device attribution, same as the decode dispatches:
             # the chunk's dispatch→ready span is device-busy even on a
             # client whose dispatch call runs the computation inline —
@@ -2220,11 +2325,12 @@ class Engine:
             t0 = time.perf_counter()
             tok, key2, okf, self._pools, *extras = fn(
                 self._st, self._pools, bt_dev, prompt_dev,
-                jnp.asarray([T], jnp.int32), start_dev,
-                jnp.asarray([p.temperature], jnp.float32),
-                jnp.asarray([p.top_k], jnp.int32),
-                jnp.asarray([p.top_p], jnp.float32),
-                jnp.asarray(req.key[None]), poison)
+                np.asarray([T], np.int32), start_dev,
+                np.asarray([p.temperature], np.float32),
+                np.asarray([p.top_k], np.int32),
+                np.asarray([p.top_p], np.float32),
+                np.asarray(req.key[None], np.uint32), poison,
+                np.asarray([req.slot], np.int32))
             if self._spec is not None:
                 # mirror the chunk into the draft pools (same pages, same
                 # positions) so drafting attends the full context
@@ -2239,6 +2345,12 @@ class Engine:
             self._mon.counter("serving.prefill_tokens").increase(pb)
             self._mon.counter("serving.prefill_slices").increase()
             self._pf_step_tokens += pb
+            if self._has_state:
+                if start == 0:
+                    self._mon.counter("serving.state.resets").increase()
+                if final and not fresh:
+                    self._mon.counter(
+                        "serving.state.recomputes").increase()
             if start == req.prefix_len:
                 monitor.counter(
                     "serving.prefix_tokens_reused").increase(start)
@@ -2394,11 +2506,8 @@ class Engine:
         with RecordEvent("engine.flush_state", rows=len(self._dirty),
                          block_table=int(self._bt_dirty)):
             if self._dirty:
-                mask = np.zeros((self.max_slots,), bool)
-                mask[list(self._dirty)] = True
-                host = tuple(self._up(m) for m in self._mirrors())
-                self._dev = _merge_rows(self._dev, host,
-                                        self._up(mask))
+                self._dev = _merge_rows(self._dev,
+                                        self._up(self._pack_rows()))
                 self._dirty.clear()
             if self._bt_dirty:
                 self._bt_dev = self._up(self._bt)

@@ -4,3 +4,4 @@ from .ernie_moe import ErnieMoEConfig, ErnieMoEForCausalLM  # noqa: F401
 from .llama import (LlamaConfig, LlamaDecoderLayer,  # noqa: F401
                     LlamaForCausalLM, LlamaModel, build_llama_pipe,
                     force_tp_layers, llama_flops_per_token)
+from .solar_open2 import SolarOpen2Config, SolarOpen2ForCausalLM  # noqa: F401

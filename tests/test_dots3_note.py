@@ -139,7 +139,7 @@ def test_one_geometry_spec_builds_todays_pools():
     try:
         assert [tuple(p.shape for p in layer) for layer in eng._pools] == \
             [((9, 4, 16, 16), (9, 4, 16, 16))] * 2
-        assert not eng._latent and not eng._tick_stats
+        assert not eng._per_layer and not eng._tick_stats
     finally:
         eng.close()
 

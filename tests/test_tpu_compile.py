@@ -312,6 +312,94 @@ def test_sliding_layer_chunk_scores_a_band_of_its_keys(one_chip, tokens,
     assert f"bf16[1,{keys},64,256]" in text      # K, expanded once a chunk
 
 
+# the linear-attention cell: 96 slots, 64 heads of 128; the GQA layer's
+# pools 96 slots x 22 pages + the scratch page, 8 KV heads, page 128
+STATE_SLOTS, KV_PAGES, KV_BT = 96, 2113, 22
+
+
+def _solar_layer_step(monkeypatch, one_chip, gqa, tokens):
+    """The compiled text and memory analysis of one Solar-Open2 mixing
+    layer's cache step at the cell's sizes, built as the engine's
+    programs build it: the decode program's one token a slot (`slots`
+    None, row i is slot i) or a prefill chunk of one slot."""
+    from paddle_tpu.core import place
+    from paddle_tpu.jit.functional import functional_call, get_params
+    from paddle_tpu.nn.initializer.lazy_init import LazyGuard
+    from paddle_tpu.nn.layer.layers import param_dtype
+    from paddle_tpu.text.models.solar_open2 import (SolarGQAttention,
+                                                    SolarKDAttention,
+                                                    SolarOpen2Config)
+    # the model asks the platform which kernel to take: steer it here
+    monkeypatch.setattr(place, "accelerator_available", lambda: True)
+    with LazyGuard(), param_dtype("bfloat16"):
+        attn = (SolarGQAttention if gqa else SolarKDAttention)(
+            SolarOpen2Config())
+    b = STATE_SLOTS if tokens == 1 else 1
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if gqa:
+        pools = [struct((KV_PAGES, 8, 128, 128), jnp.bfloat16)] * 2
+        rest = (struct((b, KV_BT), jnp.int32),)
+    else:
+        pools = [struct((STATE_SLOTS, 64, 128, 128), jnp.float32)] \
+            + [struct((STATE_SLOTS, 24576), jnp.bfloat16)] * 3
+        rest = (None if tokens == 1 else struct((b,), jnp.int32),
+                struct((b,), jnp.int32))
+
+    def step(params, u, pos0, rest, *pools):
+        (out, cache), _ = functional_call(
+            attn, params, {}, (u,),
+            dict(kv_cache=tuple(pools) + tuple(rest), cache_index=pos0))
+        return out, cache[:len(pools)]
+
+    compiled = jax.jit(step, donate_argnums=tuple(
+        range(4, 4 + len(pools)))).lower(
+        {k: struct(v.shape, v.dtype) for k, v in get_params(attn).items()},
+        struct((b, tokens, 4096), jnp.bfloat16), struct((b,), jnp.int32),
+        rest, *pools).compile()
+    return compiled.as_text(), compiled.memory_analysis()
+
+
+@pytest.mark.parametrize("tokens", [1, 2048],
+                         ids=["one-token-96-slots", "chunk-2048"])
+def test_kda_layer_state_step_compiles_in_place(monkeypatch, one_chip,
+                                                tokens):
+    """`kda_decode` compiles at the published widths (64 heads of 128, 96
+    slots) as a Mosaic call that takes the donated state where it lies,
+    and a prefill chunk reads and writes one slot's rows of it: no copy
+    of a layer's whole state array (0.4 GB) in either program."""
+    text, mem = _solar_layer_step(monkeypatch, one_chip, False, tokens)
+    assert ("%kda_decode" in text and "tpu_custom_call" in text) \
+        == (tokens == 1)
+    state_copy = re.compile(
+        rf"= \w+\[{STATE_SLOTS},(64,128,128|24576)\]\{{[^}}]*\}} copy\(")
+    assert [line.strip()[:120] for line in text.splitlines()
+            if state_copy.search(line)] == []
+    assert mem.alias_size_in_bytes >= STATE_SLOTS * (64 * 128 * 128 * 4
+                                                     + 3 * 24576 * 2)
+
+
+@pytest.mark.parametrize("tokens", [1, 2048],
+                         ids=["one-token-96-slots", "chunk-2048"])
+def test_gqa_layer_cache_step_compiles_in_place(monkeypatch, one_chip,
+                                                tokens):
+    """The gated NoPE GQA layer on the paged pools with heads (8 x 128,
+    a group of 8): `paged_decode` on a one-token step, gathered pages in
+    blocks of 256 queries on a chunk, the write in place either way."""
+    text, mem = _solar_layer_step(monkeypatch, one_chip, True, tokens)
+    assert ("%paged_decode" in text) == (tokens == 1)
+    pool_copy = re.compile(
+        rf"= \w+\[{KV_PAGES},(8,128|1024),128\]\{{[^}}]*\}} copy\(")
+    assert [line.strip()[:120] for line in text.splitlines()
+            if pool_copy.search(line)] == []
+    assert mem.alias_size_in_bytes >= 2 * KV_PAGES * 8 * 128 * 128 * 2
+    if tokens > 1:
+        # a block's float32 scores: 256 queries x the 22 pages' keys
+        assert re.search(r"f32\[(1,)?8,8,256,2816\]", text)
+
+
 def _moe_shapes(e=8, cap=8192, h=768, dff=3072):
     return [((e, cap, h), jnp.bfloat16), ((e, h, dff), jnp.bfloat16),
             ((e, 1, dff), jnp.float32), ((e, dff, h), jnp.bfloat16),
