@@ -33,6 +33,34 @@ from .gate import (BaseGate, GShardGate, NaiveGate, SigmoidTopKGate,
                    SwitchGate)
 
 
+# The sorted dispatch works its sorted picks off in slabs of twice the
+# rows a uniform router sends to this chip's experts (the factor of
+# GShard's default capacity, but as the size of a STEP: picks beyond it
+# take another slab, none is dropped), in an ODD number of row tiles.
+# XLA's TPU `ragged_dot` walks (group, row tile) pairs with the largest
+# power of two up to 512 that divides its row count as the tile, and a
+# pair costs the whole tile however few of the group's rows lie in it:
+# an odd number of tiles makes the tile ours to choose. Measured on the
+# v5e (PERF.md section 6, PR 35): 128 rows for a prefill chunk's 6-65
+# rows an expert (a tile of 512 spends its time on dead rows, one of 32
+# or 64 takes more pairs), 32 for a decode tick's 2-3
+# (tests/test_tpu_compile.py holds the compiler to that rule).
+_SLAB_FACTOR = 2
+_SLAB_ROW_TILE = 128
+_SLAB_SMALL_ROW_TILE = 32       # for a slab of fewer than 512 rows
+_SLAB_SMALL = 512
+
+
+def _slab_rows(m: int, of: int) -> int:
+    """Rows of one slab for ``m`` picks on a layer that holds one
+    ``of``-th of the experts; ``m`` itself when one slab takes them
+    all."""
+    rows = _SLAB_FACTOR * -(-m // of)
+    tile = _SLAB_ROW_TILE if rows >= _SLAB_SMALL else _SLAB_SMALL_ROW_TILE
+    tiles = -(-rows // tile)
+    return min(m, (tiles + 1 - tiles % 2) * tile)
+
+
 def _shard_expert_param(layer: Layer, name: str, axis: str = "ep"):
     """Commit layer.<name> (leading dim = experts) to Shard(0) on `axis`
     (skipped when the expert count doesn't divide the axis degree)."""
@@ -308,9 +336,9 @@ class MoELayer(Layer):
         self.shared_experts = shared_experts
         self._ep_axis = ep_axis
         self.l_aux = None
-        # [picks held here, picks made, held experts touched] of the
-        # last sorted forward: traced values inside a compiled step,
-        # which the serving engine returns with the tick
+        # [picks held here, picks made, held experts touched, slabs run]
+        # of the last sorted forward: traced values inside a compiled
+        # step, which the serving engine returns with the tick
         self.last_stats = None
 
     def _n_groups(self, n):
@@ -531,20 +559,43 @@ class MoELayer(Layer):
                                      token_mask=mask, cap=n)
 
     def _forward_sorted(self, tokens, orig_shape, token_mask=None):
-        """Capacity-free dispatch for the sigmoid top-k gate: every
-        (token, pick) whose expert is held here becomes one row; the
-        rows are sorted by expert, run through ONE ragged grouped
-        matmul per matrix (kernels.moe.grouped_ffn_gated), weighted, and
-        summed back onto their tokens by the inverse permutation (a
-        gather, not a scatter). Picks on experts held elsewhere, and the
-        picks of dead tokens (``token_mask`` False), become rows past
-        the last group: no weights are read for them and they add zero.
-        The buffer is sized for the worst case, every pick held
-        (N * top_k rows); what is computed follows the group sizes."""
+        """Capacity-free dispatch for the sigmoid top-k gate, over the
+        rows this chip holds. Every (token, pick) is sorted by the held
+        expert it fell on; picks on experts held elsewhere, and the
+        picks of dead tokens (``token_mask`` False), sort past the last
+        group. The sorted order is then worked off in SLABS of
+        ``_slab_rows`` rows, as many as the held picks fill: a slab
+        gathers its tokens, runs ONE ragged grouped matmul per matrix
+        (kernels.moe.grouped_ffn_gated) with the part of each expert's
+        group that lies in it, weights its rows in float32 and adds
+        them onto their tokens. No array of the routed path has
+        N * top_k rows, nothing is dropped and there is no capacity: a
+        router that sends every pick here runs every slab of the order
+        (about ``of / 2``), a uniform one runs one.
+
+        A token's picks are added one at a time in the order of their
+        place in the sorted order, which for the picks held here is the
+        order of their experts, and a pick outside the slab adds an
+        exact 0.0: the sum is a function of the token's own picks,
+        whatever the batch, the slab bounds or the number of slabs
+        (``_forward_decode``'s token-exactness contract).
+
+        With every row in one slab (``of <= 2``, or a batch too small
+        to split) there is no loop. Else the loop runs ``ceil(held
+        picks / slab)`` times, a traced count, which has no reverse-mode
+        derivative: in training it runs the static ``ceil(M / slab)``
+        (the whole order, at the whole order's cost)."""
+        from ..... import monitor
         from .....kernels.moe import grouped_ffn_gated
         from .gate import sigmoid_topk_routing
         gate, ex = self.gate, self.experts
         k, lo, held_n = gate.top_k, self.first_held, self.num_held
+        m = int(tokens.shape[0]) * k
+        s = _slab_rows(m, self.expert_share[1])
+        all_trips = -(-m // s)          # 1: one slab takes every row
+        training = self.training
+        monitor.counter("kernels.moe.sorted.whole" if s == m
+                        else "kernels.moe.sorted.slab").increase()
 
         def fn(tok, wr, bias, w1, w3, w2, *rest):
             n = tok.shape[0]
@@ -562,14 +613,39 @@ class MoELayer(Layer):
             counts = jnp.sum(
                 key[:, None] == jnp.arange(held_n, dtype=key.dtype)[None],
                 axis=0, dtype=jnp.int32)
-            rows = grouped_ffn_gated(jnp.take(tok, order // k, axis=0),
-                                     w1, w3, w2, counts)
-            w_rows = jnp.where(held, w, 0.0).reshape(-1)[order]
-            rows = rows.astype(jnp.float32) * w_rows[:, None]
-            out = jnp.take(rows, jnp.argsort(order), axis=0) \
-                .reshape(n, k, -1).sum(axis=1)
+            ends = jnp.cumsum(counts)
+            # each token's picks as places in the sorted order, ascending,
+            # with their weights
+            place, w_at = jax.lax.sort(
+                (jnp.argsort(order).reshape(n, k), jnp.where(held, w, 0.0)),
+                dimension=1, num_keys=1)
+            order = jnp.pad(order, (0, all_trips * s - m))
+
+            def slab(i, acc):
+                first = i * s
+                sizes = (jnp.clip(ends, first, first + s)
+                         - jnp.clip(ends - counts, first, first + s))
+                mine = jax.lax.dynamic_slice(order, (first,), (s,))
+                rows = grouped_ffn_gated(
+                    jnp.take(tok, mine // k, axis=0, mode="clip"),
+                    w1, w3, w2, sizes)
+                for j in range(k):
+                    at = place[:, j] - first
+                    row = jnp.take(rows, at, axis=0, mode="clip")
+                    acc = acc + jnp.where(
+                        ((at >= 0) & (at < s))[:, None],
+                        row.astype(jnp.float32) * w_at[:, j:j + 1], 0.0)
+                return acc
+
+            acc = jnp.zeros((n, tok.shape[1]), jnp.float32)
+            if all_trips == 1:
+                trips, out = 1, slab(0, acc)
+            else:
+                trips = all_trips if training else -(-ends[-1] // s)
+                out = jax.lax.fori_loop(0, trips, slab, acc)
             stats = jnp.stack([jnp.sum(held), jnp.sum(live) * k,
-                               jnp.sum(counts > 0)]).astype(jnp.int32)
+                               jnp.sum(counts > 0),
+                               jnp.asarray(trips)]).astype(jnp.int32)
             return out.astype(tok.dtype), stats
 
         args = [tokens, self.gate_weight, self.e_score_correction_bias,

@@ -659,7 +659,8 @@ class Dots3NoteForCausalLM(Layer):
             "tick_stats": ("serving.moe.picks_held",
                            "serving.moe.picks_total",
                            "serving.moe.experts_touched",
-                           "serving.moe.layer_ticks"),
+                           "serving.moe.layer_ticks",
+                           "serving.moe.slabs"),
             "moe": {"num_experts": c.n_routed_experts,
                     "held": c.n_routed_experts_held,
                     "top_k": c.num_experts_per_tok,
@@ -669,11 +670,13 @@ class Dots3NoteForCausalLM(Layer):
         }
 
     def serving_tick_stats(self):
-        """[4] int32, in ``tick_stats``' order: the expert layers' picks
+        """[5] int32, in ``tick_stats``' order: the expert layers' picks
         held here, picks made and held experts touched, summed over the
         expert layers of the last forward (traced inside a compiled
-        step), and how many layers that was."""
+        step), how many layers that was, and the slabs they ran."""
         stats = [unwrap(lyr.mlp.last_stats) for lyr in self.layers
                  if lyr.is_moe and lyr.mlp.last_stats is not None]
-        return jnp.concatenate([sum(stats[1:], stats[0]),
-                                jnp.asarray([len(stats)], jnp.int32)])
+        total = sum(stats[1:], stats[0])
+        return jnp.concatenate([total[:3],
+                                jnp.asarray([len(stats)], jnp.int32),
+                                total[3:]])

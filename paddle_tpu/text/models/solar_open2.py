@@ -544,7 +544,8 @@ class SolarOpen2ForCausalLM(Layer):
             "tick_stats": ("serving.moe.picks_held",
                            "serving.moe.picks_total",
                            "serving.moe.experts_touched",
-                           "serving.moe.layer_ticks"),
+                           "serving.moe.layer_ticks",
+                           "serving.moe.slabs"),
             "moe": {"num_experts": c.n_routed_experts,
                     "held": c.n_routed_experts_held,
                     "top_k": c.num_experts_per_tok,
@@ -554,8 +555,10 @@ class SolarOpen2ForCausalLM(Layer):
         }
 
     def serving_tick_stats(self):
-        """[4] int32, in ``tick_stats``' order (see Dots3NoteForCausalLM)."""
+        """[5] int32, in ``tick_stats``' order (see Dots3NoteForCausalLM)."""
         stats = [unwrap(lyr.mlp.last_stats) for lyr in self.layers
                  if lyr.mlp.last_stats is not None]
-        return jnp.concatenate([sum(stats[1:], stats[0]),
-                                jnp.asarray([len(stats)], jnp.int32)])
+        total = sum(stats[1:], stats[0])
+        return jnp.concatenate([total[:3],
+                                jnp.asarray([len(stats)], jnp.int32),
+                                total[3:]])

@@ -267,9 +267,12 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny):
             unwrap(part(paddle.to_tensor(z))),
             ref.moe_ffn(jnp.asarray(z), w_part, model, (index, of),
                         shared=False), atol=TOL)
-        held_picks, picks, touched = np.asarray(unwrap(part.last_stats))
+        held_picks, picks, touched, slabs = np.asarray(
+            unwrap(part.last_stats))
         assert picks == 19 * cfg.num_experts_per_tok
         assert 0 <= held_picks <= picks and touched <= held
+        # a new layer is in training mode: every slab of the 38 picks
+        assert slabs == 2
     np.testing.assert_allclose(total, want, atol=TOL)
 
 

@@ -400,6 +400,70 @@ def test_gqa_layer_cache_step_compiles_in_place(monkeypatch, one_chip,
         assert re.search(r"f32\[(1,)?8,8,256,2816\]", text)
 
 
+# an expert layer of each expert cell at the cell's widths: hidden, expert
+# width, experts routed over, tokens (a prefill bucket, or the decode
+# program's lanes); each holds share 0 of 8 and routes top-8
+EXPERT_LAYERS = {"notes48-chunk-2048": (5120, 1536, 256, 2048),
+                 "chat96-prefill-1024": (4096, 1280, 320, 1024),
+                 "chat96-decode-96-lanes": (4096, 1280, 320, 96)}
+
+
+@pytest.mark.parametrize("h,f,experts,tokens", EXPERT_LAYERS.values(),
+                         ids=EXPERT_LAYERS.keys())
+def test_sorted_dispatch_works_on_slabs_of_the_held_rows(one_chip, h, f,
+                                                         experts, tokens):
+    """`MoELayer._forward_sorted` on one chip's share of the experts: the
+    three `ragged-dot` calls take a slab of 2/8 of the N x 8 picks in an
+    odd number of row tiles (128 rows; 32 for the decode program), XLA
+    tiles them by that (its rule: the largest power of two up to 512
+    that divides the row count; the calls' metadata holds one entry a
+    (group, row tile) pair that can occur), and no array has N x 8 rows
+    by the hidden or the expert width. (Sized for every pick, and tiled by 512 rows, the 12 calls
+    of a prefill program were 20.6% and 36% of it, on rows of which an
+    eighth were live: PERF.md section 6, PR 35.)"""
+    from paddle_tpu import monitor
+    from paddle_tpu.core.dispatch import unwrap
+    from paddle_tpu.incubate.distributed.models.moe import (MoELayer,
+                                                            moe_layer)
+    from paddle_tpu.jit.functional import (functional_call, get_buffers,
+                                           get_params)
+    from paddle_tpu.nn.initializer.lazy_init import LazyGuard
+    from paddle_tpu.nn.layer.layers import param_dtype
+    with LazyGuard(), param_dtype("bfloat16"):
+        layer = MoELayer(h, f, experts, gate="sigmoid_topk", top_k=8,
+                         activation="swiglu", expert_share=(0, 8))
+    layer.eval()
+
+    def step(params, buffers, x):
+        out, _ = functional_call(layer, params, buffers, (x,), {})
+        return out, unwrap(layer.last_stats)
+
+    def struct(tree):
+        return {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+                for k, v in tree.items()}
+    count = monitor.counter("kernels.moe.sorted.slab")
+    before = count.get()
+    text = jax.jit(step).lower(
+        struct(get_params(layer)), struct(get_buffers(layer)),
+        jax.ShapeDtypeStruct((tokens, h), jnp.bfloat16, sharding=one_chip)
+    ).compile().as_text()
+    assert count.get() == before + 1
+    picks = tokens * 8
+    slab = moe_layer._slab_rows(picks, 8)
+    tile = 128 if tokens > 96 else 32
+    assert 0 <= slab - picks // 4 <= 2 * tile and slab % (2 * tile) == tile
+    calls = re.findall(r"%ragged-dot[\w.\-]* = bf16\[(\d+),(\d+)\]\S* "
+                       r"custom-call", text)
+    assert sorted(calls) == sorted(
+        [(str(slab), str(f))] * 2 + [(str(slab), str(h))]), calls
+    pairs = re.search(r"%ragged-dot-metadata = \(s32\[\d+\]\S* "
+                      r"s32\[(\d+)\]", text)
+    assert int(pairs.group(1)) == experts // 8 + slab // tile - 1
+    wide = re.findall(
+        rf"\w+\[(?:{picks}|{tokens},8),(?:{h}|{f})\]", text)
+    assert wide == []
+
+
 def _moe_shapes(e=8, cap=8192, h=768, dff=3072):
     return [((e, cap, h), jnp.bfloat16), ((e, h, dff), jnp.bfloat16),
             ((e, 1, dff), jnp.float32), ((e, dff, h), jnp.bfloat16),
