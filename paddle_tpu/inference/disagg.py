@@ -1023,6 +1023,7 @@ class DisaggEngine:
         # the tick in flight dies with the worker, unharvested: every
         # request above left with the tokens the host held
         w._inflight = None
+        w._prefilled = []
         w.close()
         fleet[index] = None
         return n

@@ -438,7 +438,9 @@ def _make_spec_pools(spec, rows, page_size, dtype, quant, slots=0):
     * ``"state"`` (``arrays``: name -> (shape, dtype)): one array
       [slots, *shape] per entry, in order: what a layer keeps by SLOT
       and not by page, whatever the context's length (a linear-attention
-      layer's recurrent state, its convolution's tail).
+      layer's recurrent state and its convolution's tail; a
+      sliding-window layer's ring of its last `window` keys and values).
+      A dtype of None is the cache's.
 
     The same block tables, the same in-place write (_scatter_tokens) and
     the same page walk serve the two paged kinds."""
@@ -460,13 +462,14 @@ def _make_spec_pools(spec, rows, page_size, dtype, quant, slots=0):
         elif kind == "state":
             pools.append(tuple(
                 jnp.zeros((int(slots),) + tuple(int(n) for n in shape),
-                          jnp.dtype(dt))
+                          jnp.dtype(dt or dtype))
                 for shape, dt in layer["arrays"].values()))
         else:
             raise ValueError(
                 f"cache_layers entry {layer!r}: the kinds known are 'kv' "
                 f"(paged keys and values with heads), 'latent' (one row a "
-                f"token per pool) and 'state' (arrays by slot)")
+                f"token per pool) and 'state' (arrays by slot: a recurrent "
+                f"state, a window's ring)")
     return pools
 
 
@@ -489,6 +492,26 @@ class _PendingTick:
     extras: tuple = ()        # _tick_extras outputs, still on the device
     # `engine.decode.dispatch` arguments: what the program reads
     span_args: dict = field(default_factory=dict)
+
+
+@dataclass
+class _PendingPrefill:
+    """One prefill chunk dispatched and not yet waited for: while a
+    decode tick is in flight the chunk's wait moves to the NEXT
+    step(), behind that step's dispatch, so the device goes from the
+    chunk straight into a tick (docs/SERVING.md "Dispatch
+    pipelining"). Until `_prefill_harvest` the request stays PREFILL
+    with `written` at the chunk's start."""
+
+    req: "Request"
+    data: tuple               # (tok, key2, okf), still on the device
+    extras: tuple             # _tick_extras outputs (the logits row)
+    toks: list                # the tokens the request resumes from
+    start: int                # first position the chunk wrote
+    tokens: int               # real tokens of the chunk
+    fresh: bool               # no token generated yet: sample the first
+    t_dispatch: float         # perf_counter at dispatch
+    dev_mark: float           # self._device_s at dispatch
 
 
 class _Emitted:
@@ -627,6 +650,11 @@ class Engine:
         self._cache_kinds = _cache_kinds(spec)
         self._per_layer = spec.get("cache_layers") is not None
         self._has_state = "state" in self._cache_kinds
+        # some windowed layer's cache is by page (a one-geometry spec's
+        # every layer; a per-layer spec's entries that give a `window`)
+        self._paged_window = any(
+            e.get("window") is not None and e.get("kind") != "state"
+            for e in spec.get("cache_layers") or [spec])
         self._spec_name = (
             f"{type(model).__name__}'s per-layer cache spec (kinds "
             f"{', '.join(sorted(set(self._cache_kinds)))})")
@@ -862,6 +890,9 @@ class Engine:
         # drain), handed out by the next step()
         self._inflight: Optional[_PendingTick] = None
         self._held: List[Output] = []
+        # prefill chunks dispatched beside a tick in flight; the next
+        # step() (or a drain) waits for them AFTER its own dispatch
+        self._prefilled: List[_PendingPrefill] = []
         # dispatch-pipelining attribution (see _sync_timed): host work
         # that ran while the device was still executing the in-flight
         # dispatch — hidden under device time, published as the
@@ -1291,6 +1322,7 @@ class Engine:
                 list(self._spec._prefill_fns)
         tick = [self.step, self._admit, self._expire,
                 self._run_prefills, self._safe_prefill, self._prefill,
+                self._settle_prefills, self._prefill_harvest,
                 self._ensure_pages, self._safe_decode,
                 self._decode_dispatch, self._dispatch_spec,
                 self._lanes, self._drain, self._decode_harvest,
@@ -1446,7 +1478,10 @@ class Engine:
         then does the host wait for tick t-1 (dispatched by the LAST
         step), harvest its tokens, and run the scheduling for tick
         t+1 — deadline sweeps, admission, prefill slices, page growth
-        — all while the device executes tick t. The programs carry
+        — all while the device executes tick t. A prefill slice is only
+        DISPATCHED here (behind tick t); the next step waits for it
+        after dispatching tick t+1, so the device goes from the slice
+        into that tick, and its slot joins tick t+2. The programs carry
         each lane's eos id and token budget, so a lane whose request
         ended at tick t-1 is dead in tick t without the host saying
         so; the host learns of a finish one tick late and discards
@@ -1476,6 +1511,8 @@ class Engine:
                 # its window from the step's start (_sync_timed)
                 self._inflight.t_dispatch = wall0
                 self._inflight.dev_mark = 0.0
+                for chunk in self._prefilled:
+                    chunk.t_dispatch, chunk.dev_mark = wall0, 0.0
             c0 = self._tracker.compiles
             if self._moe_layer is not None and c0 != self._moe_tracker_mark:
                 # compiles landed OUTSIDE our steps since the last sync
@@ -1507,6 +1544,9 @@ class Engine:
                 carried, self._inflight = \
                     self._inflight, pending if ahead else None
                 outputs.extend(self._decode_harvest(carried))
+                # the prefill chunks the last step dispatched behind
+                # that tick: the device goes from them into tick t
+                outputs.extend(self._settle_prefills())
                 # (c) tick-t+1 host scheduling, beside the device.
                 # Exactness is order-insensitive here (rows are
                 # independent; a request admitted now joins the NEXT
@@ -1519,6 +1559,10 @@ class Engine:
                 with RecordEvent("engine.admit") as span:
                     span.set(admitted=len(self._admit()))
                 outputs.extend(self._run_prefills())
+                if self._inflight is None:
+                    # no tick in flight for the next dispatch to queue
+                    # behind: nothing would hide the chunks' wait
+                    outputs.extend(self._settle_prefills())
                 self._watchdog.maybe_start_and_tick()
                 if not ahead:
                     # spec: block on THIS step's dispatch
@@ -1721,8 +1765,9 @@ class Engine:
         refused by name, not run with the state dropped in silence."""
         if self._has_state:
             raise ValueError(
-                f"{what} is not supported for {self._spec_name}: a "
-                f"slot's state is not part of what it moves "
+                f"{what} is not supported for {self._spec_name}: what "
+                f"a slot keeps by state (a recurrent state, a window's "
+                f"ring of keys and values) is not part of what it moves "
                 f"(docs/SERVING.md 'Model polymorphism')")
 
     def snapshot(self, sync: bool = True) -> dict:
@@ -1979,14 +2024,15 @@ class Engine:
             print(f"engine watchdog: stall snapshot failed: {e}",
                   flush=True)
 
-    def _safe_prefill(self, req: Request,
-                      cap: Optional[int] = None) -> Optional[Output]:
-        """Isolation wrapper: a failing prefill retires or requeues
-        THIS request — it never takes down the step() loop (the other
+    def _safe_prefill(self, req: Request, half, arg) -> Optional[Output]:
+        """Isolation wrapper around either half of a prefill chunk
+        (`_prefill`, the dispatch; `_prefill_harvest`, the wait and
+        the handover): a failing prefill retires or requeues THIS
+        request — it never takes down the step() loop (the other
         slots' state is untouched; the failed call's pages are rolled
         back)."""
         try:
-            return self._prefill(req, cap)
+            return half(req, arg)
         except PoolPressure as e:
             # resource pressure, not a failure: admission (chunked)
             # charges only the first slice, so a mid-prefill dry pool
@@ -2200,7 +2246,7 @@ class Engine:
                 if left <= 0 and req is not oldest:
                     continue
                 cap = max(self.prefill_bucket, left)
-            out = self._safe_prefill(req, cap)
+            out = self._safe_prefill(req, self._prefill, cap)
             if out is not None:
                 outs.append(out)
         return outs
@@ -2335,13 +2381,17 @@ class Engine:
                 # mirror the chunk into the draft pools (same pages, same
                 # positions) so drafting attends the full context
                 self._spec.prefill(pb, bt_dev, prompt_dev, start_dev)
-            # key2 rides in the sync set: the fresh-request path below
-            # reads it (np.asarray) and an unsynced fetch would be an
-            # un-attributed host sync (hotpath.host-sync-in-tick)
-            with RecordEvent("engine.prefill.wait"):
-                self._sync_timed((tok, key2, okf), dispatch_t=t0,
-                                 dev_mark=mark)
-            row = self._take_extras(extras, stats=False)
+            self._prefilled.append(_PendingPrefill(
+                req=req, data=(tok, key2, okf), extras=tuple(extras),
+                toks=toks, start=start, tokens=T, fresh=fresh,
+                t_dispatch=t0, dev_mark=mark))
+            if final and self._prefix is not None:
+                # register this prefix's full pages (newly computed chunks
+                # only; chunks matched at admission are already cached)
+                # NOW, for the router that looks between steps: whatever
+                # maps them is dispatched behind this chunk, and a chunk
+                # that comes back NaN takes them out again at its harvest
+                self._prefix.insert(toks, req.pages, P)
             self._mon.counter("serving.prefill_tokens").increase(pb)
             self._mon.counter("serving.prefill_slices").increase()
             self._pf_step_tokens += pb
@@ -2354,20 +2404,57 @@ class Engine:
             if start == req.prefix_len:
                 monitor.counter(
                     "serving.prefix_tokens_reused").increase(start)
-            if not bool(np.asarray(okf)[0]):
+            return None
+
+    def _settle_prefills(self) -> List[Output]:
+        """Wait for and harvest every chunk dispatched and not yet
+        waited for, in dispatch order. step() calls it behind its own
+        decode dispatch (or at once, with no tick in flight to hide
+        the wait behind); a drain calls it after the tick's harvest."""
+        chunks, self._prefilled = self._prefilled, []
+        outs = (self._safe_prefill(p.req, self._prefill_harvest, p)
+                for p in chunks)
+        return [o for o in outs if o is not None]
+
+    def _prefill_harvest(self, req: Request,
+                         p: _PendingPrefill) -> Optional[Output]:
+        """The host half of a chunk: the request's first token and key
+        go through the host into the slot's row; a final chunk
+        activates the slot for the NEXT dispatch."""
+        with RecordEvent("engine.prefill.harvest", req=req.req_id):
+            # key2 rides in the sync set: the fresh-request path below
+            # reads it (np.asarray) and an unsynced fetch would be an
+            # un-attributed host sync (hotpath.host-sync-in-tick)
+            tok, key2, okf = p.data
+            if req.slot is None or self._slots[req.slot] is not req:
+                # it left its slot with the chunk in flight
+                # (extract_request(device_key=False) drains nothing;
+                # it is admitted again only after this harvest): the
+                # chunk has nothing to hand over
+                return None
+            final = p.start + p.tokens >= len(p.toks)
+            ok = False
+            try:
+                with RecordEvent("engine.prefill.wait"):
+                    self._sync_timed(p.data, dispatch_t=p.t_dispatch,
+                                     dev_mark=p.dev_mark)
+                ok = bool(np.asarray(okf)[0])
+            finally:
+                if not ok and final and self._prefix is not None:
+                    self._prefix.discard(
+                        p.toks, req.pages,
+                        req.prefix_len // self.page_size)
+            if not ok:
                 # NaN/inf on the chunk's sampling logits: quarantine the
-                # request (pages freed, nothing enters the prefix cache)
-                # — the other slots never see it
+                # request (pages freed, nothing stays in the prefix
+                # cache) — the other slots never see it
                 self._mon.counter("serving.nan_quarantines").increase()
                 return self._fail(req, "nan_logits")
-            req.written = start + T
+            row = self._take_extras(p.extras, stats=False)
+            req.written = p.start + p.tokens
             if not final:
                 return None       # stays PREFILL; a later tick continues
-            if self._prefix is not None:
-                # register this prefix's full pages (newly computed chunks
-                # only; chunks matched at admission are already cached)
-                self._prefix.insert(toks, req.pages, P)
-            if fresh:
+            if p.fresh:
                 t = int(np.asarray(tok)[0])
                 req.key = np.asarray(key2)[0].astype(np.uint32)
                 req.generated.append(t)
@@ -2567,18 +2654,20 @@ class Engine:
                              variant, extras=tuple(extras))
 
     def _drain(self, cause: str) -> None:
-        """Wait for and harvest the tick in flight, NOW: what reads
-        the device-resident state or the host's view of a slot as of
-        the newest token (a preemption's key fetch, the public entries
+        """Wait for and harvest the tick in flight, then the prefill
+        chunks dispatched behind it, NOW: what reads the
+        device-resident state or the host's view of a slot as of the
+        newest token (a preemption's key fetch, the public entries
         that move requests) calls this
         first. The Outputs it retires come out of the step() that is
         running, or of the next one. Counted by cause:
         ``serving.runahead.drains.<cause>``."""
         pend, self._inflight = self._inflight, None
-        if pend is None:
-            return
-        self._mon.counter("serving.runahead.drains." + cause).increase()
-        self._held.extend(self._decode_harvest(pend))
+        if pend is not None:
+            self._mon.counter(
+                "serving.runahead.drains." + cause).increase()
+            self._held.extend(self._decode_harvest(pend))
+        self._held.extend(self._settle_prefills())
 
     def _decode_harvest(self, pend: Optional[_PendingTick]
                         ) -> List[Output]:
@@ -2849,11 +2938,13 @@ class Engine:
         if window is not None:
             # pages a windowed layer's pool holds that no later query
             # can read any more (they stay allocated: one block table
-            # serves every layer), over the slots that are decoding
+            # serves every layer), over the slots that are decoding;
+            # none where every windowed layer keeps a ring by slot
             mon.gauge("serving.cache.swa_pages_outside_window").set(sum(
                 max(0, r.written - (int(window) - 1)) // self.page_size
                 for r in self._slots
-                if r is not None and r.state == DECODE))
+                if r is not None and r.state == DECODE)
+                if self._paged_window else 0)
         if self._prefix is not None:
             mon.gauge("serving.prefix_hit_rate").set(
                 self._prefix.hit_rate)

@@ -860,6 +860,7 @@ class ServingFleet:
         # the dead replica's tick in flight dies with it, unharvested:
         # every request left with the tokens the host held
         w._inflight = None
+        w._prefilled = []
         self._remove_replica(index)
         return n
 

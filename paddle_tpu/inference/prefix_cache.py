@@ -251,6 +251,27 @@ class PrefixCache:
             parent = key
         return added
 
+    def discard(self, tokens, pages: List[int], first_chunk: int) -> int:
+        """Undo an ``insert`` whose pages turned out unusable: from
+        chunk ``first_chunk`` on (the chunks before it were matched at
+        admission and are another writer's), drop the first entry of
+        ``tokens``' chain that ``pages`` backs, with its descendants.
+        An entry backed by another request's page stays: first writer
+        won, and its pages are not in question. Returns entries
+        dropped."""
+        ps = self.page_size
+        parent: Optional[bytes] = None
+        for i in range(min(len(tokens) // ps, len(pages))):
+            chunk = tuple(int(t) for t in tokens[i * ps:(i + 1) * ps])
+            key = self._hash(parent, chunk)
+            ent = self._store.get(key)
+            if ent is None or ent.chunk != chunk:
+                break
+            if i >= first_chunk and ent.page == pages[i]:
+                return self._drop_subtree(key)
+            parent = key
+        return 0
+
     # -- eviction ------------------------------------------------------------
 
     def _idle(self, ent: _Entry) -> bool:

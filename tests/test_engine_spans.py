@@ -25,14 +25,12 @@ STEP_SPANS = {
     "engine.expire": set(),
     "engine.admit": {"admitted"},
     "engine.prefill": {"req", "bucket", "tokens", "start", "final"},
-    "engine.prefill.wait": set(),
     "engine.decode.wait": set(),
     "engine.harvest": {"tokens", "finished"},
     "engine.ensure_pages": {"allocated", "preempted"},
     "engine.bookkeeping": set(),
 }
-PARENT = {"engine.flush_state": "engine.decode.dispatch",
-          "engine.prefill.wait": "engine.prefill"}
+PARENT = {"engine.flush_state": "engine.decode.dispatch"}
 
 
 def _net(seed=0):
@@ -135,6 +133,55 @@ def test_prefill_span_req_joins_the_request_timeline(one_step):
     assert req == second
     phases = [s["phase"] for s in outs[req].spans]
     assert "PREFILL" in phases and phases[0] == "QUEUED"
+
+
+def test_a_chunk_is_waited_for_behind_the_next_steps_dispatch():
+    """With a tick in flight the step that dispatches a prefill chunk
+    does not wait for it: the NEXT step dispatches its tick first and
+    then harvests the chunk (`engine.prefill.harvest`, the wait its
+    child), so the device goes from the chunk into a tick. The slot
+    joins the dispatch after that."""
+    eng = _engine()
+    eng.add_request(_prompt(5), SamplingParams(max_new_tokens=9))
+    eng.step()
+    eng.step()
+    second = eng.add_request(_prompt(7, 2), SamplingParams(max_new_tokens=3))
+    with Profiler(timer_only=True) as prof:
+        eng.step()
+    first = _by_name(prof._store.events)
+    assert "engine.prefill" in first
+    assert "engine.prefill.wait" not in first
+    assert eng.num_prefilling == 1 and len(eng._prefilled) == 1
+    with Profiler(timer_only=True) as prof:
+        eng.step()
+    spans = _by_name(prof._store.events)
+    d0, d1, dargs = spans["engine.decode.dispatch"][0]
+    h0, h1, hargs = spans["engine.prefill.harvest"][0]
+    w0, w1, _ = spans["engine.prefill.wait"][0]
+    assert hargs == {"req": second} and dargs["slots"] == 1
+    assert d1 <= h0 <= w0 <= w1 <= h1
+    assert spans["engine.harvest"][0][1] <= h0 <= h1 \
+        <= spans["engine.expire"][0][0]
+    assert eng.num_prefilling == 0 and not eng._prefilled
+    with Profiler(timer_only=True) as prof:
+        eng.step()
+    third = _by_name(prof._store.events)
+    assert third["engine.decode.dispatch"][0][2]["slots"] == 2
+    while not eng.idle:
+        eng.step()
+
+
+def test_a_chunk_with_no_tick_in_flight_is_waited_for_at_once():
+    eng = _engine()
+    eng.add_request(_prompt(5), SamplingParams(max_new_tokens=2))
+    with Profiler(timer_only=True) as prof:
+        eng.step()
+    spans = _by_name(prof._store.events)
+    assert spans["engine.prefill"][0][1] <= \
+        spans["engine.prefill.harvest"][0][0]
+    assert eng.num_active == 1 and not eng._prefilled
+    while not eng.idle:
+        eng.step()
 
 
 def test_dispatch_of_tick_t_opens_before_the_wait_for_tick_t_minus_1():

@@ -323,19 +323,20 @@ def test_longctx_replay_p99_ttft_gate(capsys):
     import serving_replay
     fixture = os.path.join(os.path.dirname(__file__), "fixtures",
                            "serving_trace_longctx.jsonl")
-    # small-only baseline p99 on this fixture/geometry is ~11.3ms
-    # (recorded in docs/SERVING.md); 22 ≈ the 2x bar
+    # small-only baseline p99 on this fixture/geometry is ~16.4ms
+    # (recorded in docs/SERVING.md; a first token is charged to the
+    # step that harvests it, the one after its chunk's); 33 ≈ the 2x bar
     rc = serving_replay.main([
         fixture, "--pool-pages", "256", "--max-slots", "8",
         "--max-prefill-tokens", "32",
-        "--expect-p99-ttft-ms", "22", "--ttft-tag", "small",
+        "--expect-p99-ttft-ms", "33", "--ttft-tag", "small",
         "--expect-complete-timelines", "--json"])
     out = capsys.readouterr().out.strip().splitlines()
     assert rc == 0
     report = json.loads(out[-1])
     assert report["steady_state_recompiles"] == 0
     assert not report["failed"]
-    assert report["ttft_ms_by_tag"]["small"]["p99"] <= 22
+    assert report["ttft_ms_by_tag"]["small"]["p99"] <= 33
     # whales finish too (bounded slowdown, not starvation)
     assert report["ttft_ms_by_tag"]["whale"]["p99"] > 0
 
@@ -352,7 +353,7 @@ def test_longctx_replay_monolithic_trips_gate(capsys):
                            "serving_trace_longctx.jsonl")
     rc = serving_replay.main([
         fixture, "--pool-pages", "256", "--max-slots", "8",
-        "--expect-p99-ttft-ms", "22", "--ttft-tag", "small",
+        "--expect-p99-ttft-ms", "33", "--ttft-tag", "small",
         "--json"])
     capsys.readouterr()
     assert rc == 7

@@ -321,6 +321,120 @@ def test_preemption_under_pool_pressure_drains_first(net):
     eng.close()
 
 
+# -- a prefill chunk in flight beside the tick -------------------------------
+
+def _arrivals(n=5, new=10):
+    """A first request decoding, then one arrival a step: every later
+    chunk is dispatched with a tick in flight, so its wait is the next
+    step's."""
+    reqs = [(_prompt(5 + i, 3 * i + 1),
+             SamplingParams(max_new_tokens=new, temperature=0.7 * (i % 2),
+                            seed=i)) for i in range(n)]
+    return reqs, {0: reqs[:1], **{2 + i: [r] for i, r in
+                                  enumerate(reqs[1:])}}
+
+
+@pytest.mark.parametrize("budget", [None, 8])
+def test_chunks_in_flight_keep_every_stream_exact(net, budget):
+    reqs, plan = _arrivals()
+    eng = _engine(net, max_slots=3, max_prefill_tokens_per_step=budget)
+    deferred = []
+
+    def watch(eng, ids):
+        deferred.append(len(eng._prefilled))
+
+    done, ids = _drive(eng, plan, hooks={s: watch for s in range(3, 12)})
+    _exact(net, done, ids, reqs)
+    assert max(deferred) >= 1 and not eng._prefilled
+    eng.close()
+
+
+def test_cancel_with_a_chunk_in_flight(net):
+    reqs, plan = _arrivals(2, new=12)
+    eng = _engine(net)
+
+    def cancel(eng, ids):
+        assert eng._prefilled and eng._prefilled[0].req.req_id == ids[1]
+        out = eng.cancel(ids[1])
+        assert not eng._prefilled and eng._inflight is None
+        return [out]
+
+    done, ids = _drive(eng, plan, hooks={3: cancel})
+    out = done[ids[1]]
+    # the drain harvested the chunk first: the Output holds its token
+    assert out.finish_reason == "cancelled"
+    assert out.token_ids == _ref(net, *reqs[1])[:1]
+    _exact(net, done, ids, reqs, skip={ids[1]})
+    eng.close()
+
+
+def test_expiry_right_after_a_chunks_harvest(net):
+    vt = [0.0]
+    eng = _engine(net, clock=lambda: vt[0])
+    reqs, plan = _arrivals(2, new=12)
+    reqs[1] = (reqs[1][0], SamplingParams(max_new_tokens=12,
+                                          deadline_ms=5.0))
+    plan[2] = [reqs[1]]
+
+    def late(eng, ids):
+        assert eng._prefilled
+        vt[0] += 1.0
+
+    done, ids = _drive(eng, plan, hooks={3: late})
+    out = done[ids[1]]
+    assert out.finish_reason == "deadline"
+    assert out.token_ids == _ref(net, *reqs[1])[:1]
+    _exact(net, done, ids, reqs, skip={ids[1]})
+    eng.close()
+
+
+def test_nan_chunk_in_flight_leaves_nothing_in_the_prefix_cache(net):
+    """A final chunk's pages are registered at its dispatch (the
+    router looks between steps); a chunk that comes back NaN takes
+    them out again at its harvest, the cached head it started from
+    stays."""
+    inj = FaultInjector(seed=0, rate=0.0,
+                        plan=FaultPlan([(4, "prefill.nan")]))
+    eng = _engine(net, prefix_cache=True, fault_injector=inj,
+                  max_slots=3)
+    head = _prompt(2 * PAGE)
+    bad = np.concatenate([head, _prompt(2 * PAGE + 3, 40)])
+    reqs = [(head.copy(), SamplingParams(max_new_tokens=14)),
+            (bad, SamplingParams(max_new_tokens=4))]
+    seen = {}
+
+    def registered(eng, ids):
+        assert eng._prefilled
+        seen["at_dispatch"] = eng._prefix.lookup(bad)
+
+    def harvested(eng, ids):
+        seen["after"] = eng._prefix.lookup(bad)
+
+    done, ids = _drive(eng, {0: reqs[:1], 4: reqs[1:]},
+                       hooks={5: registered, 7: harvested})
+    assert seen["at_dispatch"] == 4 * PAGE
+    assert done[ids[1]].finish_reason == "nan_logits"
+    assert seen["after"] == 2 * PAGE          # the first request's pages
+    _exact(net, done, ids, reqs, skip={ids[1]})
+    eng.close()
+
+
+def test_preempting_the_request_of_a_chunk_in_flight(net):
+    """Growth finds the pool dry in the step that dispatched the
+    youngest request's chunk: the drain harvests the chunk before the
+    victim's slot is cleared."""
+    reqs = [(_prompt(7), SamplingParams(max_new_tokens=9,
+                                        temperature=0.9, seed=2)),
+            (_prompt(4, 9), SamplingParams(max_new_tokens=6))]
+    p0 = monitor.counter("serving.preemptions").get()
+    eng = _engine(net, page_size=4, pool_pages=4, max_context=16,
+                  prefill_bucket=4, watermark_pages=0)
+    done, ids = _drive(eng, {0: reqs[:1], 2: reqs[1:]})
+    _exact(net, done, ids, reqs)
+    assert monitor.counter("serving.preemptions").get() > p0
+    eng.close()
+
+
 @pytest.mark.parametrize("sync", [True, False])
 def test_snapshot_restore_mid_flight(net, sync):
     """sync=True harvests the tick in flight and reads the device's
@@ -385,6 +499,26 @@ def test_extract_request_mid_flight(net, device_key):
     _exact(net, {**moved, **stayed}, ids, reqs)
     eng.close()
     dst.close()
+
+
+def test_extract_and_return_with_a_chunk_in_flight(net):
+    """extract_request(device_key=False) drains nothing: a request
+    can leave with its chunk in flight and come back to the same
+    engine before the next step. The stale chunk hands nothing over;
+    the new admission's prefill does."""
+    reqs, plan = _arrivals(2, new=12)
+    eng = _engine(net)
+
+    def bounce(eng, ids):
+        assert eng._prefilled and eng._prefilled[0].req.req_id == ids[1]
+        req = eng.extract_request(ids[1], device_key=False)
+        assert eng._prefilled and req.state == "WAITING"
+        eng.requests[req.req_id] = req
+        eng._waiting.append(req)
+
+    done, ids = _drive(eng, plan, hooks={3: bounce})
+    _exact(net, done, ids, reqs)
+    eng.close()
 
 
 # -- the dispatches that do not run ahead ------------------------------------

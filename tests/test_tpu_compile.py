@@ -400,6 +400,62 @@ def test_gqa_layer_cache_step_compiles_in_place(monkeypatch, one_chip,
         assert re.search(r"f32\[(1,)?8,8,256,2816\]", text)
 
 
+# the window cell: 128 slots, each sliding layer a ring of 128 keys and
+# values a slot (8 KV heads of 128): one page of the paged layout
+RING_SLOTS, RING = 128, (128, 8, 128, 128)
+
+
+@pytest.mark.parametrize("tokens", [1, 2048],
+                         ids=["one-token-128-slots", "chunk-2048"])
+def test_ring_layer_step_compiles_in_place(monkeypatch, one_chip, tokens):
+    """A K-EXAONE sliding layer on its per-slot rings at the published
+    widths (64 query / 8 KV heads of 128, window 128): a one-token step
+    is `paged_decode` on the rings as a pool of one page a slot, 128
+    lanes at a group of 8; a chunk scores blocks of 256 queries against
+    the band of 384 keys their windows reach, never the chunk's 2,176;
+    either way the donated rings are written where they lie."""
+    from paddle_tpu import monitor
+    from paddle_tpu.core import place
+    from paddle_tpu.jit.functional import functional_call, get_params
+    from paddle_tpu.nn.initializer.lazy_init import LazyGuard
+    from paddle_tpu.nn.layer.layers import param_dtype
+    from paddle_tpu.text.models.k_exaone import (SLIDING, KExaoneAttention,
+                                                 KExaoneConfig)
+    monkeypatch.setattr(place, "accelerator_available", lambda: True)
+    with LazyGuard(), param_dtype("bfloat16"):
+        attn = KExaoneAttention(KExaoneConfig(), SLIDING)
+    b = RING_SLOTS if tokens == 1 else 1
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(params, u, pos0, slots, n_valid, kr, vr):
+        (out, cache), _ = functional_call(
+            attn, params, {}, (u,),
+            dict(kv_cache=(kr, vr, slots, n_valid), cache_index=pos0))
+        return out, cache
+
+    band = monitor.counter("kernels.prefill.gqa_band")
+    before = band.get()
+    compiled = jax.jit(step, donate_argnums=(5, 6)).lower(
+        {k: struct(v.shape, v.dtype) for k, v in get_params(attn).items()},
+        struct((b, tokens, 6144), jnp.bfloat16), struct((b,), jnp.int32),
+        None if tokens == 1 else struct((b,), jnp.int32),
+        struct((b,), jnp.int32), struct(RING, jnp.bfloat16),
+        struct(RING, jnp.bfloat16)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert ("%paged_decode" in text) == (tokens == 1)
+    ring_copy = re.compile(
+        rf"= \w+\[{RING_SLOTS},(8,128|1024),128\]\{{[^}}]*\}} copy\(")
+    assert [line.strip()[:120] for line in text.splitlines()
+            if ring_copy.search(line)] == []
+    assert mem.alias_size_in_bytes >= 2 * math.prod(RING) * 2
+    assert band.get() == before + (tokens > 1)
+    if tokens > 1:
+        scores = set(re.findall(r"f32\[(?:1,)?8,8,256,(\d+)\]", text))
+        assert scores == {"384"}, scores
+
+
 # an expert layer of each expert cell at the cell's widths: hidden, expert
 # width, experts routed over, tokens (a prefill bucket, or the decode
 # program's lanes); each holds share 0 of 8 and routes top-8
