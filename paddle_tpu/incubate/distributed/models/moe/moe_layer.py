@@ -37,14 +37,16 @@ from .gate import (BaseGate, GShardGate, NaiveGate, SigmoidTopKGate,
 # rows a uniform router sends to this chip's experts (the factor of
 # GShard's default capacity, but as the size of a STEP: picks beyond it
 # take another slab, none is dropped), in an ODD number of row tiles.
-# XLA's TPU `ragged_dot` walks (group, row tile) pairs with the largest
-# power of two up to 512 that divides its row count as the tile, and a
-# pair costs the whole tile however few of the group's rows lie in it:
-# an odd number of tiles makes the tile ours to choose. Measured on the
-# v5e (PERF.md section 6, PR 35): 128 rows for a prefill chunk's 6-65
+# A grouped matmul walks (group, row tile) pairs, and a pair costs the
+# whole tile however few of the group's rows lie in it. On a TPU the
+# tile is set by kernels.moe.gmm_row_tile, the same rule as these
+# constants (tests/test_moe_gmm.py holds the two equal): measured on the
+# v5e (PERF.md section 6, PR 35), 128 rows for a prefill chunk's 6-97
 # rows an expert (a tile of 512 spends its time on dead rows, one of 32
-# or 64 takes more pairs), 32 for a decode tick's 2-3
-# (tests/test_tpu_compile.py holds the compiler to that rule).
+# or 64 takes more pairs), 32 for a decode tick's 2-8. The odd count is
+# for the path without that kernel: XLA's `ragged_dot` takes the largest
+# power of two up to 512 that divides its row count as the tile, and an
+# odd number of tiles makes the tile ours to choose there too.
 _SLAB_FACTOR = 2
 _SLAB_ROW_TILE = 128
 _SLAB_SMALL_ROW_TILE = 32       # for a slab of fewer than 512 rows
@@ -565,7 +567,7 @@ class MoELayer(Layer):
         picks of dead tokens (``token_mask`` False), sort past the last
         group. The sorted order is then worked off in SLABS of
         ``_slab_rows`` rows, as many as the held picks fill: a slab
-        gathers its tokens, runs ONE ragged grouped matmul per matrix
+        gathers its tokens, runs the gated experts as grouped matmuls
         (kernels.moe.grouped_ffn_gated) with the part of each expert's
         group that lies in it, weights its rows in float32 and adds
         them onto their tokens. No array of the routed path has

@@ -35,6 +35,13 @@ Shapes that don't tile, and non-TPU backends, route to the
 batched-einsum reference (`grouped_ffn_reference`) — decided from
 geometry and platform BEFORE the call. A kernel that fails to trace,
 lower or compile raises: nothing here catches it and reroutes.
+
+The gated three-matrix experts of the sigmoid top-k layer take another
+road (`grouped_ffn_gated`, at the end of this file): no capacity buffer,
+rows sorted by expert, and a grouped matmul `gmm` whose K is not tiled,
+so that an expert's matrix is fetched once a call however many row
+tiles its group spans (docs/KERNELS.md "Grouped matmul for gated
+experts").
 """
 from __future__ import annotations
 
@@ -704,31 +711,240 @@ def grouped_ffn(x, w1, b1, w2, b2, ws, counts, *, activation="gelu",
                                  activation)
 
 
-def grouped_ffn_gated(rows, w_gate, w_up, w_down, group_sizes):
+# ---------------------------------------------------------------------------
+# grouped matmul over rows SORTED by expert (the sigmoid top-k layer's slabs)
+# ---------------------------------------------------------------------------
+
+# row tile of a grouped matmul: 128 rows for a prefill slab, 32 for a
+# decode tick's (fewer than GMM_SMALL_ROWS rows: 2-8 rows an expert, where
+# a 128-row tile would multiply mostly rows of other groups). The same
+# rule MoELayer._slab_rows rounds its slabs by.
+GMM_ROW_TILE = 128
+GMM_SMALL_ROW_TILE = 32
+GMM_SMALL_ROWS = 512
+# of the v5e's 128 MiB of VMEM, what the blocks of one call may take; the
+# compiler's own default (16 MiB scoped) holds no [6144, 512] bf16 block
+# twice beside its rows
+_GMM_VMEM_BLOCKS = 88 << 20
+_GMM_VMEM_HEADROOM = 8 << 20
+
+
+def gmm_row_tile(m: int) -> int:
+    return GMM_ROW_TILE if m >= GMM_SMALL_ROWS else GMM_SMALL_ROW_TILE
+
+
+def gmm_tiles(m: int, k: int, n: int, dtype, n_rhs: int = 1):
+    """(row tile, column tile, vmem_limit_bytes) of one grouped matmul
+    [m, k] x n_rhs x [E, k, n], from the static shapes alone. K is never
+    tiled: the weight block is [k, column tile], its index (group, column
+    tile), so the row tiles one group spans share one fetch. The column
+    tile is the widest lane-aligned divisor of n whose blocks fit
+    ``_GMM_VMEM_BLOCKS``; the pipeline double-buffers every block:
+
+        rows   2 x tm x k  x itemsize
+        weight 2 x n_rhs x k x tn x itemsize
+        out    2 x tm x tn x itemsize, + n_rhs float32 products tm x tn
+
+    e.g. bf16 [3200, 6144] x [16, 6144, 2048]: tn 2048, 3 + 48 + 1 + 1 =
+    53 MiB; gate and up in one call (n_rhs 2): tn 1024, 3 + 48 + 0.5 + 1."""
+    tm = gmm_row_tile(m)
+    item = jnp.dtype(dtype).itemsize
+    need = 0
+    for parts in range(1, n // 128 + 1):
+        tn = n // parts
+        if n % parts or tn % 128:
+            continue
+        need = (2 * item * (tm * k + n_rhs * k * tn + tm * tn)
+                + 4 * n_rhs * tm * tn)
+        if need <= _GMM_VMEM_BLOCKS:
+            break
+    return tm, tn, need + _GMM_VMEM_HEADROOM
+
+
+def gmm_requirements(m: int, h: int, f: int):
+    """Why `grouped_ffn_gated` cannot run its rows on the Pallas grouped
+    matmul, or None: whole row tiles, lane-aligned widths (both are K of
+    one call and N of another)."""
+    problems = []
+    if m % gmm_row_tile(m):
+        problems.append(f"{m} rows are not whole tiles of "
+                        f"{gmm_row_tile(m)}")
+    for name, width in (("hidden", h), ("expert", f)):
+        if width % 128:
+            problems.append(f"{name} width {width} is not a multiple of "
+                            "the 128 lane width")
+    return "; ".join(problems) if problems else None
+
+
+def gmm_metadata(group_sizes, m: int, tm: int):
+    """What the grid of a grouped matmul over ``m`` sorted rows in tiles
+    of ``tm`` walks: a VISIT is a (group, row tile) pair that shares a
+    row, in the order of the rows. Returns (offsets [E + 1]: group g is
+    rows offsets[g]:offsets[g + 1]; group_ids, tile_ids [m / tm + E - 1],
+    of which the first ``visits`` are real; visits, a traced count). An
+    empty group has no visit. Computed once a slab: the gate, up and
+    down calls walk the same rows."""
+    gs = jnp.asarray(group_sizes, jnp.int32)
+    n_groups, tiles = gs.shape[0], m // tm
+    ends = jnp.cumsum(gs)
+    first = (ends - gs) // tm
+    per_group = jnp.where(gs > 0, -(-ends // tm) - first, 0)
+    most = tiles + n_groups - 1
+    group_ids = jnp.repeat(jnp.arange(n_groups, dtype=jnp.int32), per_group,
+                           total_repeat_length=most)
+    nth = (jnp.arange(most, dtype=jnp.int32)
+           - (jnp.cumsum(per_group) - per_group)[group_ids])
+    tile_ids = jnp.clip(first[group_ids] + nth, 0, tiles - 1)
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    return (offsets, group_ids, tile_ids,
+            jnp.sum(per_group, dtype=jnp.int32))
+
+
+def _gmm_kernel(offsets_ref, group_ids_ref, tile_ids_ref, lhs_ref, *refs,
+                tm):
+    """One (column tile, visit) program: the visit's row tile times its
+    group's [K, tn] block, float32 over the whole K, rounded once, stored
+    to the rows of the tile that are the group's (a tile that holds
+    several groups is visited once for each; its output block stays in
+    VMEM between them). With two weight refs the store is
+    silu(rows·w_gate) * (rows·w_up), each product rounded to the rows'
+    dtype first, as two calls and an XLA pass would round them."""
+    from jax.experimental import pallas as pl
+
+    *rhs_refs, out_ref = refs
+    v = pl.program_id(1)
+    g = group_ids_ref[v]
+    row = (tile_ids_ref[v] * jnp.int32(tm)
+           + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0))
+    mine = jnp.logical_and(row >= offsets_ref[g], row < offsets_ref[g + 1])
+    x = lhs_ref[...]
+    dt = out_ref.dtype
+    prods = [jax.lax.dot_general(x, r[...], (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32
+                                 ).astype(dt) for r in rhs_refs]
+    if len(prods) == 2:
+        val = (jax.nn.silu(prods[0].astype(jnp.float32))
+               * prods[1].astype(jnp.float32)).astype(dt)
+    else:
+        val, = prods
+    out_ref[...] = jnp.where(mine, val, out_ref[...])
+
+
+def gmm(lhs, rhss, metadata, *, tn=None, interpret=False):
+    """Grouped matmul on the Pallas kernel: lhs [M, K] sorted by group,
+    rhss one [E, K, N] (out = lhs·rhs[group]) or two (out =
+    silu(lhs·rhs0[group]) * (lhs·rhs1[group])), metadata from
+    `gmm_metadata` at `gmm_row_tile(M)`. Rows in no group are NOT
+    written: the caller zeroes them. Grid (column tiles, visits), the
+    visits a traced count; see `gmm_tiles` for the blocks."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, k = lhs.shape
+    n = rhss[0].shape[2]
+    offsets, group_ids, tile_ids, visits = metadata
+    tm, tn_auto, vmem = gmm_tiles(m, k, n, lhs.dtype, len(rhss))
+    tn = tn or tn_auto
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(n // tn, visits),
+        in_specs=[pl.BlockSpec((tm, k), lambda j, v, o, g, t: (t[v], 0))]
+        + [pl.BlockSpec((None, k, tn), lambda j, v, o, g, t: (g[v], 0, j))
+           for _ in rhss],
+        out_specs=pl.BlockSpec((tm, tn), lambda j, v, o, g, t: (t[v], j)),
+    )
+    item = jnp.dtype(lhs.dtype).itemsize
+    with _x32_trace():
+        return pl.pallas_call(
+            functools.partial(_gmm_kernel, tm=tm),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=vmem),
+            cost_estimate=pl.CostEstimate(
+                flops=2 * m * k * n * len(rhss),
+                bytes_accessed=item * (m * k * (n // tn) + m * n + len(rhss)
+                                       * rhss[0].shape[0] * k * n),
+                transcendentals=m * n * (len(rhss) - 1)),
+            interpret=interpret,
+            name="moe_gmm",
+        )(offsets, group_ids, tile_ids, lhs, *rhss)
+
+
+def _ffn_gated_ragged(rows, w_gate, w_up, w_down, gs):
+    """`grouped_ffn_gated` as three `jax.lax.ragged_dot`s and an XLA pass:
+    the path where no Mosaic compiler is, and the derivative of both."""
+    dt = rows.dtype
+    g = jax.lax.ragged_dot(rows, w_gate, gs, preferred_element_type=dt)
+    u = jax.lax.ragged_dot(rows, w_up, gs, preferred_element_type=dt)
+    mid = (jax.nn.silu(g.astype(jnp.float32))
+           * u.astype(jnp.float32)).astype(dt)
+    return jax.lax.ragged_dot(mid, w_down, gs, preferred_element_type=dt)
+
+
+# jitted under the custom_vjp: the expert layers of one program call it
+# with the same shapes, and a nested jit is traced and lowered (Pallas to
+# Mosaic, ~0.1 s of host time a call) once for all of them; set-up time,
+# not device time
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+@functools.partial(jax.jit, static_argnames="interpret")
+def _ffn_gated_pallas(rows, w_gate, w_up, w_down, gs, interpret=False):
+    metadata = gmm_metadata(gs, rows.shape[0], gmm_row_tile(rows.shape[0]))
+    mid = gmm(rows, (w_gate, w_up), metadata, interpret=interpret)
+    return gmm(mid, (w_down,), metadata, interpret=interpret)
+
+
+def _ffn_gated_vjp_fwd(rows, w_gate, w_up, w_down, gs, interpret):
+    return (_ffn_gated_pallas(rows, w_gate, w_up, w_down, gs, interpret),
+            (rows, w_gate, w_up, w_down, gs))
+
+
+def _ffn_gated_vjp_bwd(interpret, res, ct):
+    *operands, gs = res
+    _, pull = jax.vjp(lambda *a: _ffn_gated_ragged(*a, gs), *operands)
+    return (*pull(ct), None)
+
+
+_ffn_gated_pallas.defvjp(_ffn_gated_vjp_fwd, _ffn_gated_vjp_bwd)
+
+
+def grouped_ffn_gated(rows, w_gate, w_up, w_down, group_sizes, *,
+                      interpret=False):
     """Gated three-matrix expert FFN over rows SORTED by expert:
     out[r] = (silu(rows[r]·w_gate[e]) * (rows[r]·w_up[e]))·w_down[e] for
     the expert e whose group row r falls in; rows [M, h], w_gate/w_up
     [E, h, f], w_down [E, f, h], group_sizes [E] int32 with
     sum(group_sizes) <= M (rows past the sum belong to no expert and
-    come back as zeros). Three `jax.lax.ragged_dot`s: XLA's own grouped
-    matmul, which on the TPU walks (group, row-tile) pairs and so reads
-    an expert's weights only when a row landed on it, with no capacity
-    buffer and nothing dropped. Weights follow the group sizes; TIME
-    follows M as well (a call on 16,384 rows of which 2,050 were in a
-    group took 1.86 ms on the v5e, on 384 rows 0.67: PERF.md section
-    5), as do the silu pass and the zeroing here: give it the rows
-    that can be live, as MoELayer._forward_sorted's slabs do. It is
-    XLA, not Pallas: no name on a device trace beyond the ragged-dot
-    custom calls."""
+    come back as zeros). No capacity buffer, nothing dropped; operands
+    in the rows' dtype, float32 accumulation over the whole K, one
+    rounding a product.
+
+    On a TPU: two calls of the Pallas grouped matmul `gmm` (gate and up
+    in one, then down), which walk (group, row tile) pairs and fetch an
+    expert's [K, column tile] block once however many row tiles its
+    group spans: `gmm_tiles` sets the tiles from the static shapes.
+    Weights follow the groups that have a row; TIME follows M as well
+    (the rows are read a column sweep, every tile is multiplied once a
+    group in it): give it the rows that can be live, as
+    MoELayer._forward_sorted's slabs do. Elsewhere, and for shapes
+    `gmm_requirements` names, three `jax.lax.ragged_dot`s (on the TPU
+    XLA's own grouped matmul, K in tiles of 512: an expert's matrix is
+    fetched again for every row tile of its group). The derivative is
+    the ragged formulation's on both paths. Trace-time counters
+    `kernels.moe.gmm_pallas` / `kernels.moe.gmm_fallback` say which.
+    ``interpret=True`` (tests) runs the kernel path interpreted, on any
+    backend."""
+    from .. import monitor
     gs = jnp.asarray(group_sizes, jnp.int32)
     dt = rows.dtype
-    g = jax.lax.ragged_dot(rows, w_gate.astype(dt), gs,
-                           preferred_element_type=dt)
-    u = jax.lax.ragged_dot(rows, w_up.astype(dt), gs,
-                           preferred_element_type=dt)
-    mid = (jax.nn.silu(g.astype(jnp.float32))
-           * u.astype(jnp.float32)).astype(dt)
-    out = jax.lax.ragged_dot(mid, w_down.astype(dt), gs,
-                             preferred_element_type=dt)
+    weights = [w.astype(dt) for w in (w_gate, w_up, w_down)]
+    if (interpret or place.accelerator_available()) and gmm_requirements(
+            rows.shape[0], rows.shape[1], w_gate.shape[2]) is None:
+        monitor.counter("kernels.moe.gmm_pallas").increase()
+        out = _ffn_gated_pallas(rows, *weights, gs, interpret)
+    else:
+        monitor.counter("kernels.moe.gmm_fallback").increase()
+        out = _ffn_gated_ragged(rows, *weights, gs)
     live = jnp.arange(rows.shape[0], dtype=jnp.int32) < jnp.sum(gs)
     return jnp.where(live[:, None], out, jnp.zeros((), dt))

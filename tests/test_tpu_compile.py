@@ -461,23 +461,38 @@ def test_ring_layer_step_compiles_in_place(monkeypatch, one_chip, tokens):
 # program's lanes); each holds share 0 of 8 and routes top-8
 EXPERT_LAYERS = {"notes48-chunk-2048": (5120, 1536, 256, 2048),
                  "chat96-prefill-1024": (4096, 1280, 320, 1024),
-                 "chat96-decode-96-lanes": (4096, 1280, 320, 96)}
+                 "chat96-decode-96-lanes": (4096, 1280, 320, 96),
+                 "mixed128-chunk-1536": (6144, 2048, 128, 1536),
+                 "mixed128-decode-128-lanes": (6144, 2048, 128, 128)}
+
+
+def _pallas_calls(jaxpr):
+    from jax._src import core
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
 
 
 @pytest.mark.parametrize("h,f,experts,tokens", EXPERT_LAYERS.values(),
                          ids=EXPERT_LAYERS.keys())
-def test_sorted_dispatch_works_on_slabs_of_the_held_rows(one_chip, h, f,
+def test_sorted_dispatch_works_on_slabs_of_the_held_rows(monkeypatch,
+                                                         one_chip, h, f,
                                                          experts, tokens):
-    """`MoELayer._forward_sorted` on one chip's share of the experts: the
-    three `ragged-dot` calls take a slab of 2/8 of the N x 8 picks in an
-    odd number of row tiles (128 rows; 32 for the decode program), XLA
-    tiles them by that (its rule: the largest power of two up to 512
-    that divides the row count; the calls' metadata holds one entry a
-    (group, row tile) pair that can occur), and no array has N x 8 rows
-    by the hidden or the expert width. (Sized for every pick, and tiled by 512 rows, the 12 calls
-    of a prefill program were 20.6% and 36% of it, on rows of which an
-    eighth were live: PERF.md section 6, PR 35.)"""
+    """`MoELayer._forward_sorted` on one chip's share of the experts: a
+    slab of 2/8 of the N x 8 picks in an odd number of row tiles (128
+    rows; 32 for the decode program) goes through two `moe_gmm` kernel
+    calls (gate and up in one, then down) and no `ragged-dot`; a call's
+    row block is [row tile, K] and its weight block [K, column tile]
+    with K WHOLE, so a group's row tiles share one fetch of its matrix
+    (XLA's ragged-dot tiles K by 512 and fetched it once a tile: 1.5-2.2
+    times the bytes, PERF.md section 6, PR 37); and no array has N x 8
+    rows by the hidden or the expert width (sized for every pick, and
+    tiled by 512 rows, the 12 calls of a prefill program were 20.6% and
+    36% of it: PERF.md section 6, PR 35)."""
     from paddle_tpu import monitor
+    from paddle_tpu.core import place
     from paddle_tpu.core.dispatch import unwrap
     from paddle_tpu.incubate.distributed.models.moe import (MoELayer,
                                                             moe_layer)
@@ -489,6 +504,7 @@ def test_sorted_dispatch_works_on_slabs_of_the_held_rows(one_chip, h, f,
         layer = MoELayer(h, f, experts, gate="sigmoid_topk", top_k=8,
                          activation="swiglu", expert_share=(0, 8))
     layer.eval()
+    monkeypatch.setattr(place, "accelerator_available", lambda: True)
 
     def step(params, buffers, x):
         out, _ = functional_call(layer, params, buffers, (x,), {})
@@ -497,24 +513,31 @@ def test_sorted_dispatch_works_on_slabs_of_the_held_rows(one_chip, h, f,
     def struct(tree):
         return {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
                 for k, v in tree.items()}
-    count = monitor.counter("kernels.moe.sorted.slab")
-    before = count.get()
-    text = jax.jit(step).lower(
+    counts = [monitor.counter(name) for name in (
+        "kernels.moe.sorted.slab", "kernels.moe.gmm_pallas",
+        "kernels.moe.gmm_fallback")]
+    before = [c.get() for c in counts]
+    traced = jax.jit(step).trace(
         struct(get_params(layer)), struct(get_buffers(layer)),
-        jax.ShapeDtypeStruct((tokens, h), jnp.bfloat16, sharding=one_chip)
-    ).compile().as_text()
-    assert count.get() == before + 1
+        jax.ShapeDtypeStruct((tokens, h), jnp.bfloat16, sharding=one_chip))
+    text = traced.lower().compile().as_text()
+    assert [c.get() - b for c, b in zip(counts, before)] == [1, 1, 0]
     picks = tokens * 8
     slab = moe_layer._slab_rows(picks, 8)
-    tile = 128 if tokens > 96 else 32
+    tile = moe.gmm_row_tile(slab)
+    assert tile == (128 if tokens > 128 else 32)
     assert 0 <= slab - picks // 4 <= 2 * tile and slab % (2 * tile) == tile
-    calls = re.findall(r"%ragged-dot[\w.\-]* = bf16\[(\d+),(\d+)\]\S* "
-                       r"custom-call", text)
-    assert sorted(calls) == sorted(
-        [(str(slab), str(f))] * 2 + [(str(slab), str(h))]), calls
-    pairs = re.search(r"%ragged-dot-metadata = \(s32\[\d+\]\S* "
-                      r"s32\[(\d+)\]", text)
-    assert int(pairs.group(1)) == experts // 8 + slab // tile - 1
+    assert "ragged-dot" not in text
+    calls = re.findall(r"= bf16\[(\d+),(\d+)\]\S* custom-call\([^\n]*"
+                       r"tpu_custom_call[^\n]*moe_gmm", text)
+    assert calls == [(str(slab), str(f)), (str(slab), str(h))], calls
+    blocks = [[tuple(getattr(d, "block_size", None) for d in bm.block_shape)
+               for bm in eqn.params["grid_mapping"].block_mappings]
+              for eqn in _pallas_calls(traced.jaxpr.jaxpr)]
+    tn_up = moe.gmm_tiles(slab, h, f, jnp.bfloat16, 2)[1]
+    assert blocks == [
+        [(tile, h), (None, h, tn_up), (None, h, tn_up), (tile, tn_up)],
+        [(tile, f), (None, f, h), (tile, h)]], blocks
     wide = re.findall(
         rf"\w+\[(?:{picks}|{tokens},8),(?:{h}|{f})\]", text)
     assert wide == []
