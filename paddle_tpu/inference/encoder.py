@@ -170,7 +170,7 @@ class BatchEncoder:
         self._st = (get_params(model), get_buffers(model),
                     get_frozen(model))
         self._clock = clock if clock is not None else time.perf_counter
-        self._device_s = 0.0
+        self._wait_s = 0.0
         # tenant fairness state: per-tenant FIFO queues walked
         # round-robin when a batch is formed (the Engine/DisaggEngine
         # fairness shape). OrderedDict keeps a stable walk order.
@@ -225,12 +225,12 @@ class BatchEncoder:
 
     def _sync_timed(self, outs) -> None:
         """Block until the dispatched encode lands, charging the wait
-        to the tick's DEVICE share (same attribution contract as
-        Engine._sync_timed — and the one sanctioned sync point the
-        hot-path lint recognizes)."""
+        to the tick's DEVICE share (time blocked on the device, as
+        the Engine's wait spans count it; the one sanctioned sync
+        point the hot-path lint recognizes)."""
         t0 = time.perf_counter()
         jax.block_until_ready(outs)
-        self._device_s += time.perf_counter() - t0
+        self._wait_s += time.perf_counter() - t0
 
     # -- hot-path lint (docs/ANALYSIS.md "Hot-path rules") -------------------
 
@@ -308,7 +308,7 @@ class BatchEncoder:
         bucket batch, encode it, retire its requests."""
         outs: List[EmbedOutput] = []
         wall0 = time.perf_counter()
-        self._device_s = 0.0
+        self._wait_s = 0.0
         c0 = self._tracker.compiles
         with tape_mod.no_grad_guard():
             outs.extend(self._expire())
@@ -322,7 +322,7 @@ class BatchEncoder:
         # device time is the block_until_ready wait on the encode
         # output, host time is everything else in the tick
         wall_ms = (time.perf_counter() - wall0) * 1e3
-        dev_ms = min(self._device_s * 1e3, wall_ms)
+        dev_ms = min(self._wait_s * 1e3, wall_ms)
         monitor.gauge("serving.embed.host_ms_per_tick").set(
             wall_ms - dev_ms)
         monitor.gauge("serving.embed.device_ms_per_tick").set(dev_ms)

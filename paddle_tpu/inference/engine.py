@@ -86,6 +86,14 @@ Two opt-in accelerators ride on the same scheduler (this PR):
 built with a Pallas-ineligible page geometry — validated ONCE at
 construction, docs/DECODE.md).
 
+Every ``step()`` also leaves one row in ``Engine.step_log``
+(tracing.StepLog; docs/OBSERVABILITY.md "Step record"): its phases,
+timed where the ``engine.*`` spans are opened (``_span``), its lanes
+and its programs, in a ring kept for the life of the engine; a slow
+step is kept whole (``step_log.slow()``, ``serving.slow_steps``), and
+``serving.host_ms_per_tick`` / ``serving.device_ms_per_tick`` are read
+off the row.
+
 Reliability layer (inference/reliability.py has the fault catalog and
 the snapshot format):
 
@@ -478,17 +486,12 @@ class _PendingTick:
     """One in-flight decode dispatch (the pipelined tick loop's
     handoff between dispatch and harvest): the device output futures,
     a snapshot of the (slot, request) pairs the dispatch covered —
-    harvest skips rows whose request was retired during the overlap
-    window — and the attribution bookkeeping (dispatch wall time +
-    the device-seconds mark, so the harvest sync can attribute host
-    work that ran hidden under device execution as OVERLAP instead of
-    double-counting it)."""
+    harvest skips rows whose request was retired while the tick was
+    in flight."""
 
     kind: str                 # "single" | "spec"
     data: tuple               # device outputs to sync + fetch
     active: list              # [(slot, Request)] snapshot at dispatch
-    t_dispatch: float         # perf_counter at dispatch
-    dev_mark: float           # self._device_s at dispatch
     extras: tuple = ()        # _tick_extras outputs, still on the device
     # `engine.decode.dispatch` arguments: what the program reads
     span_args: dict = field(default_factory=dict)
@@ -510,8 +513,6 @@ class _PendingPrefill:
     start: int                # first position the chunk wrote
     tokens: int               # real tokens of the chunk
     fresh: bool               # no token generated yet: sample the first
-    t_dispatch: float         # perf_counter at dispatch
-    dev_mark: float           # self._device_s at dispatch
 
 
 class _Emitted:
@@ -858,10 +859,10 @@ class Engine:
                 a.nbytes for kind, layer in zip(self._cache_kinds,
                                                 self._pools)
                 if kind == "state" for a in layer))
-        # host/device tick attribution: wall seconds this tick spent
-        # blocked on device results (block_until_ready around the
-        # tick's dispatch outputs); step() publishes the split
-        self._device_s = 0.0
+        # the step record (docs/OBSERVABILITY.md "Step record"): one row
+        # a step(), timed at the spans' own sites (_span), kept for the
+        # life of the engine and after it in tracing.step_logs()
+        self.step_log = tracing.StepLog(self.label)
         # fault_injector: an explicit FaultInjector, None = arm from
         # FLAGS_serving_fault_* (off by default), False = force OFF
         # even when the flags arm the process (the chaos tooling's
@@ -893,11 +894,6 @@ class Engine:
         # prefill chunks dispatched beside a tick in flight; the next
         # step() (or a drain) waits for them AFTER its own dispatch
         self._prefilled: List[_PendingPrefill] = []
-        # dispatch-pipelining attribution (see _sync_timed): host work
-        # that ran while the device was still executing the in-flight
-        # dispatch — hidden under device time, published as the
-        # serving.overlap_ms_per_tick gauge, never double-counted
-        self._overlap_s = 0.0
         self.last_stall_snapshot: Optional[dict] = None
         from ..distributed import watchdog as _watchdog
         self._watchdog = _watchdog
@@ -1387,15 +1383,20 @@ class Engine:
             args["state_slots"] = len(lanes)
         return args
 
-    def _pending(self, kind: str, data: tuple, lanes, t0: float,
-                 mark: float, variant: str, extras: tuple = ()
-                 ) -> _PendingTick:
+    def _pending(self, kind: str, data: tuple, lanes, variant: str,
+                 extras: tuple = ()) -> _PendingTick:
         """The handoff record of the dispatch just made over `lanes`."""
         return _PendingTick(
             kind=kind, data=data,
-            active=[(i, req) for i, req, _ in lanes],
-            t_dispatch=t0, dev_mark=mark, extras=extras,
+            active=[(i, req) for i, req, _ in lanes], extras=extras,
             span_args=self._dispatch_span_args(lanes, variant))
+
+    def _span(self, name: str, **args) -> tracing.StepSpan:
+        """THE site an engine phase is timed at: a `RecordEvent` of
+        that name and those arguments (what a profiler trace and the
+        benchmark's span readers see) whose duration, when it closes,
+        also lands in the step record's open row under its name."""
+        return tracing.StepSpan(self.step_log, name, **args)
 
     def _dispatch_steady(self, steady, fn, *args):
         """Dispatch one tick executable. On a STEADY tick (warm
@@ -1417,7 +1418,7 @@ class Engine:
         """Queue a prompt (1-D token ids, or a [1, s] Tensor/array) for
         generation under ``sampling_params``. Returns the request id;
         the request is admitted to a slot by a later ``step()``."""
-        with RecordEvent("engine.add_request") as span:
+        with self._span("engine.add_request") as span:
             params = sampling_params or SamplingParams()
             if isinstance(params, dict):
                 params = SamplingParams(**params)
@@ -1496,23 +1497,15 @@ class Engine:
         step. Deadline / queue-timeout enforcement lands on step
         boundaries, so a request can overrun its deadline_ms by at
         most one step before _expire retires it."""
-        with RecordEvent("engine.step", step=self._steps,
-                         active=self.num_active,
-                         waiting=self.num_waiting,
-                         prefilling=self.num_prefilling):
+        log = self.step_log
+        decoding, waiting, prefilling = \
+            self.num_active, self.num_waiting, self.num_prefilling
+        with RecordEvent("engine.step", step=self._steps, active=decoding,
+                         waiting=waiting, prefilling=prefilling):
+            log.begin(self._steps, decoding, prefilling, waiting)
             # what forced drains since the last step retired comes out
             # first; a drain inside this step appends here too
             outputs = self._held
-            wall0 = time.perf_counter()
-            self._device_s = 0.0
-            self._overlap_s = 0.0
-            if self._inflight is not None:
-                # the tick carried in: this step's device share counts
-                # its window from the step's start (_sync_timed)
-                self._inflight.t_dispatch = wall0
-                self._inflight.dev_mark = 0.0
-                for chunk in self._prefilled:
-                    chunk.t_dispatch, chunk.dev_mark = wall0, 0.0
             c0 = self._tracker.compiles
             if self._moe_layer is not None and c0 != self._moe_tracker_mark:
                 # compiles landed OUTSIDE our steps since the last sync
@@ -1531,10 +1524,12 @@ class Engine:
                 # (a) dispatch the decode executable for the slots settled
                 # by the LAST step — tick t queues behind tick t-1 and
                 # starts the instant that one ends
-                with RecordEvent("engine.decode.dispatch") as span:
+                with self._span("engine.decode.dispatch") as span:
                     pending = self._safe_decode()
                     if pending is not None:
                         span.set(**pending.span_args)
+                        log.variant = pending.span_args["variant"]
+                        log.inflight = pending.span_args["inflight"]
                 # (b) a single-tick dispatch stays in flight; the tick the
                 # last step left in flight is waited for and harvested now.
                 # (Nothing dispatched: the tick in flight, if any, is
@@ -1553,11 +1548,12 @@ class Engine:
                 # dispatch), and a request _expire retires with its lane
                 # in the tick in flight has that token discarded at
                 # harvest — a token the request's stream never held.
-                with RecordEvent("engine.expire"):
+                with self._span("engine.expire"):
                     outputs.extend(self._expire())
                 self._pf_step_tokens = 0
-                with RecordEvent("engine.admit") as span:
-                    span.set(admitted=len(self._admit()))
+                with self._span("engine.admit") as span:
+                    log.admitted = len(self._admit())
+                    span.set(admitted=log.admitted)
                 outputs.extend(self._run_prefills())
                 if self._inflight is None:
                     # no tick in flight for the next dispatch to queue
@@ -1566,8 +1562,6 @@ class Engine:
                 self._watchdog.maybe_start_and_tick()
                 if not ahead:
                     # spec: block on THIS step's dispatch
-                    # (attributed — host work above that hid under device
-                    # execution lands in the overlap share)
                     outputs.extend(self._decode_harvest(pending))
                 # (d) page growth for the NEXT dispatch, counting the tick
                 # in flight (a preemption drains the tick in flight before
@@ -1586,7 +1580,7 @@ class Engine:
                     self._injector.record("alloc.refcount_skew")
                     self._alloc.share(
                         held[int(self._injector.rng.integers(0, len(held)))])
-            with RecordEvent("engine.bookkeeping"):
+            with self._span("engine.bookkeeping"):
                 self._maybe_audit()
                 self._mon.counter("serving.steps").increase()
                 self._publish_gauges()
@@ -1606,35 +1600,29 @@ class Engine:
                 # tick that introduced a new executable folds its compiles
                 # into warmup. (Not tracker.on_step(): its per-step list
                 # would grow one entry per tick forever in a serving process.)
-                self._compiles += self._tracker.compiles - c0
+                log.compiles = self._tracker.compiles - c0
+                self._compiles += log.compiles
                 if self._last_compile_step == self._steps:
                     self._warm_compiles = self._compiles
-                # host/device tick attribution (ROADMAP item 5's gate input):
-                # device time is what the tick spent blocked on dispatched
-                # results PLUS the host work that provably ran while the
-                # device was still executing the in-flight dispatch (the
-                # pipelining overlap — _sync_timed's windowed accounting; the
-                # overlap share is also published on its own so the gate
-                # measures real EXPOSED host cost, never double-counted).
-                # Wall clock, never the injectable clock — timelines stay
-                # deterministic, attribution stays honest.
-                wall_ms = (time.perf_counter() - wall0) * 1e3
-                dev_ms = min(self._device_s * 1e3, wall_ms)
-                host_ms = wall_ms - dev_ms
-                ov_ms = min(self._overlap_s * 1e3, dev_ms)
-                self._mon.gauge("serving.host_ms_per_tick").set(host_ms)
-                self._mon.gauge("serving.device_ms_per_tick").set(dev_ms)
-                self._mon.gauge("serving.overlap_ms_per_tick").set(ov_ms)
-                self._mon.histogram("serving.hist.host_ms_per_tick").record(
-                    host_ms)
-                self._mon.histogram("serving.hist.device_ms_per_tick").record(
-                    dev_ms)
-                self._mon.histogram("serving.hist.overlap_ms_per_tick").record(
-                    ov_ms)
-                self._mon.histogram("serving.hist.tick_ms").record(wall_ms)
                 self._steps += 1
                 self._held = []
-                return outputs
+            # host/device tick attribution falls out of the row: device
+            # time is what the step spent BLOCKED on dispatched results
+            # (its two wait spans), host time is the rest of its wall
+            # time, hidden under the tick in flight or not. Wall clock,
+            # never the injectable one: timelines stay deterministic.
+            wall_ms, dev_ms, slow = log.end(len(outputs))
+            mon = self._mon
+            mon.gauge("serving.host_ms_per_tick").set(wall_ms - dev_ms)
+            mon.gauge("serving.device_ms_per_tick").set(dev_ms)
+            mon.histogram("serving.hist.host_ms_per_tick").record(
+                wall_ms - dev_ms)
+            mon.histogram("serving.hist.device_ms_per_tick").record(dev_ms)
+            mon.histogram("serving.hist.tick_ms").record(wall_ms)
+            if slow:
+                mon.counter("serving.slow_steps").increase()
+                mon.counter("serving.slow_step_ms").increase(wall_ms)
+            return outputs
 
     def run(self, requests: Sequence, max_steps: int = 100_000,
             heartbeat_timeout: Optional[float] = None,
@@ -1653,7 +1641,9 @@ class Engine:
         ``_stall_report`` — a per-thread stack dump plus a best-effort host-state snapshot
         (to ``snapshot_path`` when given, always kept on
         ``last_stall_snapshot``) so a wedged serving process leaves a
-        recoverable trail before the pod is killed."""
+        recoverable trail before the pod is killed. It fires WHILE a
+        step hangs; where a step that returned had sat is said
+        afterwards by the step record (``step_log.slow()``)."""
         ids_list = []
         for item in requests:
             if isinstance(item, (tuple, list)) and len(item) == 2 and \
@@ -2137,6 +2127,9 @@ class Engine:
             self._waiting.popleft()
             req.slot = slot
             req.state = PREFILL
+            # of its queue time, the wait for a SLOT ends here; the rest,
+            # to its first slice, is the wait for prefill budget
+            tracing.mark_admitted(req.spans, self._clock() * 1e3)
             req.admit_seq = self._admit_counter
             self._admit_counter += 1
             self._slots[slot] = req
@@ -2167,38 +2160,13 @@ class Engine:
         tracing.open_span(req.spans, phase, t, self.label, slot=slot,
                           **detail)
 
-    def _sync_timed(self, outs, dispatch_t: Optional[float] = None,
-                    dev_mark: float = 0.0) -> None:
-        """Block until this tick's dispatched device results land,
-        charging the wait to the tick's DEVICE share (host/device
-        attribution, see step()). The immediate np.asarray consumers
-        then read ready buffers — total tick wall time is unchanged,
-        it just gets attributed.
-
-        Pipelined syncs pass ``dispatch_t`` (perf_counter when the
-        executable was dispatched) and ``dev_mark`` (the _device_s
-        reading at dispatch): when the wait actually blocked, the
-        device was provably busy for the WHOLE dispatch→ready window,
-        so the host work that ran inside it is charged to the device
-        share and surfaced as OVERLAP (never double-counted — device
-        seconds other syncs already claimed inside the window are
-        subtracted). A wait that returns immediately means the device
-        finished at an unknown point during the host work, so only the
-        measured block is charged — the conservative split that keeps
-        the host-share gate honest when the HOST is the bottleneck."""
-        t0 = time.perf_counter()
+    def _sync_timed(self, outs) -> None:
+        """Block until dispatched device results land: THE attributed
+        wait of the tick loop (the hot-path lint knows it by name),
+        called inside an `engine.decode.wait` / `engine.prefill.wait`
+        span, which is what times it. The np.asarray consumers that
+        follow read ready buffers."""
         jax.block_until_ready(outs)
-        t1 = time.perf_counter()
-        blocked = t1 - t0
-        if dispatch_t is not None:
-            window = t1 - dispatch_t
-            inner = self._device_s - dev_mark
-            extra = window - inner
-            if blocked > 5e-5 and extra > blocked:
-                self._device_s += extra
-                self._overlap_s += extra - blocked
-                return
-        self._device_s += blocked
 
     def _run_prefills(self) -> List[Output]:
         """Run this tick's prefill work over every PREFILL-state slot.
@@ -2244,6 +2212,8 @@ class Engine:
             if budget is not None:
                 left = budget - self._pf_step_tokens
                 if left <= 0 and req is not oldest:
+                    # the wait for prefill BUDGET, not for a slot
+                    self.step_log.starved += 1
                     continue
                 cap = max(self.prefill_bucket, left)
             out = self._safe_prefill(req, self._prefill, cap)
@@ -2280,7 +2250,7 @@ class Engine:
         multi-token paged path gathers the cache it just wrote), so a
         sliced prefix produces bit-identical cache contents and first
         tokens — under any cache_dtype."""
-        with RecordEvent("engine.prefill", req=req.req_id) as span:
+        with self._span("engine.prefill", req=req.req_id) as span:
             toks = req.resume_tokens()
             fresh = not req.generated
             P = len(toks)
@@ -2362,13 +2332,6 @@ class Engine:
             poison = np.asarray(
                 [float("nan") if self._fault("prefill.nan") else 0.0],
                 np.float32)
-            # windowed device attribution, same as the decode dispatches:
-            # the chunk's dispatch→ready span is device-busy even on a
-            # client whose dispatch call runs the computation inline —
-            # without the window the whole prefill forward would read as
-            # HOST time in the host-share gate
-            mark = self._device_s
-            t0 = time.perf_counter()
             tok, key2, okf, self._pools, *extras = fn(
                 self._st, self._pools, bt_dev, prompt_dev,
                 np.asarray([T], np.int32), start_dev,
@@ -2383,8 +2346,11 @@ class Engine:
                 self._spec.prefill(pb, bt_dev, prompt_dev, start_dev)
             self._prefilled.append(_PendingPrefill(
                 req=req, data=(tok, key2, okf), extras=tuple(extras),
-                toks=toks, start=start, tokens=T, fresh=fresh,
-                t_dispatch=t0, dev_mark=mark))
+                toks=toks, start=start, tokens=T, fresh=fresh))
+            log = self.step_log
+            log.chunks += 1
+            log.chunk_tokens += T
+            log.largest_bucket = max(log.largest_bucket, pb)
             if final and self._prefix is not None:
                 # register this prefix's full pages (newly computed chunks
                 # only; chunks matched at admission are already cached)
@@ -2421,7 +2387,7 @@ class Engine:
         """The host half of a chunk: the request's first token and key
         go through the host into the slot's row; a final chunk
         activates the slot for the NEXT dispatch."""
-        with RecordEvent("engine.prefill.harvest", req=req.req_id):
+        with self._span("engine.prefill.harvest", req=req.req_id):
             # key2 rides in the sync set: the fresh-request path below
             # reads it (np.asarray) and an unsynced fetch would be an
             # un-attributed host sync (hotpath.host-sync-in-tick)
@@ -2435,9 +2401,8 @@ class Engine:
             final = p.start + p.tokens >= len(p.toks)
             ok = False
             try:
-                with RecordEvent("engine.prefill.wait"):
-                    self._sync_timed(p.data, dispatch_t=p.t_dispatch,
-                                     dev_mark=p.dev_mark)
+                with self._span("engine.prefill.wait"):
+                    self._sync_timed(p.data)
                 ok = bool(np.asarray(okf)[0])
             finally:
                 if not ok and final and self._prefix is not None:
@@ -2497,7 +2462,7 @@ class Engine:
         draft/verify tick; allocate lazily, preempting the YOUNGEST
         sequence when the pool runs dry (after reclaiming idle
         prefix-cache pages; a tick in flight is drained first)."""
-        with RecordEvent("engine.ensure_pages") as span:
+        with self._span("engine.ensure_pages") as span:
             allocated = 0
             # a preemption is the only thing here that queues a request
             waiting0 = len(self._waiting)
@@ -2563,6 +2528,7 @@ class Engine:
         """Evict back to the waiting queue (front): pages freed, tokens
         and RNG chain kept — a resume prefill rebuilds the cache."""
         self._mon.counter("serving.preemptions").increase()
+        self.step_log.preempted += 1
         req.preemptions += 1
         self._open_span(req, tracing.PREEMPTED, kind="pages")
         i = req.slot
@@ -2590,8 +2556,8 @@ class Engine:
         finishes) plus the block table when a sequence crossed a page
         boundary. A steady-state decode tick — no scheduling events,
         no page growth — uploads NOTHING."""
-        with RecordEvent("engine.flush_state", rows=len(self._dirty),
-                         block_table=int(self._bt_dirty)):
+        with self._span("engine.flush_state", rows=len(self._dirty),
+                        block_table=int(self._bt_dirty)):
             if self._dirty:
                 self._dev = _merge_rows(self._dev,
                                         self._up(self._pack_rows()))
@@ -2640,8 +2606,6 @@ class Engine:
         if self._inflight is not None:
             self._mon.counter("serving.runahead.dispatches").increase()
         self._flush_state()
-        mark = self._device_s
-        t0 = time.perf_counter()
         # the fused step: forward + per-slot sampling + state advance
         # in ONE executable; only the emitted tokens (and the tiny
         # NaN-quarantine flags) come back
@@ -2650,8 +2614,8 @@ class Engine:
                 steady, fn, self._st, self._pools, self._bt_dev,
                 self._dev, self._poison_dev)
         self._unpoison()
-        return self._pending("single", (nxt, okv), lanes, t0, mark,
-                             variant, extras=tuple(extras))
+        return self._pending("single", (nxt, okv), lanes, variant,
+                             extras=tuple(extras))
 
     def _drain(self, cause: str) -> None:
         """Wait for and harvest the tick in flight, then the prefill
@@ -2671,20 +2635,17 @@ class Engine:
 
     def _decode_harvest(self, pend: Optional[_PendingTick]
                         ) -> List[Output]:
-        """Sync the in-flight dispatch (attributed: host work that ran
-        hidden under the device is booked as overlap, not
-        double-counted) and retire its tokens. Rows whose request left
-        DECODE during the overlap window (deadline expiry, cancel) are
-        skipped — their in-flight tokens are discarded, exactly what
+        """Wait for the in-flight dispatch and retire its tokens. Rows
+        whose request left DECODE while it was in flight (deadline
+        expiry, cancel) are skipped — their in-flight tokens are discarded, exactly what
         the sequential expire-before-decode order produced."""
         if pend is None:
             return []
-        with RecordEvent("engine.decode.wait"):
-            self._sync_timed(pend.data, dispatch_t=pend.t_dispatch,
-                             dev_mark=pend.dev_mark)
+        with self._span("engine.decode.wait"):
+            self._sync_timed(pend.data)
         harvest = self._harvest_spec if pend.kind == "spec" \
             else self._harvest_single
-        with RecordEvent("engine.harvest") as span:
+        with self._span("engine.harvest") as span:
             emitted = _Emitted(self._clock())
             outs = harvest(pend, emitted)
             emitted.record(self._mon)
@@ -2770,8 +2731,6 @@ class Engine:
                   and not self._dirty and not self._bt_dirty
                   and not self._poisoned)
         self._flush_state()
-        mark = self._device_s
-        t0 = time.perf_counter()
         drafts = self._spec.draft(self._bt_dev, self._dev[0],
                                   self._dev[1], self._dev[6])
         if self._fault("spec.disagree"):
@@ -2785,8 +2744,7 @@ class Engine:
             steady, fn, self._st, self._pools, self._bt_dev, self._dev,
             drafts, self._poison_dev)
         self._unpoison()
-        return self._pending("spec", (toks, acc, okv), lanes, t0, mark,
-                             variant)
+        return self._pending("spec", (toks, acc, okv), lanes, variant)
 
     def _harvest_spec(self, pend: _PendingTick,
                       emitted: _Emitted) -> List[Output]:

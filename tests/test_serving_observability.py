@@ -205,6 +205,47 @@ def test_engine_timeline_lifecycle(rng):
     eng.close()
 
 
+def test_queued_span_says_when_the_slot_was_given(rng):
+    """`QUEUED.detail.admitted_ms` is the engine-clock instant `_admit`
+    gave the request its slot: before it the request waited for a SLOT,
+    after it (to the span's end, its first slice) for prefill BUDGET."""
+    vt = [0.0]
+
+    def queued_spans(max_slots):
+        eng = Engine(_tiny_net(), max_slots=max_slots, page_size=8,
+                     pool_pages=64, max_context=64, prefill_bucket=16,
+                     max_prefill_tokens_per_step=16, clock=lambda: vt[0])
+        outs = {}
+        for p in _prompts(rng, (12, 9, 5)):
+            eng.add_request(p, SamplingParams(max_new_tokens=3))
+        while not eng.idle:
+            vt[0] += 0.010
+            outs.update((o.req_id, o) for o in eng.step())
+        eng.close()
+        queued = [outs[i].spans[0] for i in sorted(outs)]
+        for i, q in enumerate(queued):
+            assert q["phase"] == tracing.QUEUED
+            assert q["t0_ms"] <= q["detail"]["admitted_ms"] <= q["t1_ms"]
+            assert tracing.validate_timeline(outs[i].spans) == []
+        return queued, eng.step_log.rows()
+
+    # one slot: each request waits for the SLOT and is prefilled in the
+    # step that admits it
+    queued, rows = queued_spans(1)
+    assert [q["detail"]["admitted_ms"] for q in queued] == \
+        [q["t1_ms"] for q in queued]
+    assert queued[0]["t1_ms"] < queued[1]["t1_ms"] < queued[2]["t1_ms"]
+    assert not any(r["starved"] for r in rows)
+    # three slots, a budget of one bucket a step: all are admitted at
+    # once; the shortest is served, the oldest is never passed over, and
+    # the one between them waits a step for BUDGET
+    queued, rows = queued_spans(3)
+    admitted = {q["detail"]["admitted_ms"] for q in queued}
+    assert admitted == {queued[0]["t1_ms"]} == {queued[2]["t1_ms"]}
+    assert queued[1]["t1_ms"] == queued[1]["detail"]["admitted_ms"] + 10.0
+    assert [r["starved"] for r in rows][:2] == [1, 0]
+
+
 def test_engine_timeline_preemption_spans(rng):
     """A pool-pressure preemption shows up as a PREEMPTED span between
     two decode stints, and the timeline stays contiguous through the

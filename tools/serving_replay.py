@@ -1051,10 +1051,6 @@ def main(argv=None) -> int:
                                        {"last": 0.0, "mean": 0.0}),
         "device_ms_per_tick": detail.get("serving.device_ms_per_tick",
                                          {"last": 0.0, "mean": 0.0}),
-        # hidden-host attribution: host work the dispatch window absorbed
-        # (docs/OBSERVABILITY.md) — only nonzero once pipelining overlaps
-        "overlap_ms_per_tick": detail.get("serving.overlap_ms_per_tick",
-                                          {"last": 0.0, "mean": 0.0}),
         "host_share": round(host_share, 4),
     }
     # stitched per-request timelines (span logs ride the Outputs)
@@ -1210,9 +1206,7 @@ def main(argv=None) -> int:
         print(f"  host_ms_per_tick "
               f"{hd['host_ms_per_tick'].get('mean', 0.0):.3f}  "
               f"device_ms_per_tick "
-              f"{hd['device_ms_per_tick'].get('mean', 0.0):.3f}  "
-              f"overlap_ms_per_tick "
-              f"{hd['overlap_ms_per_tick'].get('mean', 0.0):.3f}   "
+              f"{hd['device_ms_per_tick'].get('mean', 0.0):.3f}   "
               f"(wall clock, mean/tick)")
         print(f"  host_share {hd['host_share']:.4f}")
         for name, st in report["histograms"].items():
