@@ -36,7 +36,10 @@ a full layer, 1088 -> 1152 on a sliding one), plus the indexer's key
 (128) in a pool of its own on full layers. A chunk of more than one
 token (prefill) gathers the rows it may attend and EXPANDS them to
 per-head keys and values, in query blocks (on a sliding layer a block
-scores only the band of rows its window can keep); a one-token step
+scores only the band of rows its window can keep; on a full layer, on
+the chip, the blocks yield their masks and ONE call of
+``kernels.mla_prefill.mla_flash_prefill`` attends them all with the
+scores kept in VMEM); a one-token step
 (decode) ABSORBS ``W_kvb`` into the query and the output and runs
 ``kernels.paged_attention.paged_mla_decode`` on the latent rows
 themselves: full layers under the indexer's mask, sliding layers over
@@ -58,6 +61,7 @@ from ...core import place
 from ...core.dispatch import unwrap, wrap
 from ...framework.param_attr import ParamAttr
 from ...incubate.distributed.models.moe import MoELayer, SigmoidTopKGate
+from ...kernels import mla_prefill
 from ...kernels import paged_attention as paged
 from ...nn import functional as F
 from ...nn.initializer import Normal
@@ -68,7 +72,6 @@ from ...nn.layer.norm import LayerNorm
 from .llama import LlamaRMSNorm
 
 FULL, SLIDING = "full_attention", "sliding_attention"
-NEG_INF = -1e30
 LANES = 128
 
 
@@ -335,10 +338,17 @@ class Dots3LatentAttention(Layer):
     def _attend_expanded(self, q_nope, q_rope, gate, idx, rows, kI_rows,
                          q_pos, k_pos, align):
         """Expanded attention of s queries over L gathered latent rows:
-        K and V are computed from the rows for every head, the scores
-        of `q_block` queries at a time. rows [b, L, row], kI_rows
+        K and V are computed from the rows for every head, the mask of
+        `q_block` queries at a time. rows [b, L, row], kI_rows
         [b, L, dI] | None, q_pos [b, s], k_pos [b, L] (absolute and
         consecutive along L). Returns [b, s, H * d_v] gated.
+
+        On a full layer, on the chip and where the shapes tile
+        (`mla_prefill_requirements`), the blocks yield only their MASK
+        (causal compare, indexer, `topk_mask`) and one call of
+        `kernels.mla_prefill.mla_flash_prefill` attends all of them, the
+        float32 scores never leaving VMEM; elsewhere each block scores
+        its keys in XLA (`mla_block_xla`).
 
         On a sliding layer a block of queries scores only the `band`
         rows its window can keep: a slice of static length that starts
@@ -347,32 +357,42 @@ class Dots3LatentAttention(Layer):
         b, s, H = q_nope.shape[:3]
         L = rows.shape[1]
         cdt = rows.dtype
-        w_k, w_v = self._w_kvb()
-        c_kv = rows[..., :self.kv_rank]
-        k_rope = rows[..., self.kv_rank:self.kv_rank + self.d_rope]
-        # one key a head: [k_nope ; k_rope], so that a block's scores are
-        # ONE matmul and one float32 [H, qb, L] array, not two and a sum
-        k = jnp.concatenate(
-            [jnp.einsum("bLc,chd->bLhd", c_kv, w_k.astype(cdt)),
-             jnp.broadcast_to(k_rope[:, :, None],
-                              k_rope.shape[:2] + (H, self.d_rope))], -1)
-        v = jnp.einsum("bLc,chd->bLhd", c_kv, w_v.astype(cdt))
         qb = self.q_block if s % self.q_block == 0 else s
         nblk = s // qb
         band = L
+        flash = False
         if self.window is not None:
             # qb + window - 1 rows from the first kept key, up to
             # align - 1 rows between it and the slice's start
             band = min(L, -(-(qb + self.window - 1) // align) * align + align)
             monitor.counter("kernels.prefill.swa_band" if band < L else
                             "kernels.prefill.swa_whole").increase()
+        else:
+            flash = place.accelerator_available() \
+                and mla_prefill.mla_prefill_requirements(
+                    s, L, self.d_nope + self.d_rope, self.d_v, cdt) is None
+            monitor.counter("kernels.prefill.mla_flash" if flash else
+                            "kernels.prefill.mla_xla").increase()
+        w_k, w_v = self._w_kvb()
+        c_kv = rows[..., :self.kv_rank]
+        k_rope = rows[..., self.kv_rank:self.kv_rank + self.d_rope]
+        # one key a head: [k_nope ; k_rope], so that a block's scores are
+        # ONE matmul; heads-major for the kernel, whose tile is [rows, d]
+        to = "bhLd" if flash else "bLhd"
+        k_nope = jnp.einsum(f"bLc,chd->{to}", c_kv, w_k.astype(cdt))
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(
+                jnp.expand_dims(k_rope, 1 if flash else 2),
+                k_nope.shape[:3] + (self.d_rope,))], -1)
+        v = jnp.einsum(f"bLc,chd->{to}", c_kv, w_v.astype(cdt))
+        q = jnp.concatenate([q_nope.astype(cdt), q_rope.astype(cdt)], -1)
 
         def split(a):                 # [b, s, ...] -> [nblk, b, qb, ...]
             return jnp.moveaxis(
                 a.reshape((b, nblk, qb) + a.shape[2:]), 1, 0)
 
         def block(args):
-            q, qp, sel = args
+            q_blk, qp, sel = args
             k_blk, v_blk, k_pos_blk = k, v, k_pos
             if band < L:
                 first = qp[:, 0] - (self.window - 1) - k_pos[:, 0]
@@ -392,24 +412,21 @@ class Dots3LatentAttention(Layer):
                 I = jnp.where(keep, I, -jnp.inf)
                 keep &= topk_mask(I.reshape(b * qb, L),
                                   self.topk).reshape(b, qb, L)
-            sc = jnp.einsum("bqhd,bLhd->bhqL", q, k_blk,
-                            preferred_element_type=jnp.float32)
-            sc = jnp.where(keep[:, None], sc * jnp.float32(self.scale),
-                           NEG_INF)
-            # softmax with the division moved behind the value matmul:
-            # every row keeps at least one key, so exp(NEG_INF - max) = 0
-            p = jnp.exp(sc - jnp.max(sc, axis=-1, keepdims=True))
-            out = jnp.einsum("bhqL,bLhd->bqhd", p.astype(cdt), v_blk,
-                             preferred_element_type=jnp.float32)
-            return out / jnp.moveaxis(jnp.sum(p, axis=-1), 1, 2)[..., None]
+            if flash:
+                return keep.astype(jnp.int8)
+            return mla_prefill.mla_block_xla(q_blk, k_blk, v_blk, keep,
+                                             self.scale)
 
         sel = None if idx is None else (split(idx[0]), split(idx[1]))
-        xs = (split(jnp.concatenate([q_nope.astype(cdt),
-                                     q_rope.astype(cdt)], -1)),
-              split(q_pos), sel)
+        xs = (None if flash else split(q), split(q_pos), sel)
         out = jax.lax.map(block, xs) if nblk > 1 else \
             block(jax.tree_util.tree_map(lambda a: a[0], xs))[None]
-        out = jnp.moveaxis(out, 0, 1).reshape(b, s, H, self.d_v)
+        out = jnp.moveaxis(out, 0, 1)
+        if flash:
+            out = mla_prefill.mla_flash_prefill(
+                jnp.moveaxis(q, 2, 1), k, v, out.reshape(b, s, L),
+                self.scale)
+        out = out.reshape(b, s, H, self.d_v)
         return (out * gate[..., None]).astype(cdt).reshape(
             b, s, H * self.d_v)
 

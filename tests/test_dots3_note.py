@@ -235,6 +235,120 @@ def test_a_sliding_block_scores_the_band_its_window_can_keep(
                                    rtol=1e-5, atol=1e-6)
 
 
+# the kernel under a full layer's prefill, at the published head widths
+# (192-wide keys, 128-wide values) and its own tiles (256 queries x 512
+# keys), two heads: starts of each batch row, chunk tokens, gathered
+# keys, the selection's size (None: L <= index_topk, plain causal)
+FLASH_CASES = {
+    "first-chunk-causal": ([0], 512, 512, None),
+    "second-chunk-selected": ([512], 512, 1024, 384),
+    "start-off-a-tile-bound": ([300], 512, 1024, 384),
+    "two-rows": ([0, 700], 256, 1024, 384),
+    "garbage-past-the-last-query": ([100], 256, 1536, None),
+    "chunk-shorter-than-a-key-tile": ([128], 128, 512, 200),
+}
+
+
+@pytest.mark.parametrize("starts,s,L,topk", FLASH_CASES.values(),
+                         ids=FLASH_CASES.keys())
+def test_mla_flash_prefill_matches_the_xla_blocks(starts, s, L, topk):
+    """`mla_flash_prefill` (interpret mode) against `mla_block_xla`, the
+    XLA formulation a block at a time, on the same q, K, V and mask: the
+    mask a full layer builds (causal from each row's start, then
+    `topk_mask` of seeded indexer scores). Rows past a row's last query
+    hold large numbers: the kernel skips their tiles or masks them, and
+    not a digit changes when they are zeros instead."""
+    from paddle_tpu.kernels import mla_prefill as mp
+    rng = np.random.default_rng(11)
+    b, H, dk, dv, scale = len(starts), 2, 192, 128, 192 ** -0.5
+    q, k, v = (jnp.asarray(rng.normal(size=(b, n, H, d)), jnp.float32)
+               for n, d in ((s, dk), (L, dk), (L, dv)))
+    q_pos = np.asarray(starts)[:, None] + np.arange(s)[None]
+    keep = jnp.asarray(np.arange(L)[None, None] <= q_pos[:, :, None])
+    if topk is not None:
+        I = jnp.where(keep, jnp.asarray(rng.normal(size=(b, s, L)),
+                                        jnp.float32), -jnp.inf)
+        keep &= topk_mask(I.reshape(b * s, L), topk).reshape(b, s, L)
+        assert int(keep.sum(-1).max()) == topk
+    live = jnp.asarray(np.arange(L)[None] <= q_pos[:, -1:])    # [b, L]
+    junk = jnp.where(live[:, :, None, None], 0.0, 1e3)
+
+    def flash(k, v):
+        return mp.mla_flash_prefill(
+            *(jnp.moveaxis(a, 2, 1) for a in (q, k, v)),
+            keep.astype(jnp.int8), scale, interpret=True)
+
+    got = flash(k + junk, v + junk)
+    bq = mp.mla_prefill_tiles(s, L, dk, dv, jnp.float32)[0]
+    want = jnp.concatenate(
+        [mp.mla_block_xla(q[:, i:i + bq], k, v, keep[:, i:i + bq], scale)
+         for i in range(0, s, bq)], 1)
+    assert got.shape == (b, s, H * dv) and got.dtype == jnp.float32
+    np.testing.assert_allclose(got.reshape(want.shape), want, rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_array_equal(
+        got, flash(jnp.where(live[:, :, None, None], k, 0.0),
+                   jnp.where(live[:, :, None, None], v, 0.0)))
+
+
+def test_mla_flash_prefill_requirements_name_what_does_not_tile():
+    from paddle_tpu.kernels import mla_prefill as mp
+    assert mp.mla_prefill_requirements(2048, 5120, 192, 128,
+                                       jnp.bfloat16) is None
+    assert mp.mla_prefill_tiles(2048, 5120, 192, 128,
+                                jnp.bfloat16)[:2] == (256, 512)
+    assert mp.mla_prefill_tiles(384, 1280, 192, 128,
+                                jnp.bfloat16)[:2] == (128, 256)
+    for shape, word in (((24, 1024, 24, 128), "queries"),
+                        ((256, 1000, 192, 128), "keys"),
+                        ((256, 1024, 24, 16), "value width"),
+                        ((256, 1 << 17, 192, 128), "VMEM")):
+        assert word in mp.mla_prefill_requirements(*shape, jnp.bfloat16)
+
+
+def test_full_layer_chunked_prefill_on_the_kernel_equals_the_whole_pass(
+        monkeypatch):
+    """A full layer whose shapes tile (values 128 wide, chunks of 128
+    tokens, pages of 128), through `forward` with its cache: two chunks
+    on `mla_flash_prefill` (interpret mode; the platform steered here,
+    as on the chip) against the cache-less pass over all the tokens in
+    XLA. The second chunk's switch takes its wider branch and the
+    indexer drops keys (index_topk 96 < 256)."""
+    import functools
+    from paddle_tpu import monitor
+    from paddle_tpu.core import place
+    from paddle_tpu.kernels import mla_prefill as mp
+    from paddle_tpu.text.models.dots3_note import (FULL,
+                                                   Dots3LatentAttention)
+    paddle.seed(3)
+    cfg = Dots3NoteConfig.tiny(v_head_dim=128, index_topk=96,
+                               prefill_query_block=64,
+                               prefill_key_block=128)
+    attn = Dots3LatentAttention(cfg, FULL)
+    n, bs_ = 256, 128
+    x = paddle.to_tensor(np.random.default_rng(4).normal(
+        size=(1, n, cfg.hidden_size)).astype("float32"))
+    flash, xla = (monitor.counter(f"kernels.prefill.mla_{name}")
+                  for name in ("flash", "xla"))
+    n_xla = xla.get()
+    want = unwrap(attn(x))
+    assert xla.get() == n_xla + 1
+    monkeypatch.setattr(place, "accelerator_available", lambda: True)
+    monkeypatch.setattr(mp, "mla_flash_prefill", functools.partial(
+        mp.mla_flash_prefill, interpret=True))
+    cache = tuple(jnp.zeros((3, bs_, w), jnp.float32)
+                  for w in attn.cache_rows()) \
+        + (jnp.asarray([[1, 2]], jnp.int32),)
+    n_flash, n_xla, got = flash.get(), xla.get(), []
+    for p0 in (0, 128):
+        out, cache = attn(x[:, p0:p0 + 128], kv_cache=cache,
+                          cache_index=jnp.asarray([p0], jnp.int32))
+        got.append(unwrap(out))
+    # one bump a branch of the switch over key lengths (128, 256), a call
+    assert (flash.get(), xla.get()) == (n_flash + 4, n_xla)
+    np.testing.assert_allclose(jnp.concatenate(got, 1), want, atol=TOL)
+
+
 def test_the_shares_add_up_to_the_uncut_layer(tiny):
     """The guide's share test: the routed parts the 4 shares give, with
     the shared expert counted once, add up to what the reference gives

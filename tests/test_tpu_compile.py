@@ -312,6 +312,58 @@ def test_sliding_layer_chunk_scores_a_band_of_its_keys(one_chip, tokens,
     assert f"bf16[1,{keys},64,256]" in text      # K, expanded once a chunk
 
 
+@pytest.mark.parametrize("keys", [2048, 4096])
+def test_full_layer_chunk_attends_in_one_flash_call(monkeypatch, one_chip,
+                                                    keys):
+    """A full layer's 2,048-token prefill chunk at the published widths
+    (128 heads of 128 + 64, blocks of 256 queries, pages of 128) through
+    a block table `keys` wide: every branch of the switch over key
+    lengths (1,024 ... `keys`) holds ONE `mla_flash_prefill` call, and no
+    float32 score block [128 heads, 256 queries, L] is left in the
+    program. (XLA wrote, read twice and re-read that block for each of
+    8 query blocks: 13% of the MXU's peak, 46% of the cell's mean chunk:
+    PERF.md section 6, PR 39.)"""
+    from paddle_tpu import monitor
+    from paddle_tpu.core import place
+    from paddle_tpu.jit.functional import functional_call, get_params
+    from paddle_tpu.nn.initializer.lazy_init import LazyGuard
+    from paddle_tpu.nn.layer.layers import param_dtype
+    from paddle_tpu.text.models.dots3_note import (FULL,
+                                                   Dots3LatentAttention,
+                                                   Dots3NoteConfig)
+    # the layer asks the platform which route to take: steer it here
+    monkeypatch.setattr(place, "accelerator_available", lambda: True)
+    with LazyGuard(), param_dtype("bfloat16"):
+        attn = Dots3LatentAttention(Dots3NoteConfig(), FULL)
+
+    def chunk_step(params, u, pos0, bt, pool, ki_pool):
+        (out, cache), _ = functional_call(
+            attn, params, {}, (u,),
+            dict(kv_cache=(pool, ki_pool, bt), cache_index=pos0))
+        return out, cache[0], cache[1]
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = ({k: struct(v.shape, v.dtype)
+             for k, v in get_params(attn).items()},
+            struct((1, 2048, 5120), jnp.bfloat16), struct((1,), jnp.int32),
+            struct((1, keys // 128), jnp.int32),
+            struct((LATENT_PAGES, 128, 640), jnp.bfloat16),
+            struct((LATENT_PAGES, 128, 128), jnp.bfloat16))
+    flash, xla = (monitor.counter(f"kernels.prefill.mla_{n}")
+                  for n in ("flash", "xla"))
+    n_flash, n_xla = flash.get(), xla.get()
+    text = jax.jit(chunk_step, donate_argnums=(4, 5)).lower(
+        *args).compile().as_text()
+    branches = keys // 1024
+    assert (flash.get(), xla.get()) == (n_flash + branches, n_xla)
+    assert text.count("tpu_custom_call") == branches
+    assert "mla_flash_prefill" in text
+    assert re.findall(r"f32\[(?:1,)?128,256,\d+\]", text) == []
+    # K and V, expanded once a branch, heads-major for the kernel
+    assert f"bf16[1,128,{keys},192]" in text
+
+
 # the linear-attention cell: 96 slots, 64 heads of 128; the GQA layer's
 # pools 96 slots x 22 pages + the scratch page, 8 KV heads, page 128
 STATE_SLOTS, KV_PAGES, KV_BT = 96, 2113, 22
