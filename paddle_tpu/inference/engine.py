@@ -325,7 +325,10 @@ def serving_model_spec(model) -> dict:
     ``max_context`` / ``vocab_size``) and optionally a ``moe`` block
     (fused-dispatch eligibility diagnostics). A decoder whose layers
     do not share one geometry gives ``cache_layers`` in place of
-    ``kv_heads`` / ``head_dim`` (see _make_spec_pools). Models WITHOUT the hook
+    ``kv_heads`` / ``head_dim``: the flat list of what it keeps, in the
+    order its forward takes ``kv_caches`` (see _make_spec_pools; a block
+    may give two entries, paged keys and values and a state). Models
+    WITHOUT the hook
     fall back to the llama-shaped config attribute read that used to
     be inlined in ``Engine.__init__`` — with a loud error naming the
     missing attributes instead of an AttributeError mid-constructor."""
@@ -333,8 +336,8 @@ def serving_model_spec(model) -> dict:
     if callable(fn):
         spec = dict(fn())
         if spec.get("kind") == "decoder":
-            # a spec that gives its cache PER LAYER ("cache_layers": one
-            # entry a layer, see _make_spec_pools) has no one
+            # a spec that lists what it keeps ("cache_layers": a flat
+            # list of entries, see _make_spec_pools) has no one
             # kv_heads x head_dim to name
             geometry = ("num_layers", "max_context") \
                 if spec.get("cache_layers") is not None else \
@@ -423,8 +426,10 @@ def _make_paged_pools(layers, rows, hkv, page_size, hd, dtype, quant):
 
 
 def _cache_kinds(spec) -> List[str]:
-    """The kind of each layer's cache: a spec with ONE geometry is "kv"
-    on every layer; a ``cache_layers`` spec says, layer by layer."""
+    """The kind of each cache the model takes, in its order: a spec with
+    ONE geometry is "kv" on every layer; a ``cache_layers`` spec says,
+    entry by entry (as many as the model keeps: nothing holds the list
+    to ``num_layers``)."""
     layers = spec.get("cache_layers")
     if layers is None:
         return ["kv"] * int(spec["num_layers"])
@@ -432,10 +437,12 @@ def _cache_kinds(spec) -> List[str]:
 
 
 def _make_spec_pools(spec, rows, page_size, dtype, quant, slots=0):
-    """What a serving spec asks the engine to hold, one tuple a layer. A
+    """What a serving spec asks the engine to hold, one tuple an entry. A
     spec with ONE geometry (``kv_heads`` x ``head_dim``: LLaMA, Mistral,
-    ERNIE-MoE) gets _make_paged_pools' (k, v[, ks, vs]) exactly. A spec
-    with ``cache_layers`` gets what each entry's ``kind`` says:
+    ERNIE-MoE) gets _make_paged_pools' (k, v[, ks, vs]) exactly, one a
+    layer. A spec with ``cache_layers`` gets what each entry's ``kind``
+    says, in the list's order (one entry a layer, or two for a block
+    that keeps paged keys and values AND a state):
 
     * ``"kv"`` (``kv_heads``, ``head_dim``): that same paged (k, v) pair
       with heads, for one layer.
@@ -646,7 +653,7 @@ class Engine:
                 "generation instead")
         self.serving_spec = spec
         self.model = model
-        # what the spec keeps, layer by layer; what this engine does not
+        # what the spec keeps, entry by entry; what this engine does not
         # yet do for a per-layer spec is refused by name, not run wrong
         self._cache_kinds = _cache_kinds(spec)
         self._per_layer = spec.get("cache_layers") is not None
@@ -1030,7 +1037,7 @@ class Engine:
         return _ceil_div(need - 1 + self._lookahead, self.page_size)
 
     def _inject_bt(self, caches, bt, slots=None, n_valid=None):
-        """Engine state -> the model's per-layer cache tuples. A paged
+        """Engine state -> the model's cache tuples, one an entry. A paged
         layer takes its pools and the block table, engine state shared
         by every layer and injected at call time: (k, v, bt[, ks, vs])
         with heads, its pools with the block table last when latent. A

@@ -64,8 +64,8 @@ from ...nn.layer.container import LayerList
 from ...nn.layer.layers import Layer, param_dtype
 from .dots3_note import (Dots3MLP, Dots3NoteForCausalLM, _init_linear,
                          _rope)
+from .gqa import gqa_attend, paged_decode_or_gather, paged_gqa
 from .llama import LlamaRMSNorm
-from .solar_open2 import gqa_attend, paged_decode_or_gather, paged_gqa
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 DENSE, SPARSE = "dense", "sparse"

@@ -508,6 +508,126 @@ def test_ring_layer_step_compiles_in_place(monkeypatch, one_chip, tokens):
         assert scores == {"384"}, scores
 
 
+# the state-space cell: 96 slots, every block a mixer state [32, 128, 256]
+# float32 and a tail of 3 x 5120 a slot BESIDE paged pools of 4 KV heads
+# (96 slots x 21 pages + the scratch page, page 128)
+SSM_SLOTS, SSM_PAGES, SSM_BT = 96, 2017, 21
+
+
+def _falcon_step(monkeypatch, one_chip, build, tokens, blocks=1):
+    """The compiled text and memory analysis of a Falcon-H1 layer's (or,
+    `blocks` > 1, the whole decoder's) cache step at the cell's sizes,
+    built as the engine's programs build it: the decode program's one
+    token a slot (`slots` None, row i is slot i) or a prefill chunk of
+    one slot. `build(config)` gives the layer and which caches it takes
+    ("kv", "state" or both)."""
+    from paddle_tpu.core import place
+    from paddle_tpu.jit.functional import functional_call, get_params
+    from paddle_tpu.nn.initializer.lazy_init import LazyGuard
+    from paddle_tpu.text.models.falcon_h1 import FalconH1Config
+    monkeypatch.setattr(place, "accelerator_available", lambda: True)
+    # the FFN and the vocabulary are cut here (no kernel, no cache of
+    # theirs is looked at); every width of the two mixers is published
+    config = FalconH1Config(num_hidden_layers=blocks, intermediate_size=256,
+                            vocab_size=512, dtype="bfloat16")
+    with LazyGuard():
+        layer, kinds = build(config)
+    b = SSM_SLOTS if tokens == 1 else 1
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    caches = {
+        "kv": ([struct((SSM_PAGES, 4, 128, 128), jnp.bfloat16)] * 2,
+               (struct((b, SSM_BT), jnp.int32),)),
+        "state": ([struct((SSM_SLOTS, 32, 128, 256), jnp.float32)]
+                  + [struct((SSM_SLOTS, 5120), jnp.bfloat16)] * 3,
+                  (None if tokens == 1 else struct((b,), jnp.int32),
+                   struct((b,), jnp.int32)))}
+    pools = [caches[k][0] for k in kinds] * blocks
+    rests = [caches[k][1] for k in kinds] * blocks
+    whole = blocks > 1
+
+    def step(params, u, pos0, rests, pools):
+        full = [tuple(p) + tuple(r) for p, r in zip(pools, rests)]
+        kw = dict(kv_caches=full) if whole else dict(kv_cache=full[0])
+        (out, cache), _ = functional_call(layer, params, {}, (u,),
+                                          dict(kw, cache_index=pos0))
+        cache = cache if whole else [cache]
+        return out, [c[:len(p)] for c, p in zip(cache, pools)]
+
+    u = struct((b, tokens), jnp.int32) if whole else \
+        struct((b, tokens, 5120), jnp.bfloat16)
+    compiled = jax.jit(step, donate_argnums=(4,)).lower(
+        {k: struct(v.shape, v.dtype) for k, v in get_params(layer).items()},
+        u, struct((b,), jnp.int32), rests, pools).compile()
+    return compiled.as_text(), compiled.memory_analysis()
+
+
+def _state_passes(text):
+    """The instructions of a compiled program that produce a block's
+    whole state array (a fusion or a copy; the TPU compiler leaves no
+    other array op outside a fusion): each is one pass over 0.4 GB."""
+    made = re.compile(rf"^\s*(?:ROOT )?%[\w.\-]+ = \(?[^=]*?"
+                      rf"f32\[{SSM_SLOTS},32,128,256\]\{{[^}}]*\}}[^=]*? "
+                      rf"(fusion|copy)\(", re.M)
+    return [m.group(1) for m in made.finditer(text)]
+
+
+@pytest.mark.parametrize("tokens", [1, 1024],
+                         ids=["one-token-96-slots", "chunk-1024"])
+def test_ssm_mixer_state_step_compiles_in_place(monkeypatch, one_chip,
+                                                tokens):
+    """The mixer's cache step at the published widths (32 heads of 128
+    on a state of 256 in 2 groups, 96 slots). One token a slot:
+    `ssd_step_arrays` becomes ONE fusion that reads the donated state
+    and writes it where it lies, `S'` and y out of the same pass (what a
+    kernel with `S` aliased would give; no Pallas call is there). A
+    prefill chunk reads and writes one slot's rows. No copy of a block's
+    whole state array (0.4 GB) in either program."""
+    from paddle_tpu.text.models.falcon_h1 import FalconH1Mixer
+    text, mem = _falcon_step(monkeypatch, one_chip,
+                             lambda c: (FalconH1Mixer(c), ["state"]), tokens)
+    assert "tpu_custom_call" not in text
+    if tokens == 1:
+        assert _state_passes(text) == ["fusion"]
+    state_copy = re.compile(
+        rf"= \w+\[{SSM_SLOTS},(32,128,256|5120)\]\{{[^}}]*\}} copy\(")
+    assert [line.strip()[:120] for line in text.splitlines()
+            if state_copy.search(line)] == []
+    assert mem.alias_size_in_bytes >= SSM_SLOTS * (32 * 128 * 256 * 4
+                                                   + 3 * 5120 * 2)
+
+
+def test_paged_decode_lowers_at_a_group_of_five(monkeypatch, one_chip):
+    """Falcon-H1's attention on the paged pools at the published 20 query
+    / 4 KV heads of 128: `paged_decode` with an ODD number of query rows
+    a KV head, the write in place."""
+    from paddle_tpu.text.models.falcon_h1 import FalconH1Attention
+    text, mem = _falcon_step(monkeypatch, one_chip,
+                             lambda c: (FalconH1Attention(c), ["kv"]), 1)
+    assert "%paged_decode" in text and "tpu_custom_call" in text
+    assert mem.alias_size_in_bytes >= 2 * SSM_PAGES * 4 * 128 * 128 * 2
+
+
+def test_ssm_decode_program_updates_every_block_in_one_pass(monkeypatch,
+                                                            one_chip):
+    """The six-block decoder's one-token step: six `paged_decode` calls
+    and six passes over a state array, one a block, in ONE program, every
+    block's pools and state taken where they lie."""
+    from paddle_tpu.text.models.falcon_h1 import FalconH1ForCausalLM
+    text, mem = _falcon_step(
+        monkeypatch, one_chip,
+        lambda c: (FalconH1ForCausalLM(c), ["kv", "state"]), 1, blocks=6)
+    calls = re.findall(r"^\s*%(\w+?)[.\d]* = .*custom-call\(.*"
+                       r"tpu_custom_call", text, re.M)
+    assert calls == ["paged_decode"] * 6
+    assert _state_passes(text) == ["fusion"] * 6
+    assert mem.alias_size_in_bytes >= 6 * (
+        SSM_SLOTS * (32 * 128 * 256 * 4 + 3 * 5120 * 2)
+        + 2 * SSM_PAGES * 4 * 128 * 128 * 2)
+
+
 # an expert layer of each expert cell at the cell's widths: hidden, expert
 # width, experts routed over, tokens (a prefill bucket, or the decode
 # program's lanes); each holds share 0 of 8 and routes top-8
