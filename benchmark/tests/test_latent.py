@@ -44,8 +44,8 @@ TINY_TRAFFIC = dict(
     reference_prompt_tokens=24, reference_new_tokens=4)
 
 
-def test_the_cell_is_the_one_the_issue_names():
-    cell = load.load_cell(CELL)
+def test_the_cell_is_the_one_the_issue_names(root=load.REPO_ROOT):
+    cell = load.load_cell(CELL, root)
     assert (cell.config_name, cell.traffic_name, cell.chips) == \
         ("dots3-note-serve-5l", "notes48", 1)
     t, c = cell.traffic, cell.config

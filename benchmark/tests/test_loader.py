@@ -13,14 +13,14 @@ from benchmark.harness import load
 from benchmark.tests import tiny
 
 
-def test_the_real_cells_load_and_name_files_that_exist():
-    bench = json.loads((load.REPO_ROOT / "BENCHMARK.json").read_text())
+def test_the_real_cells_load_and_name_files_that_exist(root=load.REPO_ROOT):
+    bench = json.loads((root / "BENCHMARK.json").read_text())
     for entry in bench["workloads"]:
-        cell = load.load_cell(entry["name"])
+        cell = load.load_cell(entry["name"], root)
         assert hasattr(load.load_runner(cell.traffic["runner"]), "run")
         assert "setup_s" in cell.end_to_end and len(cell.end_to_end) > 1
         for name in cell.per_layer:
-            metric = load.load_metric(name)
+            metric = load.load_metric(name, root)
             listed = next(m for m in bench["per_layer"] if m["name"] == name)
             assert (metric.NAME, metric.UNIT, metric.LAYER, metric.MOVES,
                     metric.SOURCE, metric.BETTER) == tuple(
@@ -29,9 +29,9 @@ def test_the_real_cells_load_and_name_files_that_exist():
             assert listed["moves"] in cell.end_to_end
 
 
-def test_run_py_names_no_cell_config_runner_or_metric():
+def test_run_py_names_no_cell_config_runner_or_metric(root=load.REPO_ROOT):
     text = (load.BENCH_DIR / "run.py").read_text()
-    bench = json.loads((load.REPO_ROOT / "BENCHMARK.json").read_text())
+    bench = json.loads((root / "BENCHMARK.json").read_text())
     names = [e["name"] for k in ("workloads", "configs", "per_layer")
              for e in bench[k]] + [w["traffic"] for w in bench["workloads"]]
     names += [p.stem for p in (load.BENCH_DIR / "runners").glob("*.py")]

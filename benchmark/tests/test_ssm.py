@@ -27,6 +27,7 @@ STEP_RECORD = {"serve.host_ms_per_step", "serve.step_ms_p99",
                "serve.slow_step_ms", "serve.backlog_lanes_p90"}
 FOUR = ["serve-mistral7b-8l-chat48", "serve-dots3-5l-notes48",
         "serve-solar2-4l-chat96", "serve-kexaone-5l-mixed128"]
+TODAY = {"train-mistral7b-4l-s4096", CELL, *FOUR}
 
 TINY_SSM = dict(
     vocab_size=96, hidden_size=64, intermediate_size=96,
@@ -52,8 +53,14 @@ TINY_TRAFFIC = dict(
     reference_prompt_tokens=24, reference_new_tokens=4)
 
 
-def test_the_cell_is_the_one_the_issue_names():
-    cell = load.load_cell(CELL)
+def now(cells):
+    """`cells` without those added after this cell: a list of today's
+    cells, in its order."""
+    return [c for c in cells if c in TODAY]
+
+
+def test_the_cell_is_the_one_the_issue_names(root=load.REPO_ROOT):
+    cell = load.load_cell(CELL, root)
     assert (cell.config_name, cell.traffic_name, cell.chips) == \
         ("falcon-h1-34b-serve-6l", "reply96", 1)
     t, c = cell.traffic, cell.config
@@ -79,38 +86,41 @@ def test_the_cell_is_the_one_the_issue_names():
     assert c["serving"] == {"weight_dtype": "bfloat16"}
 
 
-def test_the_cells_entries_in_benchmark_json():
-    bench = json.loads((load.REPO_ROOT / "BENCHMARK.json").read_text())
-    assert bench["workloads"][-1]["name"] == CELL
-    assert bench["configs"][-1]["name"] == "falcon-h1-34b-serve-6l"
-    assert bench["configs"][-1]["reduced"] == ["num_hidden_layers",
-                                               "vocab_size"]
-    cell = load.load_cell(CELL)
+def test_the_cells_entries_in_benchmark_json(root=load.REPO_ROOT):
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    assert [w["name"] for w in bench["workloads"]].count(CELL) == 1
+    config, = [c for c in bench["configs"]
+               if c["name"] == "falcon-h1-34b-serve-6l"]
+    assert config["reduced"] == ["num_hidden_layers", "vocab_size"]
+    cell = load.load_cell(CELL, root)
     assert set(cell.end_to_end) == {"serve_tokens_per_s", "setup_s"}
     by_name = {m["name"]: m for m in bench["per_layer"]}
+    names = list(by_name)
     for name in NEW_METRICS:
-        assert by_name[name]["workloads"] == [CELL]
-        assert bench["per_layer"].index(by_name[name]) >= \
-            len(bench["per_layer"]) - 3
+        assert now(by_name[name]["workloads"]) == [CELL]
+        # appended after the step record's metrics, which came before them
+        assert names.index(name) > names.index("serve.backlog_lanes_p90")
         m = load.load_metric(name)
         assert {k: by_name[name][k] for k in
                 ("unit", "better", "source", "layer", "moves")} == dict(
             unit=m.UNIT, better=m.BETTER, source=m.SOURCE, layer=m.LAYER,
             moves=m.MOVES)
-    # appended to the lists of all four serving cells but the step
-    # record's four (`test_step_record.py` holds those to exactly the four
-    # older cells; PERF.md section 7), to the state's gauge and the paged
-    # kernel's time share; to nothing else
+    # on the lists of all four older serving cells (the step record's four
+    # among them), the state's gauge and the paged kernel's time share;
+    # on nothing else
     listed = {m["name"] for m in bench["per_layer"]
               if CELL in m.get("workloads", [])}
     all_four = {m["name"] for m in bench["per_layer"]
                 if m.get("workloads", [])[:4] == FOUR}
-    assert len(all_four) == 18
-    assert listed == (all_four - STEP_RECORD) | {
+    assert len(all_four) == 18 and STEP_RECORD <= all_four
+    assert listed == all_four | {
         "serve.state_gb", "serve.gqa_decode_time_share"} | set(NEW_METRICS)
     assert listed == set(cell.per_layer)
     assert all(by_name[n]["moves"] == "serve_tokens_per_s" for n in listed)
-    assert all(by_name[n]["workloads"][-1] == CELL for n in listed)
+    # last among today's cells on each list; a cell added later may follow
+    assert all(now(by_name[n]["workloads"])[-1] == CELL for n in listed)
+    assert all(now(by_name[n]["workloads"]) == FOUR + [CELL]
+               for n in all_four)
 
 
 def test_the_configuration_keeps_every_published_width():

@@ -12,7 +12,8 @@ from benchmark.harness.load import load_metric
 METRICS = ("serve.host_ms_per_step", "serve.step_ms_p99",
            "serve.slow_step_ms", "serve.backlog_lanes_p90")
 SERVE_CELLS = ["serve-mistral7b-8l-chat48", "serve-dots3-5l-notes48",
-               "serve-solar2-4l-chat96", "serve-kexaone-5l-mixed128"]
+               "serve-solar2-4l-chat96", "serve-kexaone-5l-mixed128",
+               "serve-falconh1-6l-reply96"]
 PHASES = ("engine.decode.dispatch", "engine.decode.wait",
           "engine.prefill.wait", "engine.harvest", "engine.add_request")
 
@@ -144,14 +145,22 @@ def test_a_four_second_step_among_three_thousand(monkeypatch):
 
 
 @pytest.mark.parametrize("name", METRICS)
-def test_benchmark_json_lists_the_metric_as_its_file_has_it(name):
-    bench = json.loads((load.REPO_ROOT / "BENCHMARK.json").read_text())
+def test_benchmark_json_lists_the_metric_as_its_file_has_it(
+        name, root=load.REPO_ROOT):
+    bench = json.loads((root / "BENCHMARK.json").read_text())
     entry = next(m for m in bench["per_layer"] if m["name"] == name)
     metric = load_metric(name)
+    cells = entry.pop("workloads")
     assert entry == {
         "name": metric.NAME, "unit": metric.UNIT, "better": metric.BETTER,
         "source": metric.SOURCE, "layer": metric.LAYER,
-        "moves": metric.MOVES, "workloads": SERVE_CELLS}
+        "moves": metric.MOVES}
+    # every serving runner keeps a step record: today's serving cells,
+    # then any serving cell added later
+    serving = next(m for m in bench["end_to_end"]
+                   if m["name"] == "serve_tokens_per_s")["workloads"]
+    assert cells[:len(SERVE_CELLS)] == SERVE_CELLS
+    assert set(cells) <= set(serving)
     assert (entry["better"], entry["source"], entry["layer"],
             entry["moves"]) == ("lower", "program_span", "serving scheduler",
                                 "serve_tokens_per_s")
