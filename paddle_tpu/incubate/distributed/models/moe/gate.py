@@ -224,15 +224,97 @@ def sigmoid_topk_routing(logits, bias, top_k: int, norm_topk_prob=True,
     return idx.astype(jnp.int32), w * jnp.float32(scaling)
 
 
-class SigmoidTopKGate(BaseGate):
-    """Sigmoid scores, top-k of score + selection bias, normalised
-    weights; routes without capacity (see sigmoid_topk_routing)."""
+def group_limited_softmax_routing(logits, top_k: int, n_group: int,
+                                  topk_group: int, norm_topk_prob=False,
+                                  scaling: float = 1.0):
+    """Softmax-score routing limited to the best groups (DeepSeek-V2's
+    `group_limited_greedy`): p = softmax(logits) [N, E] in float32; the
+    E experts are `n_group` groups of consecutive ids, a group scores
+    its highest p, and only the `topk_group` best groups (ties to the
+    lower group) stay; the top_k experts of p inside them are chosen
+    (ties to the lower id) and weighted by p. As the published code:
+    with norm_topk_prob the weights are divided by their sum, else
+    multiplied by `scaling` (`routed_scaling_factor`). No capacity.
+    Returns (expert_idx [N, k] int32, weights [N, k] float32)."""
+    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    n, e = p.shape
+    best = jnp.max(p.reshape(n, n_group, e // n_group), axis=-1)
+    _, groups = jax.lax.top_k(best, topk_group)               # [N, G']
+    kept = jnp.any(groups[:, :, None] == jnp.arange(n_group)[None, None],
+                   axis=1)                                    # [N, G]
+    w, idx = jax.lax.top_k(
+        jnp.where(jnp.repeat(kept, e // n_group, axis=1), p, 0.0), top_k)
+    if norm_topk_prob:
+        w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
+    else:
+        w = w * jnp.float32(scaling)
+    return idx.astype(jnp.int32), w
 
-    def __init__(self, num_experts, top_k=8, norm_topk_prob=True,
-                 routed_scaling_factor=1.0):
+
+class CapacityFreeGate(BaseGate):
+    """A router that drops nothing: ``route(logits, bias)`` gives each
+    token's picks and their weights, and `MoELayer` takes its sorted
+    dispatch (picks sorted by expert into grouped matmuls) for it.
+    `has_bias`: the layer keeps a selection-bias buffer and passes it;
+    `n_group`: experts come in that many groups of consecutive ids, or
+    None."""
+
+    has_bias = False
+    n_group = None
+
+    def __init__(self, num_experts, top_k):
         super().__init__(num_experts, top_k, 1.0, 0.0)
-        self.norm_topk_prob = bool(norm_topk_prob)
-        self.routed_scaling_factor = float(routed_scaling_factor)
 
     def capacity(self, num_tokens: int) -> int:
         return int(num_tokens)      # nothing is ever dropped
+
+    def route(self, logits, bias):
+        """(expert_idx [N, k] int32, weights [N, k] float32) of float32
+        router logits [N, E]; `bias` [E] or None (see `has_bias`)."""
+        raise NotImplementedError
+
+
+class SigmoidTopKGate(CapacityFreeGate):
+    """Sigmoid scores, top-k of score + selection bias, normalised
+    weights; routes without capacity (see sigmoid_topk_routing)."""
+
+    has_bias = True
+
+    def __init__(self, num_experts, top_k=8, norm_topk_prob=True,
+                 routed_scaling_factor=1.0):
+        super().__init__(num_experts, top_k)
+        self.norm_topk_prob = bool(norm_topk_prob)
+        self.routed_scaling_factor = float(routed_scaling_factor)
+
+    def route(self, logits, bias):
+        from ..... import monitor
+        monitor.counter("kernels.moe.route.sigmoid_topk").increase()
+        return sigmoid_topk_routing(logits, bias, self.top_k,
+                                    self.norm_topk_prob,
+                                    self.routed_scaling_factor)
+
+
+class GroupLimitedGate(CapacityFreeGate):
+    """Softmax scores, the top-k inside the `topk_group` best of
+    `n_group` groups, weights unnormalised times the scaling factor
+    unless norm_topk_prob; no selection bias, no capacity (see
+    group_limited_softmax_routing)."""
+
+    def __init__(self, num_experts, top_k=6, n_group=8, topk_group=3,
+                 norm_topk_prob=False, routed_scaling_factor=1.0):
+        if num_experts % n_group or not 1 <= topk_group <= n_group:
+            raise ValueError(
+                f"{num_experts} experts in {n_group} groups, the best "
+                f"{topk_group} kept: the groups must be whole and "
+                f"1 <= topk_group <= n_group")
+        super().__init__(num_experts, top_k)
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
+        self.norm_topk_prob = bool(norm_topk_prob)
+        self.routed_scaling_factor = float(routed_scaling_factor)
+
+    def route(self, logits, bias):
+        from ..... import monitor
+        monitor.counter("kernels.moe.route.group_limited").increase()
+        return group_limited_softmax_routing(
+            logits, self.top_k, self.n_group, self.topk_group,
+            self.norm_topk_prob, self.routed_scaling_factor)

@@ -29,8 +29,8 @@ from .....distributed.auto_parallel import Replicate, Shard, shard_tensor
 from .....distributed.auto_parallel.process_mesh import ProcessMesh
 from .....distributed.fleet.layers.mpu.mp_ops import mark_sharding
 from .....nn.layer.layers import Layer
-from .gate import (BaseGate, GShardGate, NaiveGate, SigmoidTopKGate,
-                   SwitchGate)
+from .gate import (BaseGate, CapacityFreeGate, GShardGate, NaiveGate,
+                   SigmoidTopKGate, SwitchGate)
 
 
 # The sorted dispatch works its sorted picks off in slabs of twice the
@@ -255,10 +255,13 @@ class MoELayer(Layer):
         gate="sigmoid_topk" (or a SigmoidTopKGate): sigmoid scores, the
             top_k of score + `e_score_correction_bias` (a buffer, zero
             until something sets it), weights normalised over the picks.
-            It has no capacity, so it takes its own dispatch whatever
-            dispatch_mode says: picks sorted by expert into one
-            ragged grouped matmul (`kernels.moe.grouped_ffn_gated`),
-            nothing dropped, in training and serving alike.
+            It and every other `CapacityFreeGate` (`GroupLimitedGate`:
+            softmax scores, group-limited greedy) route through
+            ``gate.route`` and have no capacity, so they take their own
+            dispatch whatever dispatch_mode says: picks sorted by expert
+            into one ragged grouped matmul
+            (`kernels.moe.grouped_ffn_gated`), nothing dropped, in
+            training and serving alike.
         activation: "gelu" | "relu" (two matrices and biases) or
             "swiglu" (gated, three matrices, no biases; sorted dispatch
             only).
@@ -267,7 +270,9 @@ class MoELayer(Layer):
             (one chip's share of an expert-parallel layer). The router
             keeps its E outputs and its top_k; a pick that falls on an
             expert held elsewhere adds nothing here, and nothing stands
-            in for it. Sorted dispatch only.
+            in for it. Sorted dispatch only. With a gate whose experts
+            come in groups (`n_group`), the experts held must be whole
+            groups.
         shared_experts: a Layer applied to every token and added to the
             routed result (what every chip of the layer computes alike).
 
@@ -312,7 +317,7 @@ class MoELayer(Layer):
                 "'naive', 'sigmoid_topk', or a BaseGate instance")
         if top_k is not None:
             self.gate.top_k = top_k
-        self._sorted = isinstance(self.gate, SigmoidTopKGate)
+        self._sorted = isinstance(self.gate, CapacityFreeGate)
         index, of = expert_share or (0, 1)
         if of < 1 or num_experts % of or not 0 <= index < of:
             raise ValueError(
@@ -321,7 +326,13 @@ class MoELayer(Layer):
         if not self._sorted and (of > 1 or activation == "swiglu"):
             raise ValueError(
                 "expert_share and activation='swiglu' need the capacity-"
-                "free sorted dispatch: pass gate='sigmoid_topk'")
+                "free sorted dispatch: pass gate='sigmoid_topk' or a "
+                "CapacityFreeGate")
+        groups = getattr(self.gate, "n_group", None)
+        if groups and (num_experts // of) % (num_experts // groups):
+            raise ValueError(
+                f"expert_share=({index}, {of}) holds {num_experts // of} "
+                f"experts: not whole groups of {num_experts // groups}")
         self.expert_share = (int(index), int(of))
         self.num_held = num_experts // of
         self.first_held = index * self.num_held
@@ -331,17 +342,21 @@ class MoELayer(Layer):
         if self._sorted:
             if getattr(self.experts, "_act", None) != "swiglu":
                 raise ValueError(
-                    "gate='sigmoid_topk' dispatches to gated experts "
+                    "a capacity-free gate dispatches to gated experts "
                     "only: pass activation='swiglu'")
-            self.register_buffer("e_score_correction_bias", wrap(
-                jnp.zeros((num_experts,), jnp.float32)))
+            if self.gate.has_bias:
+                self.register_buffer("e_score_correction_bias", wrap(
+                    jnp.zeros((num_experts,), jnp.float32)))
         self.shared_experts = shared_experts
         self._ep_axis = ep_axis
         self.l_aux = None
         # [picks held here, picks made, held experts touched, slabs run]
         # of the last sorted forward: traced values inside a compiled
-        # step, which the serving engine returns with the tick
+        # step, which the serving engine returns with the tick; and, for
+        # a gate with groups, [groups the live tokens' picks reached,
+        # live tokens]
         self.last_stats = None
+        self.last_groups = None
 
     def _n_groups(self, n):
         return _n_groups_cached(n, self._group_size)
@@ -561,16 +576,16 @@ class MoELayer(Layer):
                                      token_mask=mask, cap=n)
 
     def _forward_sorted(self, tokens, orig_shape, token_mask=None):
-        """Capacity-free dispatch for the sigmoid top-k gate, over the
-        rows this chip holds. Every (token, pick) is sorted by the held
-        expert it fell on; picks on experts held elsewhere, and the
-        picks of dead tokens (``token_mask`` False), sort past the last
-        group. The sorted order is then worked off in SLABS of
-        ``_slab_rows`` rows, as many as the held picks fill: a slab
-        gathers its tokens, runs the gated experts as grouped matmuls
-        (kernels.moe.grouped_ffn_gated) with the part of each expert's
-        group that lies in it, weights its rows in float32 and adds
-        them onto their tokens. No array of the routed path has
+        """Capacity-free dispatch for a `CapacityFreeGate` (its
+        ``route``), over the rows this chip holds. Every (token, pick)
+        is sorted by the held expert it fell on; picks on experts held
+        elsewhere, and the picks of dead tokens (``token_mask`` False),
+        sort past the last group. The sorted order is then worked off in
+        SLABS of ``_slab_rows`` rows, as many as the held picks fill: a
+        slab gathers its tokens, runs the gated experts as grouped
+        matmuls (kernels.moe.grouped_ffn_gated) with the part of each
+        expert's group that lies in it, weights its rows in float32 and
+        adds them onto their tokens. No array of the routed path has
         N * top_k rows, nothing is dropped and there is no capacity: a
         router that sends every pick here runs every slab of the order
         (about ``of / 2``), a uniform one runs one.
@@ -589,7 +604,6 @@ class MoELayer(Layer):
         (the whole order, at the whole order's cost)."""
         from ..... import monitor
         from .....kernels.moe import grouped_ffn_gated
-        from .gate import sigmoid_topk_routing
         gate, ex = self.gate, self.experts
         k, lo, held_n = gate.top_k, self.first_held, self.num_held
         m = int(tokens.shape[0]) * k
@@ -599,15 +613,17 @@ class MoELayer(Layer):
         monitor.counter("kernels.moe.sorted.whole" if s == m
                         else "kernels.moe.sorted.slab").increase()
 
-        def fn(tok, wr, bias, w1, w3, w2, *rest):
+        groups = gate.n_group
+
+        def fn(tok, wr, w1, w3, w2, *rest):
             n = tok.shape[0]
+            rest = list(rest)
+            bias = rest.pop(0) if gate.has_bias else None
             live = (rest[0].reshape(-1).astype(bool) if rest
                     else jnp.ones((n,), bool))
             logits = jnp.dot(tok, wr.astype(tok.dtype),
                              preferred_element_type=jnp.float32)
-            idx, w = sigmoid_topk_routing(
-                logits, bias, k, gate.norm_topk_prob,
-                gate.routed_scaling_factor)
+            idx, w = gate.route(logits, bias)
             local = idx - lo
             held = (local >= 0) & (local < held_n) & live[:, None]
             key = jnp.where(held, local, held_n).reshape(-1)
@@ -648,13 +664,24 @@ class MoELayer(Layer):
             stats = jnp.stack([jnp.sum(held), jnp.sum(live) * k,
                                jnp.sum(counts > 0),
                                jnp.asarray(trips)]).astype(jnp.int32)
-            return out.astype(tok.dtype), stats
+            if not groups:
+                return out.astype(tok.dtype), stats
+            # the groups a live token's picks fall in: the chips of the
+            # stated deployment its dispatch reaches
+            reached = jnp.any(
+                (idx // (self.num_experts // groups))[:, :, None]
+                == jnp.arange(groups)[None, None], axis=1)
+            return out.astype(tok.dtype), stats, jnp.stack(
+                [jnp.sum(reached & live[:, None]),
+                 jnp.sum(live)]).astype(jnp.int32)
 
-        args = [tokens, self.gate_weight, self.e_score_correction_bias,
-                ex.w1, ex.w3, ex.w2]
+        args = [tokens, self.gate_weight, ex.w1, ex.w3, ex.w2]
+        if gate.has_bias:
+            args.append(self.e_score_correction_bias)
         if token_mask is not None:
             args.append(token_mask)
-        out, self.last_stats = run_op("moe_sorted_ffn", fn, args)
+        out, self.last_stats, *rest = run_op("moe_sorted_ffn", fn, args)
+        self.last_groups = rest[0] if rest else None
         out = out.reshape(orig_shape)
         if self.shared_experts is not None:
             out = out + self.shared_experts(tokens).reshape(orig_shape)
@@ -745,3 +772,27 @@ class MoELayer(Layer):
         out = run_op("moe_combine", moe_combine_einsum,
                      [expert_out, combine])
         return out.reshape(orig_shape)
+
+
+def moe_tick_stats(layers) -> dict:
+    """What the expert `layers` counted in their last sorted forward,
+    summed over them, by name (a serving model's ``tick_stats``; traced
+    int32 scalars inside a compiled step): ``serving.moe.picks_held``,
+    ``.picks_total``, ``.experts_touched``, ``.slabs``, ``.layer_ticks``
+    (the layers), and for gates with groups ``.groups_reached`` and
+    ``.tokens_routed``."""
+    stats = [unwrap(m.last_stats) for m in layers
+             if m.last_stats is not None]
+    total = sum(stats[1:], stats[0])
+    out = {"serving.moe.picks_held": total[0],
+           "serving.moe.picks_total": total[1],
+           "serving.moe.experts_touched": total[2],
+           "serving.moe.layer_ticks": jnp.asarray(len(stats), jnp.int32),
+           "serving.moe.slabs": total[3]}
+    groups = [unwrap(m.last_groups) for m in layers
+              if m.last_groups is not None]
+    if groups:
+        reached = sum(groups[1:], groups[0])
+        out["serving.moe.groups_reached"] = reached[0]
+        out["serving.moe.tokens_routed"] = reached[1]
+    return out

@@ -1062,13 +1062,14 @@ class Engine:
     def _tick_extras(self, cur, stats=True):
         """What a decode or prefill program returns beyond today's
         outputs, as one tuple (empty for a model and an engine that ask
-        for neither, so their programs are unchanged): the model's
-        ``tick_stats`` vector (decode ticks only), read off the model
-        right after its forward inside the same trace, and the logits
-        `cur` when keep_logits."""
+        for neither, so their programs are unchanged): the counts the
+        model's ``tick_stats`` names, by name (decode ticks only), read
+        off the model right after its forward inside the same trace, and
+        the logits `cur` when keep_logits."""
         extras = ()
         if stats and self._tick_stats:
-            extras += (self.model.serving_tick_stats(),)
+            got = self.model.serving_tick_stats()
+            extras += ({name: got[name] for name in self._tick_stats},)
         if self.keep_logits:
             extras += (cur,)
         return extras
@@ -1079,8 +1080,7 @@ class Engine:
         hand back the logits array, still on the device, or None."""
         extras = list(extras)
         if stats and self._tick_stats:
-            for name, v in zip(self._tick_stats,
-                               np.asarray(extras.pop(0))):
+            for name, v in jax.device_get(extras.pop(0)).items():
                 self._mon.counter(name).increase(int(v))
         return extras.pop(0) if self.keep_logits else None
 

@@ -1,4 +1,5 @@
 from .bert import BertConfig, BertForPretraining, BertModel  # noqa: F401
+from .deepseek_v2 import DeepseekV2Config, DeepseekV2ForCausalLM  # noqa: F401
 from .dots3_note import Dots3NoteConfig, Dots3NoteForCausalLM  # noqa: F401
 from .ernie_moe import ErnieMoEConfig, ErnieMoEForCausalLM  # noqa: F401
 from .falcon_h1 import FalconH1Config, FalconH1ForCausalLM  # noqa: F401

@@ -67,7 +67,7 @@ from ...nn.initializer import Constant, Normal
 from ...nn.layer.common import Embedding
 from ...nn.layer.container import LayerList
 from ...nn.layer.layers import Layer, param_dtype
-from .dots3_note import _init_linear, _rope
+from .mla import init_linear, rope
 from .gqa import gqa_attend, paged_gqa
 from .llama import LlamaRMSNorm
 from .solar_open2 import _Drawn, _inverse_softplus, _log_uniform
@@ -167,7 +167,7 @@ def _mup_linear(n_in, n_out, multiplier, gain=1.0):
     """A bias-free Linear drawn N(0, 1 / n_in) over `multiplier` (a
     scalar, or one value an output column): unit scale out of unit scale
     in once the multipliers have acted."""
-    return _init_linear(n_in, n_out, gain / (
+    return init_linear(n_in, n_out, gain / (
         math.sqrt(n_in) * np.asarray(multiplier, np.float32)))
 
 
@@ -205,7 +205,7 @@ class FalconH1Attention(Layer):
             jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(
                 unwrap(cache_index), jnp.int32)), (b,))
         pos = pos0[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
-        q, k = _rope(q, pos, self.theta), _rope(k, pos, self.theta)
+        q, k = rope(q, pos, self.theta), rope(k, pos, self.theta)
         new_cache = None
         if kv_cache is None:
             out = gqa_attend(q, k, v, pos, pos, self.q_block)

@@ -62,8 +62,8 @@ from ...nn.initializer import Normal
 from ...nn.layer.common import Embedding
 from ...nn.layer.container import LayerList
 from ...nn.layer.layers import Layer, param_dtype
-from .dots3_note import (Dots3MLP, Dots3NoteForCausalLM, _init_linear,
-                         _rope)
+from .latent_decoder import LatentDecoderForCausalLM, SwiGLU
+from .mla import init_linear, rope
 from .gqa import gqa_attend, paged_decode_or_gather, paged_gqa
 from .llama import LlamaRMSNorm
 
@@ -238,14 +238,14 @@ class KExaoneAttention(Layer):
         self.theta = float(c.rope_parameters["rope_theta"])
         self.q_block = int(c.prefill_query_block)
         std = c.initializer_range
-        self.q_proj = _init_linear(c.hidden_size, self.heads * self.d, std)
-        self.k_proj = _init_linear(c.hidden_size, self.kv_heads * self.d,
+        self.q_proj = init_linear(c.hidden_size, self.heads * self.d, std)
+        self.k_proj = init_linear(c.hidden_size, self.kv_heads * self.d,
                                    std)
-        self.v_proj = _init_linear(c.hidden_size, self.kv_heads * self.d,
+        self.v_proj = init_linear(c.hidden_size, self.kv_heads * self.d,
                                    std)
         self.q_norm = LlamaRMSNorm(self.d, c.rms_norm_eps)
         self.k_norm = LlamaRMSNorm(self.d, c.rms_norm_eps)
-        self.o_proj = _init_linear(self.heads * self.d, c.hidden_size, std)
+        self.o_proj = init_linear(self.heads * self.d, c.hidden_size, std)
 
     def ring_arrays(self):
         """What a slot keeps of a sliding layer: name -> (shape, dtype),
@@ -267,7 +267,7 @@ class KExaoneAttention(Layer):
         pos = pos0[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
         new_cache = None
         if self.window is not None:
-            q, k = _rope(q, pos, self.theta), _rope(k, pos, self.theta)
+            q, k = rope(q, pos, self.theta), rope(k, pos, self.theta)
         if kv_cache is not None and self.window is not None:
             out, new_cache = ring_gqa(q, k, v, kv_cache, pos0,
                                       self.q_block, self.window)
@@ -300,7 +300,7 @@ class KExaoneDecoderLayer(Layer):
         self.post_attention_layernorm = LlamaRMSNorm(c.hidden_size,
                                                      c.rms_norm_eps)
         if self.is_moe:
-            shared = Dots3MLP(
+            shared = SwiGLU(
                 c.hidden_size,
                 c.moe_intermediate_size * c.num_shared_experts,
                 c.initializer_range) if c.num_shared_experts else None
@@ -313,7 +313,7 @@ class KExaoneDecoderLayer(Layer):
                 activation="swiglu", expert_share=c.expert_share,
                 shared_experts=shared)
         else:
-            self.mlp = Dots3MLP(c.hidden_size, c.intermediate_size,
+            self.mlp = SwiGLU(c.hidden_size, c.intermediate_size,
                                 c.initializer_range)
 
     def forward(self, x, kv_cache=None, cache_index=None, token_mask=None):
@@ -342,7 +342,7 @@ class KExaoneMTP(Layer):
         c = config
         self.hnorm = LlamaRMSNorm(c.hidden_size, c.rms_norm_eps)
         self.enorm = LlamaRMSNorm(c.hidden_size, c.rms_norm_eps)
-        self.eh_proj = _init_linear(2 * c.hidden_size, c.hidden_size,
+        self.eh_proj = init_linear(2 * c.hidden_size, c.hidden_size,
                                     c.initializer_range)
         self.layer = KExaoneDecoderLayer(c, c.mtp_layer_types[0], SPARSE)
         self.norm = LlamaRMSNorm(c.hidden_size, c.rms_norm_eps)
@@ -373,7 +373,7 @@ class KExaoneForCausalLM(Layer):
                 KExaoneDecoderLayer(c, c.layer_types[i], c.mlp_layer_types[i])
                 for i in range(c.num_hidden_layers)])
             self.norm = LlamaRMSNorm(c.hidden_size, c.rms_norm_eps)
-            self.lm_head = _init_linear(c.hidden_size, c.vocab_size,
+            self.lm_head = init_linear(c.hidden_size, c.vocab_size,
                                         c.initializer_range)
             if c.num_nextn_predict_layers:
                 self.mtp = KExaoneMTP(c)
@@ -408,14 +408,14 @@ class KExaoneForCausalLM(Layer):
         e = unwrap(self.embed_tokens(input_ids))[:, 1:]
         return self.lm_head(self.mtp(wrap(h), wrap(e)))
 
-    num_params = Dots3NoteForCausalLM.num_params
+    num_params = LatentDecoderForCausalLM.num_params
 
     def serving_spec(self):
         """The engine's probe. ``cache_layers``: a full layer's paged
         pools with heads (``kv``), a sliding layer's rings by slot
         (``state``; ``window`` says that they slide); ``window`` is what
         the engine's ``win_tokens`` span argument is computed from;
-        ``tick_stats`` as Dots3NoteForCausalLM's."""
+        ``tick_stats`` as the latent decoder's."""
         c = self.config
         return {
             "kind": "decoder",
@@ -442,5 +442,5 @@ class KExaoneForCausalLM(Layer):
                     "dispatch_mode": "ragged"},
         }
 
-    # [5] int32 in ``tick_stats``' order, summed over the expert layers
-    serving_tick_stats = Dots3NoteForCausalLM.serving_tick_stats
+    # the expert layers' counts by name, as the latent decoder's
+    serving_tick_stats = LatentDecoderForCausalLM.serving_tick_stats

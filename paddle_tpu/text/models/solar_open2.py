@@ -55,13 +55,15 @@ from ...core import place
 from ...core import random as random_mod
 from ...core.dispatch import unwrap, wrap
 from ...framework.param_attr import ParamAttr
-from ...incubate.distributed.models.moe import MoELayer, SigmoidTopKGate
+from ...incubate.distributed.models.moe import (MoELayer, SigmoidTopKGate,
+                                                moe_tick_stats)
 from ...kernels import kda
 from ...nn.initializer import Constant, Initializer, Normal
 from ...nn.layer.common import Embedding
 from ...nn.layer.container import LayerList
 from ...nn.layer.layers import Layer, param_dtype
-from .dots3_note import Dots3MLP, _init_linear
+from .latent_decoder import SwiGLU
+from .mla import init_linear
 from .gqa import gqa_attend, paged_gqa
 from .llama import LlamaRMSNorm
 
@@ -185,20 +187,20 @@ class SolarKDAttention(Layer):
         self.eps = c.rms_norm_eps
         self.beta_scale = 2.0 if c.kda_allow_neg_eigval else 1.0
         std = c.initializer_range
-        self.q_proj = _init_linear(self.hidden, H * d, std)
-        self.k_proj = _init_linear(self.hidden, H * d, std)
-        self.v_proj = _init_linear(self.hidden, H * d, std)
+        self.q_proj = init_linear(self.hidden, H * d, std)
+        self.k_proj = init_linear(self.hidden, H * d, std)
+        self.v_proj = init_linear(self.hidden, H * d, std)
         # a tap of each channel's own convolution: [taps, q ; k ; v]
         bound = 1.0 / math.sqrt(self.taps)
         self.conv_weight = self.create_parameter(
             [self.taps, 3 * H * d], default_initializer=_Drawn(
                 lambda u: (2.0 * u - 1.0) * bound))
         # the two low-rank pairs (kda_use_full_proj false), rank = d
-        self.f_a_proj = _init_linear(self.hidden, d, std)
-        self.f_b_proj = _init_linear(d, H * d, std)
-        self.g_a_proj = _init_linear(self.hidden, d, std)
-        self.g_b_proj = _init_linear(d, H * d, std)
-        self.b_proj = _init_linear(self.hidden, H, std)
+        self.f_a_proj = init_linear(self.hidden, d, std)
+        self.f_b_proj = init_linear(d, H * d, std)
+        self.g_a_proj = init_linear(self.hidden, d, std)
+        self.g_b_proj = init_linear(d, H * d, std)
+        self.b_proj = init_linear(self.hidden, H, std)
         self.A_log = self.create_parameter(
             [H], default_initializer=_Drawn(
                 lambda u: jnp.log(1.0 + 15.0 * u)))
@@ -207,7 +209,7 @@ class SolarKDAttention(Layer):
                 lambda u: _inverse_softplus(_log_uniform(u, 1e-3, 1e-1))))
         self.o_norm_weight = self.create_parameter(
             [d], default_initializer=Constant(1.0))
-        self.o_proj = _init_linear(H * d, self.hidden, std)
+        self.o_proj = init_linear(H * d, self.hidden, std)
 
     def state_arrays(self, dtype):
         """What a slot keeps of this layer: name -> (shape, dtype). The
@@ -335,14 +337,14 @@ class SolarGQAttention(Layer):
         self.d = c.head_dim
         self.q_block = int(c.prefill_query_block)
         std = c.initializer_range
-        self.q_proj = _init_linear(c.hidden_size, self.heads * self.d, std)
-        self.k_proj = _init_linear(c.hidden_size, self.kv_heads * self.d,
+        self.q_proj = init_linear(c.hidden_size, self.heads * self.d, std)
+        self.k_proj = init_linear(c.hidden_size, self.kv_heads * self.d,
                                    std)
-        self.v_proj = _init_linear(c.hidden_size, self.kv_heads * self.d,
+        self.v_proj = init_linear(c.hidden_size, self.kv_heads * self.d,
                                    std)
-        self.gate_proj = _init_linear(c.hidden_size, self.heads * self.d,
+        self.gate_proj = init_linear(c.hidden_size, self.heads * self.d,
                                       std)
-        self.o_proj = _init_linear(self.heads * self.d, c.hidden_size, std)
+        self.o_proj = init_linear(self.heads * self.d, c.hidden_size, std)
 
     def forward(self, u, kv_cache=None, cache_index=None):
         b, s = u.shape[0], u.shape[1]
@@ -374,7 +376,7 @@ class SolarDecoderLayer(Layer):
             else SolarKDAttention(c)
         self.post_attention_layernorm = LlamaRMSNorm(c.hidden_size,
                                                      c.rms_norm_eps)
-        shared = Dots3MLP(
+        shared = SwiGLU(
             c.hidden_size, c.moe_intermediate_size * c.n_shared_experts,
             c.initializer_range) if c.n_shared_experts else None
         self.mlp = MoELayer(
@@ -420,7 +422,7 @@ class SolarOpen2ForCausalLM(Layer):
             self.layers = LayerList([SolarDecoderLayer(c, i)
                                      for i in range(c.num_hidden_layers)])
             self.norm = LlamaRMSNorm(c.hidden_size, c.rms_norm_eps)
-            self.lm_head = _init_linear(c.hidden_size, c.vocab_size,
+            self.lm_head = init_linear(c.hidden_size, c.vocab_size,
                                         c.initializer_range)
 
     def forward(self, input_ids, kv_caches=None, cache_index=None):
@@ -449,7 +451,7 @@ class SolarOpen2ForCausalLM(Layer):
         """The engine's probe. ``cache_layers``: a GQA layer's paged
         pools with heads (``kv``), a KDA layer's per-slot arrays
         (``state``: name -> (shape a slot, dtype), in the order the
-        layer takes them); ``tick_stats`` as Dots3NoteForCausalLM's."""
+        layer takes them); ``tick_stats`` as the latent decoder's."""
         c = self.config
         dtype = unwrap(self.lm_head.weight).dtype
         return {
@@ -477,10 +479,5 @@ class SolarOpen2ForCausalLM(Layer):
         }
 
     def serving_tick_stats(self):
-        """[5] int32, in ``tick_stats``' order (see Dots3NoteForCausalLM)."""
-        stats = [unwrap(lyr.mlp.last_stats) for lyr in self.layers
-                 if lyr.mlp.last_stats is not None]
-        total = sum(stats[1:], stats[0])
-        return jnp.concatenate([total[:3],
-                                jnp.asarray([len(stats)], jnp.int32),
-                                total[3:]])
+        """As the latent decoder's: every layer's expert counts."""
+        return moe_tick_stats([lyr.mlp for lyr in self.layers])

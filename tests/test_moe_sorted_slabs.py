@@ -210,8 +210,9 @@ def test_slab_rows(m, of, rows):
     (Dots3NoteForCausalLM, Dots3NoteConfig),
     (SolarOpen2ForCausalLM, SolarOpen2Config)], ids=["dots3", "solar2"])
 def test_models_report_the_slabs(make, config):
-    """`serving.moe.slabs` comes last in `tick_stats`, after the four
-    names the benchmark's metrics read, and adds up the layers' slabs."""
+    """`serving.moe.slabs` is in `tick_stats` beside the four names the
+    benchmark's metrics read, and adds up the layers' slabs; the model
+    reports each name as the engine reads it, by name."""
     paddle.seed(3)
     net = make(config.tiny(expert_share=(0, 4)))
     net.eval()
@@ -223,10 +224,12 @@ def test_models_report_the_slabs(make, config):
     layers = [lyr.mlp for lyr in net.layers
               if isinstance(lyr.mlp, MoELayer)]
     per_layer = np.stack([np.asarray(unwrap(m.last_stats)) for m in layers])
-    got = np.asarray(net.serving_tick_stats())
-    assert got.shape == (5,) and got[3] == len(layers)
-    assert (got[:3] == per_layer[:, :3].sum(axis=0)).all()
-    assert got[4] == per_layer[:, 3].sum() >= 1
+    got = {n: int(v) for n, v in net.serving_tick_stats().items()}
+    assert set(got) == set(names)
+    assert got["serving.moe.layer_ticks"] == len(layers)
+    assert [got[n] for n in names[:3]] == \
+        per_layer[:, :3].sum(axis=0).tolist()
+    assert got["serving.moe.slabs"] == per_layer[:, 3].sum() >= 1
 
 
 def test_engine_counts_slabs_with_the_ticks():

@@ -628,6 +628,89 @@ def test_ssm_decode_program_updates_every_block_in_one_pass(monkeypatch,
         + 2 * SSM_PAGES * 4 * 128 * 128 * 2)
 
 
+# the full-context latent cell (benchmark/configs/deepseek-v2-serve-5l.json):
+# 64 slots x 104 pages + the scratch page, page 128, one 640-wide row a
+# token a layer
+DSV2_SLOTS, DSV2_PAGES, DSV2_BT = 64, 6657, 104
+
+
+def _dsv2_step(monkeypatch, one_chip, build, b, tokens):
+    """The compiled text and memory analysis of DeepSeek-V2's cache step
+    at the cell's sizes: `build(config)` gives a latent layer or the
+    whole decoder (every layer's pool then), the decode program's one
+    token a slot or a prefill chunk of one slot."""
+    from paddle_tpu.core import place
+    from paddle_tpu.jit.functional import functional_call, get_params
+    from paddle_tpu.nn.initializer.lazy_init import LazyGuard
+    from paddle_tpu.text.models import DeepseekV2Config
+    monkeypatch.setattr(place, "accelerator_available", lambda: True)
+    # the dense FFN and the vocabulary are cut here (no kernel, no cache
+    # of theirs is looked at); every width of attention and experts is
+    # published, and 20 of 160 experts held
+    config = DeepseekV2Config(num_hidden_layers=5, intermediate_size=256,
+                              vocab_size=512, expert_share=(0, 8),
+                              dtype="bfloat16")
+    with LazyGuard():
+        layer = build(config)
+    whole = not hasattr(layer, "cache_rows")
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pools = [struct((DSV2_PAGES, 128, 640), jnp.bfloat16)] * (
+        5 if whole else 1)
+
+    def step(params, u, pos0, bt, pools):
+        caches = [(p, bt) for p in pools]
+        kw = dict(kv_caches=caches) if whole else dict(kv_cache=caches[0])
+        (out, cache), _ = functional_call(layer, params, {}, (u,),
+                                          dict(kw, cache_index=pos0))
+        return out, [c[0] for c in (cache if whole else [cache])]
+
+    u = struct((b, tokens), jnp.int32) if whole else \
+        struct((b, tokens, 5120), jnp.bfloat16)
+    compiled = jax.jit(step, donate_argnums=(4,)).lower(
+        {k: struct(v.shape, v.dtype) for k, v in get_params(layer).items()},
+        u, struct((b,), jnp.int32), struct((b, DSV2_BT), jnp.int32),
+        pools).compile()
+    return compiled.as_text(), compiled.memory_analysis()
+
+
+def test_full_context_decode_program_reads_every_row(monkeypatch, one_chip):
+    """The five-layer decoder's one-token step at 64 slots of 104 pages:
+    five `paged_mla_decode` calls with no keep-mask (no [64, 1, 13312]
+    operand: every row of the context is read), the expert layers on
+    `moe_gmm`, every layer's pool written where it lies."""
+    from paddle_tpu.text.models import DeepseekV2ForCausalLM
+    text, mem = _dsv2_step(monkeypatch, one_chip, DeepseekV2ForCausalLM,
+                           DSV2_SLOTS, 1)
+    calls = re.findall(r"^\s*%(\w+?)[.\d]* = .*custom-call\(.*"
+                       r"tpu_custom_call", text, re.M)
+    assert calls.count("paged_mla_decode") == 5
+    assert set(calls) == {"paged_mla_decode", "moe_gmm"}
+    assert f"s32[{DSV2_SLOTS},1,{DSV2_BT * 128}]" not in text
+    assert mem.alias_size_in_bytes >= 5 * DSV2_PAGES * 128 * 640 * 2
+
+
+def test_full_context_chunk_attends_in_one_flash_call(monkeypatch,
+                                                      one_chip):
+    """A DeepSeek-V2 latent layer's 2,048-token prefill chunk through a
+    block table of 104 pages: every branch of the switch over key lengths
+    (1,024 ... 13,312) holds ONE `mla_flash_prefill` call under a plain
+    causal mask, no float32 score block [128 heads, 256 queries, L] is
+    left in the program, and K is expanded once a branch, heads-major."""
+    from paddle_tpu import monitor
+    from paddle_tpu.text.models.deepseek_v2 import latent_attention
+    flash, xla = (monitor.counter(f"kernels.prefill.mla_{n}")
+                  for n in ("flash", "xla"))
+    n_flash, n_xla = flash.get(), xla.get()
+    text, _ = _dsv2_step(monkeypatch, one_chip, latent_attention, 1, 2048)
+    assert (flash.get(), xla.get()) == (n_flash + 13, n_xla)
+    assert text.count("tpu_custom_call") == 13
+    assert re.findall(r"f32\[(?:1,)?128,256,\d+\]", text) == []
+    assert "bf16[1,128,13312,192]" in text
+
+
 # an expert layer of each expert cell at the cell's widths: hidden, expert
 # width, experts routed over, tokens (a prefill bucket, or the decode
 # program's lanes); each holds share 0 of 8 and routes top-8
