@@ -20,6 +20,7 @@ GQA/MQA: caches carry ``h_kv`` heads; query heads map to kv head
 """
 from __future__ import annotations
 
+import functools
 import logging
 import math
 from typing import Optional
@@ -370,29 +371,31 @@ def _chunk_geometry(nblocks, bs, h_kv, d, itemsize,
     return ppc, hpb
 
 
-def _paged_decode_kernel(bt_ref, cl_ref, buf_ref, step_ref, q_ref,
-                         *refs,
+def _paged_decode_kernel(bt_ref, cl_ref, nxt_ref, buf_ref, primed_ref,
+                         q_ref, *refs,
                          batch, h_kv, bs, ppc, hpb, nchunks,
                          scale, window, quant, latent_dv=None,
                          masked=False):
-    """One (slot, kv-head-block, page-chunk) program of multi-sequence
-    single-token paged decode.
+    """One (slot, kv-head-block) program of multi-sequence single-token
+    paged decode: a loop over the slot's live chunks of ppc pages.
 
-    The K/V pools stay in HBM (`ANY` memory space); each program's
-    chunk of ppc pages x hpb kv heads is streamed HBM→VMEM by explicit
+    The K/V pools stay in HBM (`ANY` memory space); each chunk of ppc
+    pages x hpb kv heads is streamed HBM→VMEM by explicit
     `pltpu.make_async_copy` DMAs into a two-slot rotating buffer: while
-    chunk i is being reduced, the DMA for the NEXT live chunk — which
-    may belong to the next head block or the next live slot — is
-    already in flight (the upstream jax paged_attention kernel's
-    schedule). `buf_ref`/`step_ref` are mutable scalar-prefetch cells:
-    the buffer toggle and a "pipeline primed" flag that persist across
-    grid steps.
+    chunk c is being reduced, the DMA for the NEXT live chunk — chunk
+    c + 1 of this slot, else the first chunk of the next head block or
+    of the next live slot — is already in flight (the upstream jax
+    paged_attention kernel's schedule). `buf_ref`/`primed_ref` are
+    mutable scalar-prefetch cells that persist across grid steps: the
+    buffer toggle and a "pipeline primed" flag.
 
-    Liveness is a prefix per (slot, head-block) group: chunk j is live
-    iff j * ppc * bs < context_len. Dead chunks and dead slots
-    (context_len 0, e.g. empty serving lanes) issue NO copy and do NO
-    math — they cost neither HBM bandwidth nor VPU/MXU cycles; a dead
-    slot's output rows are zeroed at its group's last grid step
+    Liveness: chunk c of a slot is live iff c * ppc * bs < context_len,
+    so the loop runs ceil(context_len / (ppc * bs)) times (clamped to
+    the table's width) and dead chunks cost nothing, not even a grid
+    step. `nxt_ref` [b] names the first live slot after each slot
+    (batch when none: `_next_live_slots`), one scalar read where the
+    walk leaves a slot. Dead slots (context_len 0, e.g. empty serving
+    lanes) issue NO copy and do NO math; their output rows are zeroed
     (matching paged_attention_arrays).
 
     quant=True adds per-slot scale pools (int8 cache): pages stream at
@@ -412,8 +415,9 @@ def _paged_decode_kernel(bt_ref, cl_ref, buf_ref, step_ref, q_ref,
     pool, no V copy and no V buffer, and o/acc are latent_dv wide. The
     dots then keep the pool's dtype as operands (f32 accumulation): at
     128 query heads a row the kernel is as near the MXU's bound as the
-    HBM's. masked adds a [1, T] int32 keep-row per (slot, chunk) before
-    the pools: the selected set of a sparse-attention layer.
+    HBM's. masked adds the slot's keep-rows [nchunks, 1, T] int32 before
+    the pools, one [1, T] row a chunk: the selected set of a
+    sparse-attention layer.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -431,7 +435,6 @@ def _paged_decode_kernel(bt_ref, cl_ref, buf_ref, step_ref, q_ref,
 
     i = pl.program_id(0)          # slot (sequence / decode lane)
     hb = pl.program_id(1)         # kv-head block
-    j = pl.program_id(2)          # page chunk along the block table
     nhb = h_kv // hpb
     T = ppc * bs                  # tokens per chunk
     d = q_ref.shape[-1]
@@ -464,127 +467,104 @@ def _paged_decode_kernel(bt_ref, cl_ref, buf_ref, step_ref, q_ref,
                     vsbuf.at[buf, :, p], sems.at[buf]))
         return out
 
-    # first live slot after i (batch when none): an unrolled scan over
-    # the STATIC slot count — plain scalar reads + selects, because
-    # ref reads inside lax.cond/while_loop have no interpret-mode
-    # discharge rule (and dead slots must be skipped so their chunks
-    # are never fetched)
-    next_slot = jnp.int32(batch)
-    for t in range(batch - 1, 0, -1):
-        next_slot = jnp.where(
-            jnp.logical_and(t > i, cl_ref[t] > 0),
-            jnp.int32(t), next_slot)
-
-    def next_block(chunk):
-        """First live (slot, head-block, chunk) at or after grid
-        position (i, hb, chunk), in grid order; slot == batch when none
-        is left. Pure value logic on already-read scalars. The
-        chunk < nchunks clamp guards an over-capacity context_len from
-        indexing past the block table."""
-        within = jnp.logical_and(chunk * T < ctx,
-                                 chunk < nchunks)
-        have_head = hb + 1 < nhb
-        ni = jnp.where(within | have_head, i, next_slot)
-        nh = jnp.where(within, hb, jnp.where(have_head, hb + 1, 0))
-        nj = jnp.where(within, chunk, 0)
-        return ni, nh, nj
-
-    @pl.when(jnp.logical_and(ctx == 0, j == nchunks - 1))
+    @pl.when(ctx == 0)
     def _zero_dead():
         # dead slots emit zeros, not a stale buffer (the reference
         # path's cl > 0 guard)
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(j * T < ctx)
-    def _work():
-        buf = buf_ref[0]
-
-        @pl.when(step_ref[0] == 0)
+    @pl.when(ctx > 0)
+    def _walk():
+        @pl.when(primed_ref[0] == 0)
         def _prime():
             # very first live chunk of the whole call: nobody
             # prefetched it, start its copies now (the one unavoidable
             # pipeline bubble)
-            for c in copies(i, hb, j, buf):
+            for c in copies(i, hb, 0, buf_ref[0]):
                 c.start()
 
-        ni, nh, nj = next_block(j + 1)
-
-        @pl.when(ni < batch)
-        def _prefetch():
-            # issue the NEXT live chunk's HBM→VMEM copies into the
-            # other buffer slot while this chunk computes
-            for c in copies(ni, nh, nj, 1 - buf):
-                c.start()
-
-        @pl.when(j == 0)
-        def _init():
-            m_ref[...] = jnp.full_like(m_ref, neg_inf)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-
-        for c in copies(i, hb, j, buf):
-            c.wait()
-        q = q_ref[...].astype(jnp.float32) * jnp.float32(scale)
-        if latent:
-            k = kbuf[buf].reshape(hpb, T, d)
-            v = k[:, :, :latent_dv]
-            q = q.astype(k.dtype)
-        else:
-            k = kbuf[buf].reshape(hpb, T, d).astype(jnp.float32)
-            v = vbuf[buf].reshape(hpb, T, d).astype(jnp.float32)
-        # batched-over-heads skinny dots, f32 accumulation
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)       # [hpb, rep, T]
-        if quant:
-            # dequantize on the SCORE side: q·(k∘ks) = (q·k)∘ks and
-            # p·(v∘vs) = (p∘vs)·v, with the per-token scales as
-            # lane-dense [hpb, 1, T] rows (tokens on lanes, like s) —
-            # a [hpb, T, 1] column to scale k/v themselves is a shape
-            # cast the v5e compiler refuses, and rep·T multiplies are
-            # fewer than T·d anyway
-            def lane_row(sbuf):
-                pages = sbuf[buf]                  # [hpb, ppc, 1, bs]
-                return jnp.concatenate(
-                    [pages[:, p] for p in range(ppc)], axis=-1)
-            s = s * lane_row(ksbuf)
+        m_ref[...] = jnp.full_like(m_ref, neg_inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        n_live = jnp.minimum((ctx + T - 1) // T, nchunks)
+        # where the walk goes after this program's last chunk: the
+        # next head block of this slot, else the next live slot's first
+        last_head = hb + 1 >= nhb
+        slot_after = jnp.where(last_head, nxt_ref[i], i)
+        head_after = jnp.where(last_head, 0, hb + 1)
         pos = ctx - 1
-        k_pos = (j * T
-                 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2))
-        keep = k_pos <= pos
-        if window is not None:
-            keep = jnp.logical_and(keep, pos - k_pos < jnp.int32(window))
-        if masked:
-            keep = jnp.logical_and(keep, (mask_ref[...] != 0)[None])
-        s = jnp.where(keep, s, neg_inf)
 
-        m_prev = m_ref[:, :, :1]
-        l_prev = l_ref[:, :, :1]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        p = jnp.exp(s - m_cur)
-        p = jnp.where(s > neg_inf * 0.5, p, 0.0)
-        alpha = jnp.exp(m_prev - m_cur)
-        l_cur = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
-        if latent:
-            p = p.astype(v.dtype)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p * lane_row(vsbuf) if quant else p, v,
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)       # [hpb, rep, d]
-        m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_cur, l_ref.shape)
+        def chunk_step(c, buf):
+            more = c + 1 < n_live
+            ni = jnp.where(more, i, slot_after)
 
-        last_live = jnp.minimum((ctx + T - 1) // T, nchunks) - 1
+            @pl.when(ni < batch)
+            def _prefetch():
+                # issue the NEXT live chunk's HBM→VMEM copies into the
+                # other buffer slot while this chunk computes
+                for cp in copies(ni, jnp.where(more, hb, head_after),
+                                 jnp.where(more, c + 1, 0), 1 - buf):
+                    cp.start()
 
-        @pl.when(j == last_live)
-        def _fin():
-            l_safe = jnp.maximum(l_ref[:, :, :1], jnp.float32(1e-30))
-            valid = m_ref[:, :, :1] > neg_inf * 0.5
-            o_ref[...] = jnp.where(valid, acc_ref[...] / l_safe,
-                                   0.0).astype(o_ref.dtype)
+            for cp in copies(i, hb, c, buf):
+                cp.wait()
+            q = q_ref[...].astype(jnp.float32) * jnp.float32(scale)
+            if latent:
+                k = kbuf[buf].reshape(hpb, T, d)
+                v = k[:, :, :latent_dv]
+                q = q.astype(k.dtype)
+            else:
+                k = kbuf[buf].reshape(hpb, T, d).astype(jnp.float32)
+                v = vbuf[buf].reshape(hpb, T, d).astype(jnp.float32)
+            # batched-over-heads skinny dots, f32 accumulation
+            s = jax.lax.dot_general(
+                q, k, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)   # [hpb, rep, T]
+            if quant:
+                # dequantize on the SCORE side: q·(k∘ks) = (q·k)∘ks and
+                # p·(v∘vs) = (p∘vs)·v, with the per-token scales as
+                # lane-dense [hpb, 1, T] rows (tokens on lanes, like s)
+                # — a [hpb, T, 1] column to scale k/v themselves is a
+                # shape cast the v5e compiler refuses, and rep·T
+                # multiplies are fewer than T·d anyway
+                def lane_row(sbuf):
+                    pages = sbuf[buf]              # [hpb, ppc, 1, bs]
+                    return jnp.concatenate(
+                        [pages[:, p] for p in range(ppc)], axis=-1)
+                s = s * lane_row(ksbuf)
+            k_pos = (c * T
+                     + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2))
+            keep = k_pos <= pos
+            if window is not None:
+                keep = jnp.logical_and(keep,
+                                       pos - k_pos < jnp.int32(window))
+            if masked:
+                keep = jnp.logical_and(keep, (mask_ref[c] != 0)[None])
+            s = jnp.where(keep, s, neg_inf)
 
-        buf_ref[0] = 1 - buf
-        step_ref[0] = step_ref[0] + 1
+            m_prev = m_ref[:, :, :1]
+            l_prev = l_ref[:, :, :1]
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+            p = jnp.exp(s - m_cur)
+            p = jnp.where(s > neg_inf * 0.5, p, 0.0)
+            alpha = jnp.exp(m_prev - m_cur)
+            l_cur = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
+            if latent:
+                p = p.astype(v.dtype)
+            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                p * lane_row(vsbuf) if quant else p, v,
+                (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)   # [hpb, rep, d]
+            m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
+            l_ref[...] = jnp.broadcast_to(l_cur, l_ref.shape)
+            return 1 - buf
+
+        buf_ref[0] = jax.lax.fori_loop(0, n_live, chunk_step, buf_ref[0])
+        primed_ref[0] = 1
+        l_safe = jnp.maximum(l_ref[:, :, :1], jnp.float32(1e-30))
+        valid = m_ref[:, :, :1] > neg_inf * 0.5
+        o_ref[...] = jnp.where(valid, acc_ref[...] / l_safe,
+                               0.0).astype(o_ref.dtype)
 
 
 def paged_decode_pallas(q, k_cache, v_cache, block_tables, context_lens,
@@ -594,19 +574,31 @@ def paged_decode_pallas(q, k_cache, v_cache, block_tables, context_lens,
     """Pallas multi-sequence paged decode: q [b, h, d] (one token per
     sequence) against the page pool, masked to context_lens (and a
     sliding window). Returns [b, h, d]. One kernel instance covers ALL
-    b slots — grid (slot, kv-head-block, page-chunk) with
-    double-buffered HBM→VMEM page prefetch over the block table; slots
-    with context_len 0 (empty serving lanes) cost no bandwidth and
-    emit zeros. Pass k_scale/v_scale [num_blocks, h_kv, block_size]
-    f32 for an int8 pool (in-kernel dequant). Geometry must satisfy
-    paged_pallas_eligible(d, block_size, k_cache.dtype);
-    pages_per_chunk/kv_heads_per_block override the auto tiling (each
-    must divide its dimension)."""
+    b slots — grid (slot, kv-head-block), each program a loop over the
+    slot's live page chunks with double-buffered HBM→VMEM page prefetch
+    over the block table; slots with context_len 0 (empty serving
+    lanes) cost no bandwidth and emit zeros. Pass k_scale/v_scale
+    [num_blocks, h_kv, block_size] f32 for an int8 pool (in-kernel
+    dequant). Geometry must satisfy paged_pallas_eligible(d,
+    block_size, k_cache.dtype); pages_per_chunk/kv_heads_per_block
+    override the auto tiling (each must divide its dimension)."""
     return _paged_decode_call(
         "paged_decode", q, k_cache, v_cache, block_tables, context_lens,
         scale=scale, window=window, interpret=interpret, k_scale=k_scale,
         v_scale=v_scale, pages_per_chunk=pages_per_chunk,
         kv_heads_per_block=kv_heads_per_block)
+
+
+def _next_live_slots(context_lens):
+    """[b] int32: the first slot after each slot whose context_len is
+    nonzero, b where none is: a reverse running minimum over the live
+    slots' indices, shifted by one. Where the decode walk goes after a
+    slot's last chunk, read with ONE scalar load."""
+    b = context_lens.shape[0]
+    live = jnp.where(context_lens > 0, jnp.arange(b, dtype=jnp.int32),
+                     jnp.int32(b))
+    at_or_after = jax.lax.cummin(live, reverse=True)
+    return jnp.concatenate([at_or_after[1:], jnp.full((1,), b, jnp.int32)])
 
 
 def _paged_decode_call(name, q, k_cache, v_cache, block_tables,
@@ -618,9 +610,32 @@ def _paged_decode_call(name, q, k_cache, v_cache, block_tables,
     paged_mla_decode: the same grid, page walk and online softmax;
     `v_cache` None with `latent_dv` set is the latent pool whose rows
     are keys and (their first latent_dv entries) values, `mask`
-    [b, max_blocks * block_size] a keep-set on top of the causal one."""
-    import functools
+    [b, max_blocks * block_size] a keep-set on top of the causal one.
+    Counted once a traced call (`kernels.decode.paged_live_walk`)."""
+    from .. import monitor
 
+    monitor.counter("kernels.decode.paged_live_walk").increase()
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    return _paged_decode_jit(
+        q, k_cache, v_cache, block_tables, context_lens, k_scale, v_scale,
+        mask, name=name, scale=float(scale),
+        window=None if window is None else int(window),
+        interpret=bool(interpret), pages_per_chunk=pages_per_chunk,
+        kv_heads_per_block=kv_heads_per_block,
+        latent_dv=None if latent_dv is None else int(latent_dv))
+
+
+# jitted: the decode layers of one program call the kernel with the same
+# shapes, and a nested jit is traced and lowered (Pallas to Mosaic) once
+# for all of them; set-up time, not device time
+@functools.partial(jax.jit, static_argnames=(
+    "name", "scale", "window", "interpret", "pages_per_chunk",
+    "kv_heads_per_block", "latent_dv"))
+def _paged_decode_jit(q, k_cache, v_cache, block_tables, context_lens,
+                      k_scale, v_scale, mask, *, name, scale, window,
+                      interpret, pages_per_chunk, kv_heads_per_block,
+                      latent_dv):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -632,14 +647,13 @@ def _paged_decode_call(name, q, k_cache, v_cache, block_tables,
     rep = h // h_kv
     quant = k_scale is not None
     latent = latent_dv is not None
-    dv = int(latent_dv) if latent else d
-    if scale is None:
-        scale = 1.0 / math.sqrt(d)
+    dv = latent_dv if latent else d
     ppc, hpb = _chunk_geometry(nblocks, bs, h_kv, d,
                                jnp.dtype(k_cache.dtype).itemsize,
                                pages_per_chunk, kv_heads_per_block)
     nchunks = nblocks // ppc
     nhb = h_kv // hpb
+    T = ppc * bs
     bt = jnp.asarray(block_tables, jnp.int32)
     cl = jnp.asarray(context_lens, jnp.int32)
     qr = q.reshape(b, h_kv, rep, d)
@@ -651,19 +665,19 @@ def _paged_decode_call(name, q, k_cache, v_cache, block_tables,
 
     kernel = functools.partial(
         _paged_decode_kernel, batch=b, h_kv=h_kv, bs=bs, ppc=ppc,
-        hpb=hpb, nchunks=nchunks, scale=float(scale),
-        window=None if window is None else int(window), quant=quant,
-        latent_dv=dv if latent else None, masked=mask is not None)
+        hpb=hpb, nchunks=nchunks, scale=scale, window=window, quant=quant,
+        latent_dv=latent_dv, masked=mask is not None)
     any_space = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
     in_specs = [pl.BlockSpec((None, hpb, rep, d),
-                             lambda i, hb, j, *_: (i, hb, 0, 0))]   # q
+                             lambda i, hb, *_: (i, hb, 0, 0))]   # q
     inputs = [qr]
     if mask is not None:
-        # one [1, T] keep-row per (slot, chunk), tokens on lanes like s
-        in_specs.append(pl.BlockSpec((None, 1, ppc * bs),
-                                     lambda i, hb, j, *_: (i, 0, j)))
+        # the slot's keep-rows, one [1, T] row a chunk (tokens on lanes
+        # like s), picked by the walk on the leading dimension
+        in_specs.append(pl.BlockSpec((None, nchunks, 1, T),
+                                     lambda i, hb, *_: (i, 0, 0, 0)))
         inputs.append(jnp.asarray(mask, jnp.int32)
-                      .reshape(b, 1, nblocks * bs))
+                      .reshape(b, nchunks, 1, T))
     in_specs.append(any_space)
     inputs.append(k_cache)
     if not latent:
@@ -689,14 +703,14 @@ def _paged_decode_call(name, q, k_cache, v_cache, block_tables,
         pltpu.VMEM((hpb, rep, dv), jnp.float32),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        # bt, cl, plus two MUTABLE scalar cells the kernel uses as
-        # cross-step pipeline state: the DMA buffer toggle and the
-        # "pipeline primed" step counter
-        num_scalar_prefetch=4,
-        grid=(b, nhb, nchunks),
+        # bt, cl, the next-live-slot table, plus two MUTABLE scalar
+        # cells the kernel uses as cross-step pipeline state: the DMA
+        # buffer toggle and the "pipeline primed" flag
+        num_scalar_prefetch=5,
+        grid=(b, nhb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((None, hpb, rep, dv),
-                               lambda i, hb, j, *_: (i, hb, 0, 0)),
+                               lambda i, hb, *_: (i, hb, 0, 0)),
         scratch_shapes=scratch,
     )
     with _x32_trace():
@@ -705,12 +719,11 @@ def _paged_decode_call(name, q, k_cache, v_cache, block_tables,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, h_kv, rep, dv), q.dtype),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary", "arbitrary",
-                                     "arbitrary")),
+                dimension_semantics=("arbitrary", "arbitrary")),
             interpret=interpret,
             name=name,
-        )(bt, cl, jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
-          *inputs)
+        )(bt, cl, _next_live_slots(cl), jnp.zeros((1,), jnp.int32),
+          jnp.zeros((1,), jnp.int32), *inputs)
     return out.reshape(b, h, dv)
 
 

@@ -2,8 +2,10 @@
 
 The kernel contract under test (kernels/paged_attention.py,
 docs/DECODE.md): ONE kernel instance covers every decode slot — grid
-(slot, kv-head-block, page-chunk) with double-buffered HBM→VMEM page
-prefetch driven by explicit async copies — and must agree with the
+(slot, kv-head-block), each program a loop over the slot's live page
+chunks, with double-buffered HBM→VMEM page prefetch driven by explicit
+async copies that cross from one program to the next — and must agree
+with the
 reference ``paged_attention_arrays`` gather path across the serving
 matrix: mixed live/dead slots (dead slots emit zeros and are skipped
 by the prefetch schedule), ragged context lengths including exact
@@ -39,14 +41,25 @@ def _pool(rng, b, h, h_kv, d, bs, nblocks, dtype=np.float32):
     return q, kc, vc, bt
 
 
-def test_mixed_live_dead_slots(rng):
+# context lengths a slot (page 8, 5 pages: capacity 40); 0 = a dead lane
+LIVE_LAYOUTS = {
+    "mixed": [0, 1, 13, 0, 40, 23],
+    "live-between-dead-runs": [0, 0, 0, 9, 0, 0, 40, 0, 0, 0, 17, 0],
+    "only-the-last-live": [0, 0, 0, 0, 0, 33],
+}
+
+
+@pytest.mark.parametrize("layout", LIVE_LAYOUTS)
+def test_mixed_live_dead_slots(rng, layout):
     """Dead slots (context 0 — empty serving lanes) must emit exact
     zeros while live neighbours, including a 1-token context, stay
     bit-identical to the same call without the dead lanes: the
-    prefetch lookahead has to skip dead slots, not stall on them."""
-    b, h, h_kv, d, bs, nblocks = 6, 8, 4, 128, 8, 5
+    prefetch lookahead has to skip dead slots (a run of them, or all
+    but the last), not stall on them."""
+    cl = np.array(LIVE_LAYOUTS[layout], np.int32)
+    b, h, h_kv, d, bs, nblocks = len(cl), 8, 4, 128, 8, 5
     q, kc, vc, bt = _pool(rng, b, h, h_kv, d, bs, nblocks)
-    cl = jnp.asarray(np.array([0, 1, 13, 0, 40, 23], np.int32))
+    cl = jnp.asarray(cl)
     ref = paged_attention_arrays(q, kc, vc, bt, cl)
     out = paged_decode_pallas(q, kc, vc, bt, cl, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL)
@@ -70,18 +83,20 @@ def test_all_slots_dead(rng):
     assert (np.asarray(out) == 0.0).all()
 
 
-def test_page_boundary_context_lengths(rng):
-    """Contexts ending exactly ON a page/chunk boundary, one past it,
-    and at full capacity — the liveness predicate and the last-live-
-    chunk output write must agree with the reference masks."""
-    b, h, h_kv, d, bs, nblocks = 5, 8, 4, 128, 8, 4
+@pytest.mark.parametrize("ppc", [1, 2, 4])
+def test_page_boundary_context_lengths(rng, ppc):
+    """Contexts ending one short of a chunk boundary, exactly ON it,
+    one past it, mid-page, and at full capacity — the walk's trip count
+    ceil(ctx / T) and the output write after the last live chunk must
+    agree with the reference masks, for chunks of 1, 2 and 4 pages."""
+    b, h, h_kv, d, bs, nblocks = 6, 8, 4, 128, 8, 8
     q, kc, vc, bt = _pool(rng, b, h, h_kv, d, bs, nblocks)
-    # bs=8, chunks of 2 pages (16 tokens): [boundary, boundary+1,
-    # mid-page, capacity, 1]
-    cl = jnp.asarray(np.array([16, 17, 11, 32, 1], np.int32))
+    T, capacity = ppc * bs, nblocks * bs
+    cl = jnp.asarray(np.array([T - 1, T, T + 1, 11, capacity, 1],
+                              np.int32))
     ref = paged_attention_arrays(q, kc, vc, bt, cl)
     out = paged_decode_pallas(q, kc, vc, bt, cl, interpret=True,
-                              pages_per_chunk=2)
+                              pages_per_chunk=ppc)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL)
 
 
@@ -107,7 +122,9 @@ def test_chunk_and_headblock_partitions_agree(rng):
                 err_msg=f"ppc={ppc} hpb={hpb}", **TOL)
 
 
-def test_int8_scale_pools_mixed_slots_window(rng):
+@pytest.mark.parametrize("cl", [[0, 33, 128, 64], [33, 0, 0, 97]],
+                         ids=["mixed", "live-between-dead-runs"])
+def test_int8_scale_pools_mixed_slots_window(rng, cl):
     """int8 pools + per-slot scale pools through the multi-sequence
     grid: in-VMEM dequant must match the gather+dequant reference with
     dead lanes, ragged lengths and a sliding window in the mix."""
@@ -119,14 +136,14 @@ def test_int8_scale_pools_mixed_slots_window(rng):
         (b * nblocks, h_kv, bs, d)).astype(np.float32)))
     bt = jnp.asarray(rng.permutation(b * nblocks).astype(
         np.int32).reshape(b, nblocks))
-    cl = jnp.asarray(np.array([0, 33, 128, 64], np.int32))
+    cl = jnp.asarray(np.array(cl, np.int32))
     ref = paged_attention_arrays(q, kq, vq, bt, cl,
                                  k_scale=ks, v_scale=vs)
     out = paged_decode_pallas(q, kq, vq, bt, cl, interpret=True,
                               k_scale=ks, v_scale=vs,
                               pages_per_chunk=2)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL)
-    assert (np.asarray(out)[0] == 0.0).all()
+    assert (np.asarray(out)[np.asarray(cl) == 0] == 0.0).all()
     # windowed: only the last `window` positions stay visible
     win = 17
     L = nblocks * bs
@@ -161,6 +178,87 @@ def test_bf16_pool(rng):
     out = paged_decode_pallas(q, kc, vc, bt, cl, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=5e-3, atol=5e-3)
+
+
+# a latent pool of 640-lane rows (512 values + 64 rope + padding, the
+# DeepSeek-V2 / dots3 full layer's row), page 16, 8 pages a slot walked
+# in chunks of 2 (T = 32): contexts at T - 1, T + 1, 2T and capacity,
+# live slots between runs of dead ones
+LATENT_CTX = [31, 0, 0, 33, 128, 0, 64]
+
+
+@pytest.mark.parametrize("kw", [
+    dict(), dict(mask=True), dict(window=40), dict(dtype=jnp.bfloat16)],
+    ids=["causal", "selected-set", "window", "bf16-pool"])
+def test_latent_walk_matches_the_xla_formulation(rng, kw):
+    """`paged_mla_decode` on the 640-lane latent row, with and without a
+    keep-mask (the mask row follows the walk chunk by chunk), under a
+    window, on a bf16 pool: every live slot agrees with
+    `paged_mla_arrays`, every dead one is zeros."""
+    from paddle_tpu.kernels.paged_attention import (paged_mla_arrays,
+                                                    paged_mla_decode)
+    kw = dict(kw)
+    dtype = kw.pop("dtype", jnp.float32)
+    cl = np.array(LATENT_CTX, np.int32)
+    b, h, w, dv, bs, mb = len(cl), 8, 640, 512, 16, 8
+    pool = jnp.asarray(rng.normal(size=(1 + b * mb, bs, w)), dtype)
+    bt = jnp.asarray(1 + rng.permutation(b * mb).reshape(b, mb), jnp.int32)
+    q = jnp.asarray(rng.normal(size=(b, h, w)), jnp.float32).astype(dtype)
+    if kw.pop("mask", False):
+        kw["mask"] = jnp.asarray(rng.integers(0, 2, (b, mb * bs)), jnp.int32)
+    want = paged_mla_arrays(q, pool, bt, jnp.asarray(cl), dv, 0.05, **kw)
+    got = paged_mla_decode(q, pool, bt, jnp.asarray(cl), dv, 0.05,
+                           interpret=True, pages_per_chunk=2, **kw)
+    assert got.shape == (b, h, dv)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+    assert not np.asarray(got)[cl == 0].any()
+
+
+def test_layers_of_one_signature_trace_the_kernel_once(rng, monkeypatch):
+    """Six decode layers of one program calling the kernel at one
+    signature trace its body ONCE (a nested jit), and each call is
+    counted in `kernels.decode.paged_live_walk`: a body traced per call
+    costs set-up time in every program that holds six such layers."""
+    from paddle_tpu import monitor
+    from paddle_tpu.kernels import paged_attention as pa
+    traced = []
+    body = pa._paged_decode_kernel
+
+    def counting(*refs, **kw):
+        traced.append(1)
+        return body(*refs, **kw)
+
+    monkeypatch.setattr(pa, "_paged_decode_kernel", counting)
+    # a geometry no other test uses, so no earlier trace is cached
+    b, h, h_kv, d, bs, nblocks = 3, 10, 2, 128, 8, 3
+    q, kc, vc, bt = _pool(rng, b, h, h_kv, d, bs, nblocks)
+    cl = jnp.asarray(np.array([5, 0, 24], np.int32))
+
+    def six_layers(q, kc, vc, bt, cl):
+        out = q
+        for _ in range(6):
+            out = paged_decode_pallas(out, kc, vc, bt, cl, interpret=True)
+        return out
+
+    walks = monitor.counter("kernels.decode.paged_live_walk")
+    before = walks.get()
+    jax.jit(six_layers).lower(q, kc, vc, bt, cl)
+    assert len(traced) == 1
+    assert walks.get() == before + 6
+
+
+def test_next_live_slots_table():
+    """The walk's next-live-slot table: for each slot, the first LATER
+    slot with a nonzero context, b where none is."""
+    from paddle_tpu.kernels.paged_attention import _next_live_slots
+    cl = jnp.asarray([0, 5, 0, 0, 7, 0, 1, 0], jnp.int32)
+    np.testing.assert_array_equal(np.asarray(_next_live_slots(cl)),
+                                  [1, 4, 4, 4, 6, 6, 8, 8])
+    np.testing.assert_array_equal(
+        np.asarray(_next_live_slots(jnp.zeros((3,), jnp.int32))), [3, 3, 3])
 
 
 def test_chunk_geometry_and_requirements():

@@ -126,6 +126,52 @@ def test_paged_decode_int8_narrow_page_is_refused(one_chip):
         _compiled_text(_paged, one_chip, *_paged_shapes(jnp.int8, 32))
 
 
+# the cells' decode calls, each its own walk over a slot's live chunks:
+# (query heads, kv heads or None for a latent pool, row width, value width,
+# slots, pages a slot, keep-mask); pages of 128, bfloat16
+WALK_CALLS = {
+    # Falcon-H1: 20 / 4 heads of 128 (a group of 5), 96 slots x 21 pages
+    "reply96": (20, 4, 128, 128, 96, 21, False),
+    # DeepSeek-V2: 128 heads on 640-lane latent rows, 64 slots x 104
+    "docs64": (128, None, 640, 512, 64, 104, False),
+    # dots3-note's full layer: the same row under the selected-set mask
+    "notes48-masked": (128, None, 640, 512, 48, 40, True),
+}
+
+
+@pytest.mark.parametrize("cell", WALK_CALLS)
+def test_live_walk_compiles_at_the_cells_geometries(one_chip, cell):
+    """The decode kernel's walk (a (slot, head block) grid, a loop over
+    the slot's live chunks, the next-live-slot table) is taken by the
+    TPU compiler at each cell's geometry: one Pallas call under the
+    kernel's own name, counted once in `kernels.decode.paged_live_walk`."""
+    from paddle_tpu import monitor
+    heads, kv, w, dv, slots, pages, masked = WALK_CALLS[cell]
+    nb = slots * pages + 1
+    shapes = [((slots, heads, w), jnp.bfloat16),
+              ((nb, 128, w) if kv is None else (nb, kv, 128, w),
+               jnp.bfloat16),
+              ((slots, pages), jnp.int32), ((slots,), jnp.int32)]
+    if kv is not None:
+        shapes.insert(2, ((nb, kv, 128, w), jnp.bfloat16))
+    if masked:
+        shapes.append(((slots, pages * 128), jnp.int32))
+
+    def call(q, *rest):
+        if kv is not None:
+            return paged_decode_pallas(q, *rest)
+        pool, bt, cl, *mask = rest
+        return paged_mla_decode(q, pool, bt, cl, dv, 0.07,
+                                mask=mask[0] if mask else None)
+
+    walks = monitor.counter("kernels.decode.paged_live_walk")
+    before = walks.get()
+    text = _compiled_text(call, one_chip, *shapes)
+    name = "paged_decode" if kv is not None else "paged_mla_decode"
+    assert text.count("tpu_custom_call") == 1 and f"%{name}" in text
+    assert walks.get() == before + 1
+
+
 # the serve cell's pools (benchmark/configs/mistral-7b-serve-8l.json):
 # 48 slots x 18 pages + the scratch page, 8 kv heads, page 128, d 128
 POOL, BT_WIDTH, Q_HEADS = (865, 8, 128, 128), 18, 32
